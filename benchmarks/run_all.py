@@ -34,6 +34,17 @@ from benchmarks import (
 
 
 def main() -> None:
+    import jax
+
+    from orleans_tpu import native
+    from orleans_tpu.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
+    dev = jax.devices()[0]
+    print(json.dumps({"device": {"platform": dev.platform,
+                                 "kind": dev.device_kind,
+                                 "count": len(jax.devices())},
+                      "wire_codec": native.wire_codec()}))
     for r in asyncio.run(ping.run(n_grains=10_000, concurrency=100,
                                   seconds=3.0, rounds=30)):
         print(json.dumps(r))

@@ -20,7 +20,7 @@ from typing import Iterator
 from ..model import FileContext, Finding, Rule, register
 from .common import dotted_name, func_params, lexical_walk
 
-TRACING_WRAPPERS = {"jit", "pjit", "shard_map", "shard_map_compat"}
+TRACING_WRAPPERS = {"jit", "pjit", "shard_map"}
 DEVICE_DIRS = ("dispatch", "ops", "parallel")
 
 IMPURE_CALLS = {
@@ -38,7 +38,7 @@ MUTATOR_METHODS = {
 
 def _wrapper_target(call: ast.Call) -> "ast.expr | None":
     """First positional arg of a tracing-wrapper call, else None.
-    Handles ``jax.jit(f)``, ``shard_map_compat(f, mesh=...)``,
+    Handles ``jax.jit(f)``, ``jax.shard_map(f, mesh=...)``,
     ``partial(jax.jit, ...)`` (returns None — no target yet)."""
     last = dotted_name(call.func).rsplit(".", 1)[-1]
     if last in TRACING_WRAPPERS and call.args:
@@ -135,7 +135,7 @@ class TracedImpurity(Rule):
                         seen.add(id(d))
                         traced.append((d, qualnames[id(d)]))
             elif isinstance(target, ast.Call):
-                # jit(shard_map_compat(f, ...)) — unwrap one level
+                # jit(shard_map(f, ...)) — unwrap one level
                 mark(_wrapper_target(target), scope_id)
 
         for scope in scopes:

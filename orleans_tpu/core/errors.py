@@ -1,4 +1,4 @@
-"""Framework exception taxonomy.
+"""Framework exception hierarchy.
 
 Mirrors the reference's public exception surface
 (/root/reference/src/Orleans.Core.Abstractions/Core/ — ``OrleansException``,

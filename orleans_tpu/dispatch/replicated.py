@@ -38,8 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.mesh import (SILO_AXIS, replicated_spec, shard_map_compat,
-                             shard_spec)
+from ..parallel.mesh import SILO_AXIS, replicated_spec, shard_spec
 from .engine import _validate_args
 from .vector_grain import VectorGrain, vector_methods
 
@@ -86,9 +85,8 @@ class ReplicatedWorkerHost:
         self.n_shards = mesh.devices.size
         self.n_keys = int(n_keys)
         self.methods = vector_methods(cls)
-        self._sharding = shard_spec(mesh) if self.n_shards > 1 else None
-        self._replicated = replicated_spec(mesh) if self.n_shards > 1 \
-            else None
+        self._sharding = shard_spec(mesh)
+        self._replicated = replicated_spec(mesh)
         self._rr = 0  # round-robin shard assignment (the scale-out knob)
         # per-(shard, key) activation bitmap: first touch runs
         # initial_state on that shard's replica row (OnActivate per
@@ -102,8 +100,7 @@ class ReplicatedWorkerHost:
         self.calls = 0
 
     def _put(self, arr):
-        return jax.device_put(arr, self._sharding) if self._sharding \
-            else arr
+        return jax.device_put(arr, self._sharding)
 
     # ------------------------------------------------------------------
     def call_batch(self, method: str, keys: np.ndarray,
@@ -235,7 +232,7 @@ class ReplicatedWorkerHost:
 
         if self.n_shards > 1:
             spec = P(SILO_AXIS)
-            local = shard_map_compat(
+            local = jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(spec, spec, spec, spec, spec),
                 out_specs=(spec, spec), check_vma=False)
@@ -255,8 +252,7 @@ class ReplicatedWorkerHost:
         keys = np.asarray(keys, dtype=np.int32)
         self._check_keys(keys)
         kern = self._merge_kernel(keys.shape[0])
-        d_keys = jax.device_put(jnp.asarray(keys), self._replicated) \
-            if self._replicated else jnp.asarray(keys)
+        d_keys = jax.device_put(jnp.asarray(keys), self._replicated)
         out = kern(self.state, d_keys)
         return jax.tree_util.tree_map(np.asarray, out)
 
@@ -291,7 +287,7 @@ class ReplicatedWorkerHost:
             return jax.tree_util.tree_map(lambda a: a[None], rows)
 
         if sharded:
-            local = shard_map_compat(
+            local = jax.shard_map(
                 local, mesh=self.mesh, in_specs=(P(SILO_AXIS), P()),
                 out_specs=P(None), check_vma=False)
 

@@ -208,7 +208,9 @@ class FileMembershipTable(MembershipTable):
                      for k, (e, tag) in rows.items()},
             "version": version.version, "etag": version.etag,
         }
-        tmp = self.path + ".tmp"
+        # per-process tmp name: the silo's worker processes share this
+        # table by path, and two writers on one tmp name lose the rename
+        tmp = f"{self.path}.{os.getpid()}.tmp"
         with open(tmp, "w") as f:
             json.dump(raw, f)
         os.replace(tmp, self.path)

@@ -94,3 +94,13 @@ def load(name: str):
             mod = None
     _CACHED[name] = mod
     return mod
+
+
+def wire_codec() -> str:
+    """Which wire-tier value codec this process runs: the C extension by
+    file name, or the pure-Python fallback by name — entry points print
+    it so a run can never pass for a native one when the build failed."""
+    mod = load("_hotwire")
+    if mod is None:
+        return "python (fallback: the _hotwire C extension did not build)"
+    return f"native ({Path(mod.__file__).name})"

@@ -51,19 +51,25 @@ def _prefix_kernel(ids_ref, out_ref, *, block: int, n_dest: int):
 
 def rank_by_dest(dest: jax.Array, n_dest: int, *, block: int = 256,
                  use_pallas: bool | None = None,
-                 interpret: bool | None = None) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """rank[i] = position of message i within its destination group.
 
     dest: [B] int32 in [0, n_dest) — map invalid lanes to a sink id in
     [0, n_dest) *before* calling. Returns [B] int32.
+
+    ``use_pallas=None`` (what the main path passes): the compiled MXU
+    kernel on a TPU for B >= 512, plain XLA everywhere else — the sort
+    rank off-TPU, the pairwise mask for small batches. Interpret mode is
+    never chosen here; a test asks for it with ``interpret=True``.
     """
     B = dest.shape[0]
     d = dest.astype(jnp.int32)
     if use_pallas is None:
-        use_pallas = B >= 512
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        use_pallas = B >= 512 and (
+            interpret or jax.default_backend() == "tpu")
     if not use_pallas:
+        if B >= 512:
+            return rank_dense_keys(d)
         # small batches: the O(B^2) pairwise mask fits comfortably on-chip
         row = d[:, None] == d[None, :]
         lower = jnp.tril(jnp.ones((B, B), jnp.bool_), -1)

@@ -22,12 +22,16 @@ classic TPU anti-pattern. Both implementations here instead ride the MXU:
 ``segment_sum`` picks the Pallas path on TPU for well-tiled shapes, the
 one-hot path for other TPU shapes, and a plain scatter-add on non-TPU
 backends (where the one-hot operand is pure overhead — the scatter IS the
-fast path there; Pallas runs in interpret mode only for tests).
+fast path there). Nothing here chooses Pallas interpret mode by itself:
+``interpret=True`` is something a test asks for by name.
 
-Accumulation note: the MXU paths accumulate in float32, exact for integer
-values only below 2^24 per segment; the CPU scatter path sums exactly in
-the input dtype. Per-segment totals beyond 2^24 should accumulate across
-calls in caller state (as the bench's GameGrain does), not per call.
+Accumulation note: the MXU paths multiply and accumulate in float32 at
+``Precision.HIGHEST`` — a TPU's default precision rounds f32 matmul
+operands to bfloat16, which is exact only up to 256 — so integer values
+are summed exactly while each value and each per-segment total stays
+below 2^24; the CPU scatter path sums exactly in the input dtype.
+Per-segment totals beyond 2^24 should accumulate across calls in caller
+state (as the bench's GameGrain does), not per call.
 """
 
 from __future__ import annotations
@@ -41,6 +45,11 @@ from jax.experimental import pallas as pl
 
 __all__ = ["segment_sum", "segment_sum_onehot", "segment_sum_pallas",
            "masked_reduce", "host_fold", "REDUCE_OPS"]
+
+
+# f32 x f32 on the MXU without rounding the operands to bfloat16 (the
+# exactness contract of the module docstring)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _as_2d(values: jax.Array) -> tuple[jax.Array, bool]:
@@ -63,7 +72,7 @@ def segment_sum_onehot(values: jax.Array, seg_ids: jax.Array,
     seg_range = jax.lax.broadcasted_iota(jnp.int32, (num_segments, 1), 0)
     mask = (seg_range == ids[None, :]).astype(jnp.float32)  # [S, B]
     out = jnp.dot(mask, v.astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
+                  preferred_element_type=jnp.float32, precision=_EXACT)
     out = out.astype(values.dtype)
     return out[:, 0] if squeeze else out
 
@@ -81,17 +90,18 @@ def _seg_kernel(ids_ref, v_ref, out_ref, *, block_s: int):
     seg = jax.lax.broadcasted_iota(jnp.int32, (block_s, ids.shape[0]), 0)
     mask = (seg + seg_base == ids[None, :]).astype(jnp.float32)  # [TS, TB]
     out_ref[:] += jnp.dot(mask, v_ref[:].astype(jnp.float32),
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=_EXACT)
 
 
 def segment_sum_pallas(values: jax.Array, seg_ids: jax.Array,
                        num_segments: int, *, block_s: int = 256,
                        block_b: int = 512,
-                       interpret: bool | None = None) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """Blocked-MXU segment sum (see module docstring). Pads B and S up to
-    tile multiples; out-of-range ids never match a segment tile."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    tile multiples; out-of-range ids never match a segment tile.
+    Compiled for the backend it runs on unless a test passes
+    ``interpret=True``."""
     v, squeeze = _as_2d(values)
     B, D = v.shape
     ids = seg_ids.astype(jnp.int32)
@@ -212,6 +222,5 @@ def segment_sum(values: jax.Array, seg_ids: jax.Array,
                                    num_segments=num_segments)
     B, D = v2.shape
     if B >= 1024 and num_segments >= 256 and D % 128 == 0:
-        return segment_sum_pallas(values, seg_ids, num_segments,
-                                  interpret=False)
+        return segment_sum_pallas(values, seg_ids, num_segments)
     return segment_sum_onehot(values, seg_ids, num_segments)

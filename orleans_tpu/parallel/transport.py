@@ -25,7 +25,7 @@ from functools import partial
 import jax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import SILO_AXIS, shard_map_compat
+from .mesh import SILO_AXIS
 
 __all__ = ["build_exchange"]
 
@@ -82,7 +82,7 @@ def build_exchange(mesh, capacity: int):
 
     if n_shards > 1:
         spec = P(SILO_AXIS)
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(spec, spec, spec), out_specs=(spec, spec, spec),
             check_vma=False)

@@ -1015,6 +1015,8 @@ class Silo:
         for f in _fields(self.config):
             log.info("SiloConfig.%s = %r", f.name,
                      getattr(self.config, f.name))
+        from ..native import wire_codec
+        log.info("wire codec: %s", wire_codec())
         self.status = "Joining"
         if self.config.worker_procs > 1 and self.workers is None:
             # fork FIRST — before the message center, profiler, metrics

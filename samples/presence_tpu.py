@@ -5,8 +5,8 @@ heartbeats fan into GameGrain summaries) re-expressed two-tier:
 
 * PlayerGrain is a **VectorGrain**: 100k concurrent players live as rows of
   a sharded device table; heartbeat waves arrive as bulk batches and run as
-  ONE kernel per tick (the ≥1M msgs/sec path — bench.py measures 1M players
-  at 104M msgs/sec/chip on a v5e).
+  ONE kernel per tick (the bulk path; `chip_smoke.py` drives it at 1M
+  players on the chip).
 * GameGrain stays a **host grain**: low-rate queries, arbitrary Python.
   Game summaries are computed from the device table with an MXU segment
   reduction (ops.segment_sum) — the fan-in without 100k messages.
@@ -16,7 +16,9 @@ heartbeats fan into GameGrain summaries) re-expressed two-tier:
 * Write-behind persistence keeps per-player state durable (MemoryStorage
   here; any GrainStorage provider works).
 
-Run: python samples/presence_tpu.py   (CPU works; TPU if present)
+Run: python samples/presence_tpu.py   (on whatever backend jax selects; set
+JAX_PLATFORMS=cpu to keep it off the chip — the first lines it prints name
+the device)
 """
 
 import asyncio
@@ -26,6 +28,7 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -71,23 +74,28 @@ class PlayerVectorGrain(VectorGrain):
 
 class GameGrain(Grain):
     """GameGrain (host tier): summarizes its players from the device table
-    — one MXU reduction instead of N_PLAYERS messages."""
+    — one MXU reduction instead of one message per player."""
 
     async def summary(self) -> dict:
-        tbl = self.runtime.vector.table(PlayerVectorGrain)
+        rt = self.runtime.vector
+        tbl = rt.table(PlayerVectorGrain)
         game = int(self.primary_key)
-        games = tbl.state["game"].reshape(-1)
-        scores = tbl.state["score"].reshape(-1)
-        totals = segment_sum_onehot(scores.astype(jnp.float32), games,
-                                    N_GAMES)
-        members = segment_sum_onehot(jnp.ones_like(scores, jnp.float32),
-                                     games, N_GAMES)
+        per = tbl.dense_per_shard
+        with rt.tick_fence():
+            # never slice state an off-loop tick has donated mid-flight
+            games = tbl.state["game"][:, :per].reshape(-1)
+            scores = tbl.state["score"][:, :per].reshape(-1)
+        # rows in key order; only players that have been activated count
+        # (out-of-range segment ids contribute nothing)
+        live = np.zeros(games.shape[0], bool)
+        live[:tbl.dense_n] = tbl.dense_active
+        live = jnp.asarray(live)
+        seg = jnp.where(live, games, N_GAMES)
+        totals = segment_sum_onehot(scores.astype(jnp.float32), seg, N_GAMES)
+        members = segment_sum_onehot(live.astype(jnp.float32), seg, N_GAMES)
         return {"game": game,
                 "total_score": int(totals[game]),
-                "players": int(members[game]) - (
-                    # padding/sink rows init to game 0; exclude them
-                    int(tbl.state["game"].size - N_PLAYERS)
-                    if game == 0 else 0)}
+                "players": int(members[game])}
 
 
 async def main() -> None:
@@ -99,6 +107,8 @@ async def main() -> None:
                       storage=storage, flush_period=0.5)
     silo = b.build()
     await silo.start()
+    print(f"device: {jax.devices()[0].platform} "
+          f"({jax.devices()[0].device_kind}) x {len(jax.devices())}")
     client = await ClusterClient(silo.fabric).connect()
 
     # --- bulk heartbeat waves: the device-tier hot path ------------------

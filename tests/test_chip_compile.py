@@ -1,0 +1,216 @@
+"""Compiles for the real chip, kept as tests (no chip needed).
+
+The TPU's compiler is installed in the sandbox and compiles for a chip
+that is described, not attached. These are the programs ``chip_smoke.py``
+runs on a v5e, at its sizes: both Pallas kernels, the one-chip tick, bulk
+and scan kernels over a 1M-row table, and the four-device ``shard_map``
+kernel plus the exchange (``all_to_all`` with the Pallas rank inside).
+What the chip's compiler would refuse — a misaligned slice, too much
+fast memory, a program that does not fit — fails here, at no chip time.
+Nothing executes, so nothing here says anything about results or speed.
+
+The topology is described inside a module-scoped fixture of THIS file and
+nowhere else: only one process may load libtpu, the driver runs the suite
+under several workers, and each worker imports every test file — so
+nothing at import time, in a ``skipif`` or in ``conftest.py`` may touch
+it. Keep every such test in this one file.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "samples"))
+
+N_PLAYERS = 1_000_000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but can never be read back without the chip: keep it off around these
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from orleans_tpu.parallel import SILO_AXIS
+    assert len(topo.devices) == 4
+    return Mesh(np.array(topo.devices), (SILO_AXIS,))
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the two Pallas kernels at the smoke's shapes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,S,D", [(1 << 20, 1024, 128), (1 << 16, 1024, 0)])
+def test_segment_sum_pallas_compiles_for_v5e(one_chip, B, S, D):
+    from orleans_tpu.ops import segment_sum_pallas
+
+    vals = _struct((B, D) if D else (B,), jnp.float32, one_chip)
+    ids = _struct((B,), jnp.int32, one_chip)
+    compiled, text = _compile(
+        lambda v, s: segment_sum_pallas(v, s, S), vals, ids)
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= B * max(D, 1) * 4
+
+
+@pytest.mark.parametrize("B,S", [(32768, 5), (4096, 9)])
+def test_rank_by_dest_pallas_compiles_for_v5e(one_chip, B, S):
+    from orleans_tpu.ops import rank_by_dest
+
+    dest = _struct((B,), jnp.int32, one_chip)
+    _, text = _compile(
+        lambda d: rank_by_dest(d, S, use_pallas=True), dest)
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# the engine's kernels over a 1M-row table, one chip
+# ---------------------------------------------------------------------------
+
+def _presence_runtime(mesh, n_players):
+    from orleans_tpu.dispatch import VectorRuntime
+    from presence_tpu import PlayerVectorGrain
+
+    n = mesh.devices.size
+    rt = VectorRuntime(mesh=mesh, capacity_per_shard=-(-n_players // n))
+    tbl = rt.table(PlayerVectorGrain)
+    tbl.ensure_dense(n_players)
+    return rt, tbl, PlayerVectorGrain
+
+
+def _kernel_operands(tbl, B, sharding, rounds=0, rounds_sharding=None):
+    n = tbl.n_shards
+    state = {k: _struct(v.shape, v.dtype, sharding)
+             for k, v in tbl.state.items()}
+    lane = (n, B)
+    lead = (rounds,) if rounds else ()
+    args = {"pos": _struct((*lead, *lane, 2), jnp.float16,
+                           rounds_sharding or sharding),
+            "delta": _struct((*lead, *lane), jnp.int32,
+                             rounds_sharding or sharding)}
+    return (state, _struct(lane, jnp.int32, sharding),
+            _struct(lane, jnp.int32, sharding),
+            _struct(lane, jnp.bool_, sharding),
+            _struct(lane, jnp.bool_, sharding), args)
+
+
+@pytest.fixture(scope="module")
+def presence_1m():
+    from orleans_tpu.parallel import make_mesh
+    return _presence_runtime(make_mesh(1), N_PLAYERS)
+
+
+def test_served_tick_kernel_compiles_for_v5e(one_chip, presence_1m):
+    """The per-tick kernel as the served path launches it: operands
+    donated, a 1024-lane batch gathered from the 1M-row table."""
+    rt, tbl, Player = presence_1m
+    kern = rt._build_kernel(Player, "heartbeat", donate_operands=True)
+    compiled = kern.lower(*_kernel_operands(tbl, 1024, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 0          # the state really aliases
+    assert mem.argument_size_in_bytes < 1 << 30
+
+
+def test_bulk_tick_kernel_compiles_for_v5e(one_chip, presence_1m):
+    """call_batch over the whole population: the contiguous plan."""
+    rt, tbl, Player = presence_1m
+    kern = rt._build_kernel(Player, "heartbeat", contiguous=True)
+    compiled = kern.lower(
+        *_kernel_operands(tbl, tbl.capacity, one_chip)).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def test_scan_kernel_compiles_for_v5e(one_chip, presence_1m):
+    """call_batch_rounds: K=8 rounds scanned in one launch."""
+    rt, tbl, Player = presence_1m
+    kern = rt._build_kernel(Player, "heartbeat", scan_rounds=8,
+                            contiguous=True, scan_all_valid=False)
+    compiled = kern.lower(*_kernel_operands(
+        tbl, tbl.capacity, one_chip, rounds=8)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 0
+    assert mem.temp_size_in_bytes < 8 << 30     # fits the 16 GB chip
+
+
+# ---------------------------------------------------------------------------
+# four chips: the shard_map kernel and the exchange
+# ---------------------------------------------------------------------------
+
+def test_sharded_scan_and_exchange_compile_for_v5e_2x2(
+        four_chips, monkeypatch):
+    """The 1M-player table over the described 2x2: the scanned heartbeat
+    kernel under ``shard_map`` and ``build_exchange`` with the Pallas rank
+    inside it, each argument a NamedSharding on the described mesh."""
+    from orleans_tpu.parallel import SILO_AXIS, make_mesh
+    from orleans_tpu.parallel.transport import build_exchange
+
+    # the runtime allocates its table where it is built, and nothing can
+    # be copied to a described device: build on four CPU devices, then
+    # hand the kernel builder the described mesh
+    rt, tbl, Player = _presence_runtime(make_mesh(4), N_PLAYERS)
+    tbl.mesh = four_chips
+    shard = NamedSharding(four_chips, P(SILO_AXIS))
+    rounds = NamedSharding(four_chips, P(None, SILO_AXIS))
+    B = 1 << 18                                  # 250k lanes, bucketed
+    kern = rt._build_kernel(Player, "heartbeat", scan_rounds=8,
+                            contiguous=True)
+    compiled = kern.lower(*_kernel_operands(
+        tbl, B, shard, rounds=8, rounds_sharding=rounds)).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                      for v in tbl.state.values())
+    # per device: a quarter of the table, not all of it
+    assert mem.alias_size_in_bytes <= state_bytes // 4 + 4096
+
+    # ops.route asks jax.default_backend() whether the MXU rank kernel
+    # applies; the process's backend is the CPU, the program is for a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lanes, capacity = 32768, 10240
+    ex = build_exchange(four_chips, capacity=capacity)
+    compiled = ex.lower(
+        _struct((4, lanes), jnp.int32, shard),
+        _struct((4, lanes), jnp.bool_, shard),
+        {"__key__": _struct((4, lanes), jnp.int32, shard),
+         "score": _struct((4, lanes), jnp.int32, shard)}).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-to-all" in text
+    assert compiled.memory_analysis().output_size_in_bytes < 1 << 30

@@ -1,5 +1,8 @@
-"""Hot-op kernel tests (orleans_tpu.ops) — run on the CPU backend with
-Pallas in interpret mode; numerical references are plain numpy."""
+"""Hot-op kernel tests (orleans_tpu.ops) — run on the CPU backend; the
+Pallas kernels run in interpret mode because these tests ask for it
+(``interpret=True``), never by default. Numerical references are plain
+numpy. The compiled kernels are checked on the chip by ``chip_smoke.py``
+and compiled for it in ``tests/test_chip_compile.py``."""
 
 import numpy as np
 import pytest
@@ -79,10 +82,23 @@ def _np_segment_sum(values, ids, S):
 
 
 class TestSegmentSum:
-    def test_onehot_matches_numpy_1d(self):
+    @pytest.mark.parametrize("shape,ints", [
+        ((300,), False),
+        # integer values far above 256, totals below 2^24: the case a
+        # TPU's default (bfloat16-operand) matmul precision breaks —
+        # exact by contract, so compared exactly
+        ((300,), True), ((2048, 4), True)])
+    def test_onehot_matches_numpy_1d(self, shape, ints):
         rng = np.random.default_rng(1)
-        v = rng.normal(size=300).astype(np.float32)
-        ids = rng.integers(0, 40, size=300)
+        ids = rng.integers(0, 40, size=shape[0])
+        if ints:
+            v = rng.integers(257, 60_000, size=shape).astype(np.float32)
+            got = segment_sum_onehot(jnp.asarray(v), jnp.asarray(ids), 40)
+            np.testing.assert_array_equal(
+                np.asarray(got).astype(np.int64),
+                _np_segment_sum(v, ids, 40).astype(np.int64))
+            return
+        v = rng.normal(size=shape).astype(np.float32)
         got = segment_sum_onehot(jnp.asarray(v), jnp.asarray(ids), 40)
         np.testing.assert_allclose(got, _np_segment_sum(v, ids, 40),
                                    rtol=1e-5)
@@ -95,16 +111,34 @@ class TestSegmentSum:
         np.testing.assert_allclose(got, _np_segment_sum(v, ids, 8),
                                    rtol=1e-5)
 
-    @pytest.mark.parametrize("B,S,D", [(100, 17, 3), (1024, 300, 1),
-                                       (513, 8, 5)])
-    def test_pallas_matches_numpy(self, B, S, D):
+    @pytest.mark.parametrize("B,S,D,ints", [
+        (100, 17, 3, False), (1024, 300, 1, False), (513, 8, 5, False),
+        # integers >> 256 (see test_onehot_matches_numpy_1d): exact
+        (1024, 300, 4, True), (2048, 64, 128, True)])
+    def test_pallas_matches_numpy(self, B, S, D, ints):
         rng = np.random.default_rng(3)
-        v = rng.normal(size=(B, D)).astype(np.float32)
         ids = rng.integers(0, S, size=B)
+        if ints:
+            v = rng.integers(257, 60_000, size=(B, D)).astype(np.float32)
+        else:
+            v = rng.normal(size=(B, D)).astype(np.float32)
         got = segment_sum_pallas(jnp.asarray(v), jnp.asarray(ids), S,
                                  block_s=64, block_b=128, interpret=True)
-        np.testing.assert_allclose(got, _np_segment_sum(v, ids, S),
-                                   rtol=1e-4, atol=1e-4)
+        want = _np_segment_sum(v, ids, S)
+        if ints:
+            np.testing.assert_array_equal(
+                np.asarray(got).astype(np.int64), want.astype(np.int64))
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+    def test_pallas_default_never_interprets(self):
+        """``interpret`` is something a test asks for by name: the
+        default compiles for the backend it runs on, and the CPU backend
+        has no Pallas TPU lowering — so off-TPU the bare call refuses
+        instead of quietly interpreting."""
+        v, ids = jnp.ones((256, 128), jnp.float32), jnp.zeros(256, jnp.int32)
+        with pytest.raises(ValueError, match="[Ii]nterpret"):
+            segment_sum_pallas(v, ids, 8)
 
     def test_pallas_1d_values(self):
         v = np.ones(50, np.float32)
@@ -164,15 +198,28 @@ class TestRankDenseKeys:
 
 
 class TestPackByDest:
-    def test_matches_semantics(self):
+    @pytest.mark.parametrize("B,rank_kw", [
+        (200, {"use_pallas": False}),
+        # the default on the CPU: plain XLA (the sort rank from 512 lanes
+        # up), never the Pallas interpreter — see the monkeypatch below
+        (200, {}), (1024, {}),
+        (1024, {"use_pallas": True, "interpret": True})])
+    def test_matches_semantics(self, B, rank_kw, monkeypatch):
+        if not rank_kw:
+            import orleans_tpu.ops.route as route
+
+            def refuse(*a, **k):
+                raise AssertionError("the default path reached Pallas "
+                                     "off-TPU")
+            monkeypatch.setattr(route.pl, "pallas_call", refuse)
         rng = np.random.default_rng(6)
-        B, S, CAP = 200, 6, 16
+        S, CAP = 6, 16 * max(1, B // 200)
         d = rng.integers(-1, S + 1, size=B)  # includes out-of-range
         valid = rng.random(B) < 0.8
         payload = {"x": rng.normal(size=(B, 2)).astype(np.float32)}
         out, ovalid, drops = pack_by_dest(
             jnp.asarray(d), jnp.asarray(valid),
-            {"x": jnp.asarray(payload["x"])}, S, CAP, use_pallas=False)
+            {"x": jnp.asarray(payload["x"])}, S, CAP, **rank_kw)
         ovalid = np.asarray(ovalid)
         outx = np.asarray(out["x"])
         # every valid in-range message appears exactly once, in dest order

@@ -1,34 +1,20 @@
 """Loose CI performance floors: a regression on a hot path cannot land
-silently (VERDICT r3 ask #8; the reference's BVT gating discipline,
+silently (the reference's BVT gating discipline,
 test/Benchmarks/Ping/PingBenchmark.cs:35-46).
 
-Floors are HALF-BAND values — deliberately far below the documented
-medians (RESULTS_r3/r4) so single-shared-core noise can't flake them,
-while a real regression (2x slowdown) still trips. Each check takes the
+Floors are HALF-BAND values — deliberately far below the medians the
+CPU sandbox showed when they were set, so noise can't flake them, while
+a real regression (2x slowdown) still trips. They are CPU-sandbox guards,
+never statements about the chip. Each check takes the
 best of two short runs for the same reason. The >=1M events/sec stream
 floor lives in test_vector_streams.py."""
-
-import asyncio
 
 import pytest
 
 from benchmarks import ping, ping_socket, transactions
 
-# The documented bands were measured with eager turn execution
-# (asyncio.eager_task_factory, Python >= 3.12): every non-suspending turn
-# skips an event-loop round trip. On older interpreters that machinery
-# does not exist and the whole hot path runs ~2-4x slower for structural
-# reasons, so the ABSOLUTE floors cannot distinguish a regression from the
-# missing-feature baseline — skip rather than fail on noise. (Applied
-# per-test rather than module-wide: the hot-lane margin floor below is a
-# same-process A/B ratio, valid on any interpreter.)
-needs_eager = pytest.mark.skipif(
-    not hasattr(asyncio, "eager_task_factory"),
-    reason="perf floors calibrated with asyncio.eager_task_factory "
-           "(Python >= 3.12); this interpreter lacks it")
-
-# floor, documented band (single shared core, JAX_PLATFORMS=cpu)
-TXN_FLOOR = 2_500          # band 3.7-4.7k @ c=32 (RESULTS_r4, 5 runs)
+# floor, band when set (CPU sandbox, JAX_PLATFORMS=cpu, eager turns)
+TXN_FLOOR = 2_500          # band 3.7-4.7k @ c=32
 HOST_PING_FLOOR = 30_000   # band ~38-45k (r5: catalog-first addressing);
 # kept at the r4 value: floors are half-band-ish guards far below the
 # documented medians, and the single shared core swings ±10% — the r5
@@ -45,7 +31,6 @@ async def _floor_check(fn, floor, label):
     assert v >= floor, f"{label} {v:.0f}/s below floor {floor}"
 
 
-@needs_eager
 async def test_floor_transactions_c32():
     async def once():
         r = await transactions.run(n_accounts=32, concurrency=32,
@@ -54,7 +39,6 @@ async def test_floor_transactions_c32():
     await _floor_check(once, TXN_FLOOR, "transactions")
 
 
-@needs_eager
 async def test_floor_host_ping():
     async def once():
         r = await ping.bench_host_tier(n_grains=256, concurrency=100,
@@ -63,7 +47,6 @@ async def test_floor_host_ping():
     await _floor_check(once, HOST_PING_FLOOR, "host ping")
 
 
-@needs_eager
 async def test_floor_trace_overhead():
     """trace_overhead check: with tracing installed but sampled at 0 the
     hot path pays only a None/attr check per site — ping throughput must
@@ -84,7 +67,6 @@ async def test_floor_trace_overhead():
         f"{base:.0f}/s — tracing is taxing the disabled hot path"
 
 
-@needs_eager
 async def test_floor_socket_gateway_and_cross_silo(tmp_path):
     gw_best = cs_best = 0.0
     for attempt in range(2):
@@ -104,7 +86,7 @@ async def test_floor_socket_gateway_and_cross_silo(tmp_path):
 
 
 # Tail-record tracing over untraced: a same-process ratio (interpreter
-# speed cancels out, so no needs_eager). The acceptance budget is "within
+# speed cancels out). The acceptance budget is "within
 # 1.5x of the trace_overhead floor": that floor allows traced >= 0.7 *
 # untraced, so tail-record must stay >= 0.7 / 1.5 ≈ 0.467 of untraced —
 # every ping here pays span recording AND the pending-buffer/decide/drop
@@ -128,7 +110,7 @@ async def test_floor_trace_tail_overhead():
 
 
 # Metrics pipeline over a bare silo: a same-process ratio (interpreter
-# speed cancels out, so no needs_eager). The metered side pays the ingest
+# speed cancels out). The metered side pays the ingest
 # stage instrumentation on every message (arrival stamp + queue-wait
 # observe) plus the sampler loop — measured ~1-3% on this box, far inside
 # the 0.85 acceptance floor; the guard trips if instrumentation ever
@@ -164,7 +146,7 @@ async def test_floor_metrics_overhead():
 
 
 # Loop profiler over a bare silo: a same-process ratio (interpreter
-# speed cancels out, so no needs_eager). The profiled side pays the
+# speed cancels out). The profiled side pays the
 # per-callback interposition (one scheduled bound method — no closure
 # alloc — two clock reads, a contextvar get, two dict upserts) plus
 # per-turn enter/exit — measured ~0.88-0.91 on this box; the 0.85 floor
@@ -644,8 +626,8 @@ async def test_floor_multiproc_observability():
         f"(floor {MULTIPROC_OBS_OVERHEAD_FLOOR}x on a multi-core runner)"
 
 
-# SLO monitor over the metrics pipeline: a same-process ratio (no
-# needs_eager). Both sides pay identical per-message metrics stamps —
+# SLO monitor over the metrics pipeline: a same-process ratio. Both
+# sides pay identical per-message metrics stamps —
 # the monitor adds zero hot-path instrumentation by design (evaluation
 # rides interval-diffed registry snapshots at 10Hz) — so the ratio
 # isolates the evaluation loop's own tax; the floor trips if evaluation
@@ -676,8 +658,8 @@ async def test_floor_slo_overhead():
 
 
 # Bulk collectives vs message-per-edge (ISSUE 13): a same-process ratio
-# on IDENTICAL edge traffic at fan-out >= 64 (interpreter speed cancels,
-# no needs_eager; both sides get one full warmup drive, so the ratio is
+# on IDENTICAL edge traffic at fan-out >= 64 (interpreter speed cancels;
+# both sides get one full warmup drive, so the ratio is
 # steady-state dispatch). Measured ~10-13x in-proc (BENCH_r13); 3x is
 # the acceptance criterion with a wide noise band — a regression that
 # turns broadcast_actors back into per-edge dispatch (a lost kernel

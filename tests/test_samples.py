@@ -116,3 +116,19 @@ async def test_bank_sample_end_to_end():
     cancellable sweep, batch audit ledger — run the sample's own main."""
     import bank
     await bank.main()
+
+
+def test_chip_smoke_refuses_without_a_tpu():
+    """``chip_smoke.py`` is the proof that a run was on the chip: on a
+    machine where jax finds no TPU (these tests pin the CPU) it must exit
+    non-zero and never print its verdict line."""
+    import subprocess
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    r = subprocess.run(
+        [sys.executable, os.path.join(root, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    assert '"ok": true' not in r.stdout
+    assert "no TPU" in r.stderr
