@@ -299,10 +299,6 @@ class TracingOptions:
     otlp_endpoint: str | None = None
     otlp_batch_size: int = 64
     otlp_flush_interval: float = 0.5
-    # ship OTLP bodies as protobuf wire bytes (application/x-protobuf)
-    # instead of the JSON mapping; requires google.protobuf importable,
-    # else the sink warns and keeps JSON
-    otlp_protobuf: bool = False
 
     def validate(self) -> None:
         _positive(self, "buffer_size", "tail_window", "tail_leg_ttl",
@@ -344,9 +340,6 @@ class MetricsOptions:
     port: int | None = None
     otlp_endpoint: str | None = None
     otlp_period: float = 5.0
-    # protobuf wire encoding for the metrics push (same gate/fallback as
-    # the tracing sink's otlp_protobuf)
-    otlp_protobuf: bool = False
 
     def validate(self) -> None:
         _positive(self, "sample_period", "window", "otlp_period")
@@ -557,14 +550,12 @@ _FLAT_MAP = {
     "trace_otlp_endpoint": (TracingOptions, "otlp_endpoint"),
     "trace_otlp_batch_size": (TracingOptions, "otlp_batch_size"),
     "trace_otlp_flush_interval": (TracingOptions, "otlp_flush_interval"),
-    "trace_otlp_protobuf": (TracingOptions, "otlp_protobuf"),
     "metrics_enabled": (MetricsOptions, "enabled"),
     "metrics_sample_period": (MetricsOptions, "sample_period"),
     "metrics_window": (MetricsOptions, "window"),
     "metrics_port": (MetricsOptions, "port"),
     "metrics_otlp_endpoint": (MetricsOptions, "otlp_endpoint"),
     "metrics_otlp_period": (MetricsOptions, "otlp_period"),
-    "metrics_otlp_protobuf": (MetricsOptions, "otlp_protobuf"),
     "slo_enabled": (SloOptions, "enabled"),
     "slo_period": (SloOptions, "period"),
     "slo_fast_window": (SloOptions, "fast_window"),

@@ -41,15 +41,17 @@ from jax.sharding import PartitionSpec as P
 from ..compile_cache import ensure_compile_cache
 from ..core.ids import GrainId
 from ..observability.stats import INGEST_STATS as _INGEST
+from ..observability.stats import NO_SPAN, StageSpan
+from ..observability.stats import observe_or_defer as _emit
 from ..parallel.mesh import SILO_AXIS, make_mesh
 from .table import ShardedActorTable
 from .vector_grain import ActorMethod, VectorGrain
 
 _QUEUE_WAIT = _INGEST["queue_wait"]
-_STAGING = _INGEST["staging"]
-_TRANSFER = _INGEST["transfer"]
 _TICK = _INGEST["tick"]
 _MESSAGES = _INGEST["messages"]
+_WORKER_QUEUE = "engine.worker_queue.seconds"
+_COMPLETE_HOP = "engine.complete_hop.seconds"
 # worker-side ledger stamp (cost attribution, observability.ledger): the
 # payload rides the job's deferred-stats list and replays loop-side in
 # _complete_job — the CostLedger is loop-confined like the registries
@@ -64,19 +66,6 @@ MIN_BUCKET = 8
 
 def _bucket(n: int) -> int:
     return max(MIN_BUCKET, 1 << max(0, (n - 1).bit_length()))
-
-
-def _emit(sink, st, key: str, value: float) -> None:
-    """One stage observation: direct on the loop (inline tick), deferred
-    into ``sink`` on the worker (StatsRegistry/Histogram are not
-    thread-safe — concurrent += loses updates and a first-tick key
-    insert can break the sampler's snapshot iteration — so worker-side
-    measurements REPLAY loop-side in _complete_job; the timing itself
-    is still stamped off-loop)."""
-    if sink is not None:
-        sink.append((key, value))
-    else:
-        st.observe(key, value)
 
 
 async def join_poll(reduce_once, need: int, timeout: float | None,
@@ -263,12 +252,15 @@ class _TickJob:
     resolve; ``stats`` collects the worker's deferred stage observations
     — ``(key, value)`` with None = shed-trend note and _MESSAGES =
     counter increment — replayed loop-side (the registries are
-    loop-confined)."""
+    loop-confined). ``tick`` is ``rt.ticks`` at the claim (the unit of
+    work the stage spans name); ``t_hand`` is the perf_counter stamp of
+    the last hand-off between threads (submit, then completion), 0.0
+    with metrics off."""
 
     __slots__ = ("cls", "method", "ready", "trace", "per_shard", "span",
-                 "stats")
+                 "stats", "tick", "t_hand")
 
-    def __init__(self, cls, method, ready, trace=False):
+    def __init__(self, cls, method, ready, trace=False, tick=0):
         self.cls = cls
         self.method = method
         self.ready = ready
@@ -276,6 +268,8 @@ class _TickJob:
         self.per_shard = None
         self.span = None
         self.stats: list = []
+        self.tick = tick
+        self.t_hand = 0.0
 
 
 class VectorActorRef:
@@ -342,16 +336,16 @@ class VectorRuntime:
         # device batch starts feed it beside the INGEST queue_wait stage
         self.shed_trend = None
         # distributed-tracing collector (observability.tracing), set by
-        # dispatch.hosting when the owning silo traces: each batch records
-        # a "device_tick" span AND opens a jax.profiler.TraceAnnotation so
-        # XLA kernels nest under the logical tick on a profiler capture
+        # dispatch.hosting when the owning silo traces: each sampled batch
+        # records a "device_tick" span
         self.tracer = None
         # ingest stage metrics (observability.stats.INGEST_STATS), set by
         # dispatch.hosting when the owning silo has metrics enabled: each
         # message batch splits into staging (pending -> host arrays),
         # transfer (host -> device operands), and tick (kernel dispatch +
         # device execution + host materialize) histograms — the device
-        # half of the socket->tick ingest attribution
+        # half of the socket->tick ingest attribution — each a StageSpan,
+        # so the same intervals lie on a jax.profiler capture
         self.stats = None
         # cost-attribution ledger (observability.ledger), set by
         # dispatch.hosting when the owning silo runs ledger_enabled: the
@@ -790,17 +784,33 @@ class VectorRuntime:
             if job is None:
                 return
             host = err = None
+            st = self.stats
+            wait = None
             try:
+                if st is not None:
+                    # the job waited while this thread ran the previous
+                    # tick; from here it waits for whoever holds the
+                    # fence (a write-behind gather, a snapshot)
+                    wait = StageSpan(st, "engine.fence_wait", job.stats,
+                                     tick=job.tick)
+                    job.stats.append((_WORKER_QUEUE, wait.t0 - job.t_hand))
                 # the fence is held for the WHOLE batch: donated tbl.state
                 # and donated staging operands are in flight until the
                 # sync at the end of _execute_batch proves the uploads
                 # completed
                 with self._fence:
+                    if wait is not None:
+                        wait.close()
                     job.per_shard, host, job.span = self._execute_batch(
                         job.cls, job.method, job.ready, None,
-                        trace_roll=job.trace, sink=job.stats)
+                        trace_roll=job.trace, sink=job.stats,
+                        tick=job.tick)
             except BaseException as e:  # noqa: BLE001 — futures fail loop-side
                 err = e
+            if st is not None:
+                if err is not None:
+                    StageSpan.unwind()
+                job.t_hand = time.perf_counter()
             try:
                 self._loop.call_soon_threadsafe(
                     self._complete_job, job, host, err,
@@ -818,6 +828,8 @@ class VectorRuntime:
         ctr = self._inflight_keys.setdefault(job.cls, {})
         for p in job.ready:
             ctr[p.key_hash] = ctr.get(p.key_hash, 0) + 1
+        if self.stats is not None:
+            job.t_hand = time.perf_counter()
         self._worker_q.put(job)
 
     def _record_tick_span(self, span, ready: list, error: bool = False
@@ -878,12 +890,16 @@ class VectorRuntime:
         key fence and re-arm the quiescence event. A loop-side failure
         here fails the batch's futures like the inline path's tick
         except does; it never leaves callers hanging."""
+        st = self.stats
         try:
+            if st is not None and job.t_hand:
+                # the hop: this callback waited behind whatever the loop
+                # ran since the worker posted it
+                st.observe(_COMPLETE_HOP, time.perf_counter() - job.t_hand)
             # replay the worker's deferred observations into the loop-
             # confined registries (timings were stamped off-loop); on an
             # errored batch the list holds whatever stages completed
             if job.stats:
-                st = self.stats
                 trend = self.shed_trend
                 for key, val in job.stats:
                     if key is None:
@@ -911,7 +927,8 @@ class VectorRuntime:
                         p.future.set_exception(err)
             else:
                 self._record_tick_span(job.span, job.ready)
-                self._resolve_batch(job.ready, job.per_shard, host)
+                self._resolve_batch(job.ready, job.per_shard, host,
+                                    job.tick)
         except BaseException as e2:  # noqa: BLE001 — fail futures, not loop
             log.exception("vector tick completion failed for %s.%s",
                           job.cls.__name__, job.method)
@@ -960,20 +977,25 @@ class VectorRuntime:
         work, self.pending = self.pending, {}
         offloop = self.offloop_tick
         tracer = self.tracer
+        st = self.stats
+        tick = self.ticks
         for (cls, method), items in work.items():
-            ready = self._claim(cls, method, items)
-            if not ready:
-                continue
-            # device-tick sampling rolls HERE (loop-side) on both paths:
-            # the worker must not touch the collector. A batch carrying
-            # request trace contexts (threaded over the cross-process
-            # staging ring or the vector bridge) records regardless of
-            # the roll: header presence IS the upstream sampled decision
-            roll = tracer is not None and (
-                tracer.sample()
-                or any(p.trace is not None for p in ready))
-            if offloop:
-                self._submit_job(_TickJob(cls, method, ready, roll))
+            with StageSpan(st, "engine.claim", tick=tick) \
+                    if st is not None else NO_SPAN:
+                ready = self._claim(cls, method, items)
+                # device-tick sampling rolls HERE (loop-side) on both
+                # paths: the worker must not touch the collector. A batch
+                # carrying request trace contexts (threaded over the
+                # cross-process staging ring or the vector bridge) records
+                # regardless of the roll: header presence IS the upstream
+                # sampled decision
+                roll = bool(ready) and tracer is not None and (
+                    tracer.sample()
+                    or any(p.trace is not None for p in ready))
+                if ready and offloop:
+                    self._submit_job(_TickJob(cls, method, ready, roll,
+                                              tick))
+            if not ready or offloop:
                 continue
             try:
                 self._run_batch(cls, method, ready, trace_roll=roll)
@@ -1015,23 +1037,33 @@ class VectorRuntime:
         callable from any thread, and a worker batch may still be in
         flight when offloop_tick is flipped off (restart-in-process).
         Uncontended reentrant acquire is ~100ns against a multi-ms tick."""
-        with self._fence:
-            per_shard, host, span = self._execute_batch(
-                cls, method, ready, self.loop_prof, trace_roll=trace_roll)
+        try:
+            with self._fence:
+                per_shard, host, span = self._execute_batch(
+                    cls, method, ready, self.loop_prof,
+                    trace_roll=trace_roll, tick=self.ticks)
+        except BaseException:
+            if self.stats is not None:
+                StageSpan.unwind()  # a loop callback starts with none open
+            raise
         self._record_tick_span(span, ready)
-        self._resolve_batch(ready, per_shard, host)
+        self._resolve_batch(ready, per_shard, host, self.ticks)
 
     def _resolve_batch(self, ready: list[_Pending], per_shard,
-                       host) -> None:
-        for s, ps in enumerate(per_shard):
-            for i, p in enumerate(ps):
-                if p.future is not None and not p.future.done():
-                    p.future.set_result(jax.tree_util.tree_map(
-                        lambda a: a[s, i], host))
+                       host, tick: int = 0) -> None:
+        st = self.stats
+        with StageSpan(st, "engine.resolve", tick=tick) \
+                if st is not None else NO_SPAN:
+            for s, ps in enumerate(per_shard):
+                for i, p in enumerate(ps):
+                    if p.future is not None and not p.future.done():
+                        p.future.set_result(jax.tree_util.tree_map(
+                            lambda a: a[s, i], host))
         self.messages_processed += len(ready)
 
     def _execute_batch(self, cls: type, method: str, ready: list[_Pending],
-                       lp, trace_roll: bool = False, sink: list | None = None):
+                       lp, trace_roll: bool = False, sink: list | None = None,
+                       tick: int = 0):
         """Staging fill → operand upload → kernel dispatch → host
         materialize sync for one claimed, conflict-free batch. Runs on
         the loop (inline path; ``lp`` is the loop profiler, ``sink``
@@ -1041,6 +1073,10 @@ class VectorRuntime:
         ``sink`` = the job's deferred-stats list — timings are STAMPED
         here off-loop but recorded loop-side in _complete_job, because
         StatsRegistry/Histogram/QueueWaitTrend are not thread-safe).
+        With metrics on the batch is four contiguous stage spans of unit
+        ``tick`` — ingest.staging, ingest.transfer, ingest.tick.dispatch,
+        ingest.tick.sync — the last two tiling ingest.tick; a raising
+        batch leaves its open span to the caller's ``StageSpan.unwind``.
         Returns ``(per_shard, host_results, span_timing)`` where
         ``span_timing`` is ``(name, wall_start, duration)`` for a sampled
         tick (recorded by the caller on the loop) or None."""
@@ -1051,9 +1087,10 @@ class VectorRuntime:
             # names this batch in the flight recorder's top-K and is only
             # string-joined on admission — every tick pays no format
             lp.set_category("tick_staging", ("tick", cls.__name__, method))
-        t_stage = now_mono = batch_wall = 0.0
+        now_mono = batch_wall = 0.0
+        stage = None
         if st is not None:
-            t_stage = time.perf_counter()
+            stage = StageSpan(st, "ingest.staging", sink, tick=tick)
         if st is not None or self.shed_trend is not None or trace_roll:
             now_mono = time.monotonic()  # queue-wait ends at batch start
             # (the shed trend needs the stamp even with metrics off —
@@ -1104,10 +1141,10 @@ class VectorRuntime:
             lp.set_category("tick_transfer")
         if inferred:
             m.args_schema = schema  # needed by the kernel builder
-        t_xfer = t_tick = 0.0
+        t_tick = 0.0
         if st is not None:
-            t_xfer = time.perf_counter()
-            _emit(sink, st, _STAGING, t_xfer - t_stage)
+            stage.close()
+            stage = StageSpan(st, "ingest.transfer", sink, tick=tick)
             # per-item queue wait: enqueue (rt.call) -> this batch start —
             # tick scheduling plus any conflict-deferred full ticks; items
             # enqueued by non-call paths carry no stamp and are skipped
@@ -1139,23 +1176,23 @@ class VectorRuntime:
                 jnp.asarray(fresh), jnp.asarray(valid),
                 {k: jnp.asarray(v) for k, v in args_stacked.items()})
             if st is not None:
-                t_tick = time.perf_counter()
-                _emit(sink, st, _TRANSFER, t_tick - t_xfer)
+                stage.close()
+                # the stage span bridges host tracing to the XLA
+                # timeline: on a jax.profiler capture this tick's kernel
+                # launch nests under otpu:ingest.tick.dispatch
+                stage = StageSpan(st, "ingest.tick.dispatch", sink,
+                                 tick=tick)
+                t_tick = stage.t0
             elif led is not None:
                 t_tick = time.perf_counter()  # ledger-only tick wall start
             if trace_roll:
                 span_name = f"tick {cls.__name__}.{method}"
                 span_start = time.time()
                 t_span0 = time.perf_counter()
-                # the TraceAnnotation bridges host tracing to the XLA
-                # timeline: on a jax.profiler capture, this tick's
-                # kernels nest under a span named like the logical tick
-                # span. Gated on the SAMPLED tick (rolled loop-side) so
-                # unsampled/untraced silos pay nothing per batch flush.
-                with jax.profiler.TraceAnnotation(span_name):
-                    new_state, results = kernel(*kernel_args)
-            else:
-                new_state, results = kernel(*kernel_args)
+            new_state, results = kernel(*kernel_args)
+            if st is not None:
+                stage.close()
+                stage = StageSpan(st, "ingest.tick.sync", sink, tick=tick)
         except BaseException as e:
             if inferred:
                 m.args_schema = None  # do not poison the class schema
@@ -1205,7 +1242,7 @@ class VectorRuntime:
             # tick closes AFTER the host transfer for the same reason the
             # span timing does: jax dispatch is async, and the np.asarray
             # sync is where device execution is actually paid
-            _emit(sink, st, _TICK, time.perf_counter() - t_tick)
+            _emit(sink, st, _TICK, stage.t0 + stage.close() - t_tick)
             if sink is not None:
                 sink.append((_MESSAGES, len(ready)))
             else:

@@ -173,12 +173,24 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
         state = {"task": None}
 
         async def flush_all(strict: bool = False) -> int:
+            from ..observability.stats import (COUNT_BOUNDS, FLUSH_STATS,
+                                               StageSpan)
+
             n = 0
             first_error: BaseException | None = None
+            st = silo.ingest_stats
+            span = None
             for cls in grain_classes:
                 keys = silo.vector.drain_dirty(cls)
                 if not len(keys):
                     continue
+                if st is not None and span is None:
+                    # one pass that found dirty rows, held across the
+                    # per-key writes: wall time of the pass (a cancelled
+                    # pass records nothing; stop() runs it again)
+                    span = StageSpan(
+                        st, "flush", nest=False,
+                        flush=st.get(FLUSH_STATS["flushes"]) + 1)
                 try:
                     n += await silo.vector_bridges[cls].flush(
                         keys, strict=strict)
@@ -197,8 +209,13 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
                     # abandon the other classes' shutdown drain
                     silo.vector._mark_dirty(cls, keys)
                     first_error = first_error or e
+            if span is not None:
+                span.close()
+                st.increment(FLUSH_STATS["flushes"])
+                st.histogram_with(FLUSH_STATS["rows"],
+                                  COUNT_BOUNDS).observe(n)
             if n:
-                silo.stats.increment("vector.storage.flushed", n)
+                silo.stats.increment(FLUSH_STATS["flushed"], n)
             if first_error is not None:
                 raise first_error
             return n

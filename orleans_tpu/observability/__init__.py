@@ -19,12 +19,9 @@ from .profiling import (  # noqa: F401
     LOOP_CATEGORIES,
     LoopProfiler,
     Profiler,
-    StepTimer,
-    annotate,
     install_loop_profiler,
     loop_profiler,
     mark_loop_category,
-    traced,
     uninstall_loop_profiler,
 )
 from .slo import (  # noqa: F401
@@ -39,6 +36,7 @@ from .stats import (  # noqa: F401
     SLO_STATS,
     CallSiteStats,
     Histogram,
+    StageSpan,
     StatsRegistry,
 )
 from .tracing import (  # noqa: F401

@@ -19,37 +19,25 @@ collector degrades to counted drops; it can never stall or break the
 runtime that feeds it.
 
 Device-side XLA kernel timelines come from ``jax.profiler`` capture
-(:mod:`orleans_tpu.observability.profiling`); the dispatch engine opens a
-``TraceAnnotation`` per tick named like the logical tick span, so the two
-captures correlate by name when viewed together.
+(:mod:`orleans_tpu.observability.profiling`); a metrics-enabled silo's
+stage spans (``observability.stats.StageSpan``) annotate that capture with
+``otpu:<stage>`` events carrying the tick number.
 """
 
 from __future__ import annotations
 
 import asyncio
-import importlib.util
 import json
 import logging
-import struct
 import urllib.error
 import urllib.request
 from collections import deque
 
 __all__ = ["chrome_trace_events", "write_chrome_trace",
            "OtlpSink", "OtlpMetricsSink", "spans_to_otlp",
-           "snapshots_to_otlp_metrics", "otlp_trace_protobuf",
-           "otlp_metrics_protobuf"]
+           "snapshots_to_otlp_metrics"]
 
 log = logging.getLogger("orleans.export")
-
-# The binary OTLP encoding is OPT-IN (encoding="protobuf") and gated on
-# the collector-side schema actually being present in the environment:
-# the wire bytes below are hand-assembled (varint + length-delimited
-# framing over the same dicts the JSON mapping ships — no generated
-# stubs, no import of the package itself), but advertising
-# application/x-protobuf only makes sense where the OTel proto toolchain
-# exists, and the gate keeps JSON the universal default elsewhere.
-_HAS_PROTOBUF = importlib.util.find_spec("google.protobuf") is not None
 
 
 def chrome_trace_events(spans, loop_profiles: dict | None = None
@@ -283,187 +271,6 @@ def spans_to_otlp(span_dicts, service_name: str = "orleans_tpu") -> dict:
     }]}
 
 
-# ---------------------------------------------------------------------------
-# OTLP protobuf wire encoding (opt-in; encoding="protobuf")
-# ---------------------------------------------------------------------------
-# Hand-assembled protobuf wire format over the SAME dicts the JSON
-# mapping produces (spans_to_otlp / snapshots_to_otlp_metrics output):
-# proto-JSON field names map 1:1 onto opentelemetry-proto field numbers,
-# so one canonical builder feeds both encodings and they cannot drift.
-# Only the shapes we emit are encoded (string/bool/int/double attrs,
-# spans with events/links/status, gauge/sum/histogram metrics).
-
-def _pb_varint(n: int) -> bytes:
-    n &= 0xFFFFFFFFFFFFFFFF  # two's-complement int64, like the proto wire
-    out = bytearray()
-    while True:
-        b = n & 0x7F
-        n >>= 7
-        if n:
-            out.append(b | 0x80)
-        else:
-            out.append(b)
-            return bytes(out)
-
-
-def _pb_key(field: int, wire: int) -> bytes:
-    return _pb_varint((field << 3) | wire)
-
-
-def _pb_len(field: int, payload: bytes) -> bytes:
-    return _pb_key(field, 2) + _pb_varint(len(payload)) + payload
-
-
-def _pb_str(field: int, s) -> bytes:
-    return _pb_len(field, s.encode() if isinstance(s, str) else bytes(s))
-
-
-def _pb_u64(field: int, n) -> bytes:
-    return _pb_key(field, 0) + _pb_varint(int(n))
-
-
-def _pb_fixed64(field: int, n) -> bytes:
-    return _pb_key(field, 1) + struct.pack("<Q",
-                                           int(n) & 0xFFFFFFFFFFFFFFFF)
-
-
-def _pb_sfixed64(field: int, n) -> bytes:
-    return _pb_key(field, 1) + struct.pack("<q", int(n))
-
-
-def _pb_double(field: int, v) -> bytes:
-    return _pb_key(field, 1) + struct.pack("<d", float(v))
-
-
-def _pb_anyvalue(v: dict) -> bytes:
-    if "stringValue" in v:
-        return _pb_str(1, v["stringValue"])
-    if "boolValue" in v:
-        return _pb_u64(2, 1 if v["boolValue"] else 0)
-    if "intValue" in v:
-        return _pb_u64(3, int(v["intValue"]))
-    if "doubleValue" in v:
-        return _pb_double(4, v["doubleValue"])
-    return _pb_str(1, str(v))
-
-
-def _pb_attrs(field: int, attrs) -> bytes:
-    return b"".join(
-        _pb_len(field, _pb_str(1, kv["key"]) +
-                _pb_len(2, _pb_anyvalue(kv["value"])))
-        for kv in attrs or ())
-
-
-def _pb_span(s: dict) -> bytes:
-    out = _pb_str(1, bytes.fromhex(s["traceId"]))
-    out += _pb_str(2, bytes.fromhex(s["spanId"]))
-    if s.get("parentSpanId"):
-        out += _pb_str(4, bytes.fromhex(s["parentSpanId"]))
-    out += _pb_str(5, s["name"])
-    out += _pb_u64(6, s.get("kind", 1))
-    out += _pb_fixed64(7, int(s["startTimeUnixNano"]))
-    out += _pb_fixed64(8, int(s["endTimeUnixNano"]))
-    out += _pb_attrs(9, s.get("attributes"))
-    for ev in s.get("events") or ():
-        out += _pb_len(11, _pb_fixed64(1, int(ev["timeUnixNano"])) +
-                       _pb_str(2, ev["name"]) +
-                       _pb_attrs(3, ev.get("attributes")))
-    for ln in s.get("links") or ():
-        out += _pb_len(13, _pb_str(1, bytes.fromhex(ln["traceId"])) +
-                       _pb_str(2, bytes.fromhex(ln["spanId"])))
-    status = s.get("status")
-    if status:
-        body = b""
-        if status.get("message"):
-            body += _pb_str(2, status["message"])
-        if status.get("code"):
-            body += _pb_u64(3, status["code"])
-        out += _pb_len(15, body)
-    return out
-
-
-def otlp_trace_protobuf(req: dict) -> bytes:
-    """An ``ExportTraceServiceRequest`` JSON-mapping dict
-    (:func:`spans_to_otlp` output) as protobuf wire bytes."""
-    out = b""
-    for rs in req.get("resourceSpans", ()):
-        body = _pb_len(1, _pb_attrs(
-            1, rs.get("resource", {}).get("attributes")))
-        for ss in rs.get("scopeSpans", ()):
-            sbody = _pb_len(1, _pb_str(1, ss.get("scope",
-                                                 {}).get("name", "")))
-            for sp in ss.get("spans", ()):
-                sbody += _pb_len(2, _pb_span(sp))
-            body += _pb_len(2, sbody)
-        out += _pb_len(1, body)
-    return out
-
-
-def _pb_number_point(dp: dict) -> bytes:
-    out = _pb_fixed64(3, int(dp["timeUnixNano"]))
-    if "asDouble" in dp:
-        out += _pb_double(4, dp["asDouble"])
-    if "asInt" in dp:
-        out += _pb_sfixed64(6, int(dp["asInt"]))
-    out += _pb_attrs(7, dp.get("attributes"))
-    return out
-
-
-def _pb_hist_point(dp: dict) -> bytes:
-    out = _pb_fixed64(3, int(dp["timeUnixNano"]))
-    out += _pb_fixed64(4, int(dp["count"]))
-    out += _pb_double(5, dp.get("sum", 0.0))
-    counts = dp.get("bucketCounts") or ()
-    if counts:  # packed repeated fixed64
-        out += _pb_len(6, b"".join(struct.pack("<Q", int(c))
-                                   for c in counts))
-    bounds = dp.get("explicitBounds") or ()
-    if bounds:  # packed repeated double
-        out += _pb_len(7, b"".join(struct.pack("<d", float(b))
-                                   for b in bounds))
-    out += _pb_attrs(9, dp.get("attributes"))
-    return out
-
-
-def _pb_metric(m: dict) -> bytes:
-    out = _pb_str(1, m["name"])
-    if "gauge" in m:
-        out += _pb_len(5, b"".join(
-            _pb_len(1, _pb_number_point(dp))
-            for dp in m["gauge"]["dataPoints"]))
-    elif "sum" in m:
-        s = m["sum"]
-        body = b"".join(_pb_len(1, _pb_number_point(dp))
-                        for dp in s["dataPoints"])
-        body += _pb_u64(2, s.get("aggregationTemporality", 2))
-        body += _pb_u64(3, 1 if s.get("isMonotonic") else 0)
-        out += _pb_len(7, body)
-    elif "histogram" in m:
-        h = m["histogram"]
-        body = b"".join(_pb_len(1, _pb_hist_point(dp))
-                        for dp in h["dataPoints"])
-        body += _pb_u64(2, h.get("aggregationTemporality", 2))
-        out += _pb_len(9, body)
-    return out
-
-
-def otlp_metrics_protobuf(req: dict) -> bytes:
-    """An ``ExportMetricsServiceRequest`` JSON-mapping dict
-    (:func:`snapshots_to_otlp_metrics` output) as protobuf wire bytes."""
-    out = b""
-    for rm in req.get("resourceMetrics", ()):
-        body = _pb_len(1, _pb_attrs(
-            1, rm.get("resource", {}).get("attributes")))
-        for sm in rm.get("scopeMetrics", ()):
-            sbody = _pb_len(1, _pb_str(1, sm.get("scope",
-                                                 {}).get("name", "")))
-            for m in sm.get("metrics", ()):
-                sbody += _pb_len(2, _pb_metric(m))
-            body += _pb_len(2, sbody)
-        out += _pb_len(1, body)
-    return out
-
-
 class _OtlpHttpSink:
     """Shared OTLP/HTTP export machinery with the OTel-collector queue
     discipline: bounded buffer (overflow drops oldest + counts), batches
@@ -482,21 +289,7 @@ class _OtlpHttpSink:
     def __init__(self, endpoint: str, *, service_name: str = "orleans_tpu",
                  batch_size: int = 64, flush_interval: float = 0.5,
                  max_queue: int = 2048, max_retries: int = 2,
-                 retry_backoff: float = 0.05, timeout: float = 2.0,
-                 encoding: str = "json"):
-        if encoding not in ("json", "protobuf"):
-            raise ValueError(f"OTLP encoding must be 'json' or 'protobuf', "
-                             f"got {encoding!r}")
-        if encoding == "protobuf" and not _HAS_PROTOBUF:
-            # degrade, don't die: the binary encoding is an optimization,
-            # and a silo must come up identically in a slimmer image
-            log.warning("OTLP protobuf encoding requested but "
-                        "google.protobuf is not importable; using JSON")
-            encoding = "json"
-        self.encoding = encoding
-        self.content_type = ("application/x-protobuf"
-                             if encoding == "protobuf"
-                             else "application/json")
+                 retry_backoff: float = 0.05, timeout: float = 2.0):
         self.endpoint = endpoint
         self.service_name = service_name
         self.batch_size = batch_size
@@ -590,7 +383,7 @@ class _OtlpHttpSink:
         # sync on purpose: runs in the executor thread, never on the loop
         req = urllib.request.Request(
             self.endpoint, data=body,
-            headers={"Content-Type": self.content_type})
+            headers={"Content-Type": "application/json"})
         with urllib.request.urlopen(req, timeout=self.timeout) as resp:
             if resp.status >= 400:  # urlopen raises on most, belt+braces
                 raise urllib.error.HTTPError(
@@ -627,10 +420,7 @@ class OtlpSink(_OtlpHttpSink):
     it from ``trace_otlp_endpoint``."""
 
     def _encode(self, batch: list[dict]) -> bytes:
-        req = spans_to_otlp(batch, self.service_name)
-        if self.encoding == "protobuf":
-            return otlp_trace_protobuf(req)
-        return json.dumps(req).encode()
+        return json.dumps(spans_to_otlp(batch, self.service_name)).encode()
 
 
 # ---------------------------------------------------------------------------
@@ -706,17 +496,13 @@ class OtlpMetricsSink(_OtlpHttpSink):
     def __init__(self, endpoint: str, *, service_name: str = "orleans_tpu",
                  batch_size: int = 4, flush_interval: float = 1.0,
                  max_queue: int = 64, max_retries: int = 2,
-                 retry_backoff: float = 0.05, timeout: float = 2.0,
-                 encoding: str = "json"):
+                 retry_backoff: float = 0.05, timeout: float = 2.0):
         super().__init__(endpoint, service_name=service_name,
                          batch_size=batch_size,
                          flush_interval=flush_interval,
                          max_queue=max_queue, max_retries=max_retries,
-                         retry_backoff=retry_backoff, timeout=timeout,
-                         encoding=encoding)
+                         retry_backoff=retry_backoff, timeout=timeout)
 
     def _encode(self, batch: list[dict]) -> bytes:
-        req = snapshots_to_otlp_metrics(batch, self.service_name)
-        if self.encoding == "protobuf":
-            return otlp_metrics_protobuf(req)
-        return json.dumps(req).encode()
+        return json.dumps(
+            snapshots_to_otlp_metrics(batch, self.service_name)).encode()

@@ -4,8 +4,8 @@ jax.profiler device-trace wrappers.
 Two lenses live here:
 
 **Device lens** (the original thin wrapper): ``Profiler.start/stop``
-captures an XLA trace (TensorBoard/Perfetto timelines), ``annotate`` /
-``@traced`` bridge host sections onto it, ``StepTimer`` counts slow ticks.
+captures an XLA trace (TensorBoard/Perfetto timelines); the program's own
+stages reach it through ``observability.stats.StageSpan``.
 
 **Host-loop lens** (the continuous occupancy profiler): the silo's wall
 time is one event loop, and at closed-loop saturation the residual
@@ -48,18 +48,14 @@ import asyncio
 import contextlib
 import contextvars
 import functools
-import inspect
 import logging
 import sys
 import time
 import weakref
 from collections import deque
-from typing import TYPE_CHECKING, Iterator
+from typing import Iterator
 
 import jax
-
-if TYPE_CHECKING:
-    from .stats import StatsRegistry
 
 log = logging.getLogger("orleans.profiling")
 
@@ -80,8 +76,7 @@ try:
 except Exception:  # noqa: BLE001 — native must never break import
     _hotloop = None
 
-__all__ = ["Profiler", "annotate", "traced", "StepTimer",
-           "LoopProfiler", "LOOP_CATEGORIES", "LOOP_CATEGORY",
+__all__ = ["Profiler", "LoopProfiler", "LOOP_CATEGORIES", "LOOP_CATEGORY",
            "install_loop_profiler", "uninstall_loop_profiler",
            "loop_profiler", "mark_loop_category"]
 
@@ -626,35 +621,6 @@ def uninstall_loop_profiler(loop) -> None:
             pass
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named span on the profiler timeline (no-op cost when no trace is
-    active)."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-def traced(name: str):
-    """Decorator form of :func:`annotate`. Coroutine-aware: wrapping an
-    ``async def`` keeps the annotation open across the whole awaited turn
-    (a naive wrapper would return the coroutine object and close the span
-    before the turn ever ran). Function metadata is preserved."""
-    def wrap(fn):
-        if inspect.iscoroutinefunction(fn):
-            @functools.wraps(fn)
-            async def inner(*args, **kwargs):
-                with jax.profiler.TraceAnnotation(name):
-                    return await fn(*args, **kwargs)
-            return inner
-
-        @functools.wraps(fn)
-        def inner(*args, **kwargs):
-            with jax.profiler.TraceAnnotation(name):
-                return fn(*args, **kwargs)
-        return inner
-    return wrap
-
-
 class Profiler:
     """Start/stop XLA trace capture (jax.profiler.start_trace). One active
     capture per process; ``stop()`` is idempotent."""
@@ -684,31 +650,3 @@ class Profiler:
             yield
         finally:
             self.stop()
-
-
-class StepTimer:
-    """Wall-clock per named step into a stats histogram, warning on slow
-    steps (the device-tier TurnWarningLengthThreshold,
-    OrleansTaskScheduler.cs:26)."""
-
-    def __init__(self, stats: "StatsRegistry", name: str,
-                 warn_threshold: float = 0.2):
-        self.stats = stats
-        self.name = name
-        self.warn_threshold = warn_threshold
-
-    @contextlib.contextmanager
-    def step(self) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            with jax.profiler.TraceAnnotation(self.name):
-                yield
-        finally:
-            # record failed steps too — crashed/timed-out ticks are the
-            # most important ones in the latency telemetry
-            dt = time.perf_counter() - t0
-            self.stats.observe(f"{self.name}.seconds", dt)
-            if dt > self.warn_threshold:
-                self.stats.increment(f"{self.name}.slow")
-                log.warning("%s took %.3fs (threshold %.3fs)", self.name,
-                            dt, self.warn_threshold)

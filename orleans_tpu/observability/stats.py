@@ -9,11 +9,18 @@ dumpable for the management surface and test assertions.
 from __future__ import annotations
 
 import bisect
+import contextlib
+import threading
 import time
 from collections import deque
 from typing import Callable
 
+import jax.monitoring
+from jax.profiler import TraceAnnotation
+
 __all__ = ["StatsRegistry", "Histogram", "QueueWaitTrend", "CallSiteStats",
+           "StageSpan", "NO_SPAN", "observe_or_defer", "open_stage_registry",
+           "close_stage_registry", "STAGES", "FLUSH_STATS",
            "DISPATCH_STATS", "REBALANCE_STATS", "INGEST_STATS",
            "INGEST_STAGES", "EGRESS_STATS", "EGRESS_STAGES", "RING_STATS",
            "RING_STAGES", "SLO_STATS", "SIZE_BOUNDS", "COUNT_BOUNDS"]
@@ -198,6 +205,157 @@ SLO_STATS = {
     # error-rate objective's bad-event counter
     "turn_errors": "turns.errors",
 }
+
+
+# Stage spans (StageSpan below) — the one span substrate of the served
+# device path. Each name is a stage of one unit of work (``tick=<rt.ticks>``
+# or ``flush=<n>``): closing the span observes ``<name>.seconds`` here and
+# closes a ``jax.profiler.TraceAnnotation("otpu:<name>")`` over the same
+# interval, so a profiler capture shows the program's own stages beside
+# the device ops. ``ingest.staging`` / ``ingest.transfer`` are the
+# INGEST_STATS histograms, unchanged; ``ingest.tick`` stays one histogram
+# and is tiled by ``ingest.tick.dispatch`` + ``ingest.tick.sync``.
+#
+#   pump.batch            one decoded socket read routed (loop)
+#   engine.claim          _tick's claim + submit loop (loop)
+#   engine.worker_queue   _submit_job -> worker dequeue (histogram only:
+#                         the wait crosses threads)
+#   engine.fence_wait     worker dequeue -> tick fence acquired (worker)
+#   ingest.tick.dispatch  kernel(*args) call -> return
+#   ingest.tick.sync      dispatch return -> host materialize done
+#   engine.complete_hop   worker's call_soon_threadsafe -> _complete_job
+#                         entered (histogram only, crosses threads)
+#   engine.resolve        _resolve_batch (loop)
+#   egress.flush          EgressBatcher.flush (loop)
+#   flush                 one write-behind pass of hosting.flush_all
+#   flush.locate/.gather  VectorStorageBridge.flush under the tick fence
+#   flush.write           the gather of per-key storage writes
+#   recover               first touch -> bridge.load done
+#
+# Spans that stay open across an ``await`` (flush, flush.write, recover)
+# interleave with other work on the loop: their seconds are wall time of
+# the unit of work, not loop time, and they never become the thread's
+# current stage. Worker-thread spans take the job's ``sink`` and replay
+# loop-side. All of it is gated on SiloConfig.metrics_enabled: sites
+# guard on the registry being None and construct nothing when it is.
+STAGES = ("pump.batch", "engine.claim", "engine.worker_queue",
+          "engine.fence_wait", "ingest.staging", "ingest.transfer",
+          "ingest.tick.dispatch", "ingest.tick.sync", "engine.complete_hop",
+          "engine.resolve", "egress.flush", "flush", "flush.locate",
+          "flush.gather", "flush.write", "recover")
+
+FLUSH_STATS = {
+    "rows": "vector.storage.flush.rows",   # COUNT_BOUNDS: rows per flush
+    "flushes": "vector.storage.flushes",   # counter: flushes that wrote
+    "flushed": "vector.storage.flushed",   # counter: rows written
+}
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_listening = False
+
+
+class _PerThread(threading.local):
+    stage = None   # the innermost open (nesting) StageSpan of this thread
+    home = None    # the registry open_stage_registry() gave this thread
+
+
+_thread = _PerThread()
+
+
+def observe_or_defer(sink, stats, key: str, value: float) -> None:
+    """One stage observation: direct on the loop, deferred into ``sink``
+    on a worker thread (StatsRegistry/Histogram are not thread-safe —
+    concurrent += loses updates and a first-tick key insert can break
+    the sampler's snapshot iteration — so worker-side measurements
+    REPLAY loop-side, engine._complete_job; the timing itself is still
+    stamped off-loop)."""
+    if sink is not None:
+        sink.append((key, value))
+    else:
+        stats.observe(key, value)
+
+
+class StageSpan:
+    """One stage of one unit of work, on the host clock and on the
+    profiler's: from construction to :meth:`close` (or as a context
+    manager). ``sink`` is the off-loop tick worker's deferred-stats list:
+    with it the observation is appended as ``(key, seconds)`` and replayed
+    loop-side, because the registry is loop-confined. ``nest=False`` for a
+    span held across an ``await``: it does not become the thread's
+    current stage (compiles are booked to the innermost *synchronous*
+    stage). ``unit`` names the unit of work on the annotation."""
+
+    __slots__ = ("name", "stats", "sink", "t0", "_ann", "_prev", "_nest")
+
+    def __init__(self, stats, name: str, sink: list | None = None,
+                 nest: bool = True, **unit):
+        self.name = name
+        self.stats = stats
+        self.sink = sink
+        self._nest = nest
+        if nest:
+            self._prev = _thread.stage
+            _thread.stage = self
+        self._ann = TraceAnnotation("otpu:" + name, **unit)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def close(self) -> float:
+        dt = time.perf_counter() - self.t0
+        self._ann.__exit__(None, None, None)
+        if self._nest:
+            _thread.stage = self._prev
+        observe_or_defer(self.sink, self.stats, self.name + ".seconds", dt)
+        return dt
+
+    @staticmethod
+    def unwind() -> None:
+        """Error path of a unit of work: close every stage the calling
+        thread still has open (a failed step is recorded too, and the
+        thread's current stage must not outlive the work)."""
+        while _thread.stage is not None:
+            _thread.stage.close()
+
+    def __enter__(self) -> "StageSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
+# what a site enters instead of a StageSpan when its registry is None
+NO_SPAN = contextlib.nullcontext()
+
+
+def _book_compile(event: str, secs: float, **_kw) -> None:
+    """jax.monitoring listener: every backend compile (a persistent-cache
+    hit passes through the same event) is booked to the calling thread's
+    current stage — ``compile.<stage>.seconds`` — or, outside any stage,
+    to ``compile.other.seconds`` in the registry opened on that thread."""
+    if event != _BACKEND_COMPILE:
+        return
+    span = _thread.stage
+    if span is not None:
+        observe_or_defer(span.sink, span.stats,
+                         f"compile.{span.name}.seconds", secs)
+    elif _thread.home is not None:
+        _thread.home.observe("compile.other.seconds", secs)
+
+
+def open_stage_registry(stats: "StatsRegistry") -> None:
+    """Make ``stats`` the calling thread's registry for compiles outside
+    any stage (a metrics-enabled silo calls this from its loop at start),
+    and install the process-wide compile listener on first use."""
+    global _listening
+    if not _listening:
+        _listening = True
+        jax.monitoring.register_event_duration_secs_listener(_book_compile)
+    _thread.home = stats
+
+
+def close_stage_registry(stats: "StatsRegistry") -> None:
+    if _thread.home is stats:
+        _thread.home = None
 
 
 class Histogram:

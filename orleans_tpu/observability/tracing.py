@@ -13,9 +13,8 @@ This module grows that into a W3C-style trace/span model:
   client invoke (``runtime_client``), server turn with queue-wait vs.
   execution split (``runtime/dispatcher``), the network leg (stamped
   send-side, measured receive-side), directory lookups
-  (``directory/locator``), device ticks (``dispatch/engine``, bridged to
-  ``jax.profiler.TraceAnnotation`` so XLA kernels nest under the logical
-  span), and rebalance migration legs (``rebalance/executor``);
+  (``directory/locator``), device ticks (``dispatch/engine``), and
+  rebalance migration legs (``rebalance/executor``);
 * a per-silo :class:`SpanCollector` ring buffer holds finished spans with
   a head-based sampling knob (``config.TracingOptions`` /
   ``trace_sample_rate``): the ROOT of a trace rolls the sampling die once

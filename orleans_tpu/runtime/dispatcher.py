@@ -57,6 +57,7 @@ _HDR_UNPARSED = object()
 
 from ..observability.stats import INGEST_STATS as _INGEST  # noqa: E402
 from ..observability.stats import SLO_STATS as _SLO  # noqa: E402
+from ..observability.stats import NO_SPAN, StageSpan  # noqa: E402
 
 _QUEUE_WAIT = _INGEST["queue_wait"]
 _TURNS = _INGEST["turns"]
@@ -213,6 +214,16 @@ class Dispatcher:
             # forward — re-addressing before the owner drops the stale
             # entry would just bounce back here
             reason = str(e)  # `e` unbinds when the except block exits
+            if msg.target_grain.is_system_target():
+                # a system target lives on one silo generation and has no
+                # directory entry: a control message addressed to a
+                # restarted silo's old generation is rejected, never
+                # healed, forwarded or answered with a cache-invalidate
+                # notice — each of those sends another control message to
+                # the same dead address, and eager tasks then recurse
+                # without yielding to the membership view that ends it
+                self._reject(msg, RejectionType.TRANSIENT, reason)
+                return
             heal = getattr(self.silo.locator,
                            "unregister_after_nonexistent", None)
             if heal is None:
@@ -398,7 +409,7 @@ class Dispatcher:
                               hdr[2], time.time() - hdr[2])
                 # device span: enqueue → tick-resolved future (the host
                 # view of the batched kernel turn; the engine's own tick
-                # spans + TraceAnnotation carry the per-tick detail)
+                # spans carry the per-tick detail)
                 vspan = tracer.open(
                     f"{msg.interface_name}.{msg.method_name}", "device",
                     hdr[0], hdr[1])
@@ -873,12 +884,22 @@ class Dispatcher:
                 # again would re-scatter stale stored state over ticks
                 # that already ran
                 return await rt.call(vcls, key_hash, method, **kwargs)
+            st = self.silo.ingest_stats
+            # first touch -> the stored row is in the table (or there was
+            # none); opened before the load task exists (an eager task
+            # factory runs it to its first suspension right here) and
+            # held across the storage read
+            span = StageSpan(st, "recover", nest=False) \
+                if st is not None else NO_SPAN
             rec = asyncio.ensure_future(bridge.load([key_hash]))
             self._vector_recoveries[rec_key] = rec
             try:
-                restored = await rec
-                if restored:
-                    self.silo.stats.increment("vector.storage.recovered")
+                with span:
+                    restored = await rec
+                # counted from the first touch on, so a deployment that
+                # recovers nothing reads 0 and not "no such counter"
+                self.silo.stats.increment("vector.storage.recovered",
+                                          len(restored))
             finally:
                 self._vector_recoveries.pop(rec_key, None)
         else:

@@ -45,7 +45,7 @@ import asyncio
 import time
 
 from ..core import message as _msg_mod
-from ..observability.stats import COUNT_BOUNDS, EGRESS_STATS
+from ..observability.stats import COUNT_BOUNDS, EGRESS_STATS, StageSpan
 
 _BUILD = EGRESS_STATS["build"]
 _DWELL = EGRESS_STATS["dwell"]
@@ -150,13 +150,15 @@ class EgressBatcher:
         # the build window covers ONLY the grouping/bookkeeping work —
         # the hand-off below runs outside it so the stage decomposition
         # stays non-overlapping (encode times itself in the wire layer,
-        # transport write is not an egress stage)
-        t0 = time.perf_counter()
-        for dest, msgs in groups.items():
-            self._observe_group(dest, msgs)
-        st.observe(_BUILD, time.perf_counter() - t0)
-        for dest, msgs in groups.items():
-            center.send_batch(dest, msgs)
+        # transport write is not an egress stage); egress.flush is the
+        # whole callback, hand-off and encode included
+        with StageSpan(st, "egress.flush", groups=len(groups)):
+            t0 = time.perf_counter()
+            for dest, msgs in groups.items():
+                self._observe_group(dest, msgs)
+            st.observe(_BUILD, time.perf_counter() - t0)
+            for dest, msgs in groups.items():
+                center.send_batch(dest, msgs)
 
     def flush_dest(self, dest) -> None:
         """FIFO guard: drain the pending group for ONE destination now

@@ -218,10 +218,6 @@ class SiloConfig:
     trace_otlp_endpoint: str | None = None
     trace_otlp_batch_size: int = 64
     trace_otlp_flush_interval: float = 0.5
-    # ship OTLP bodies as protobuf wire bytes instead of the JSON mapping
-    # (opt-in; requires google.protobuf importable, else falls back to
-    # JSON with a warning — the JSON path is untouched when off)
-    trace_otlp_protobuf: bool = False
     # live rebalancer (orleans_tpu.rebalance): plan/execute period in
     # seconds (0 disables the loop even when the service is installed),
     # per-round migration budget, and the hot/mean load ratio below which
@@ -260,9 +256,6 @@ class SiloConfig:
     # periodic OTLP metrics push (export.OtlpMetricsSink); None = no sink
     metrics_otlp_endpoint: str | None = None
     metrics_otlp_period: float = 5.0
-    # protobuf wire encoding for the metrics push (same gate/fallback as
-    # trace_otlp_protobuf)
-    metrics_otlp_protobuf: bool = False
     # host-loop occupancy profiler + flight recorder (observability.
     # profiling.LoopProfiler / config.ProfilingOptions): when enabled the
     # silo interposes on its event loop's call_soon/call_at and buckets
@@ -929,9 +922,7 @@ class Silo:
                 self.tracer.sinks.append(OtlpSink(
                     config.trace_otlp_endpoint, service_name=config.name,
                     batch_size=config.trace_otlp_batch_size,
-                    flush_interval=config.trace_otlp_flush_interval,
-                    encoding=("protobuf" if config.trace_otlp_protobuf
-                              else "json")))
+                    flush_interval=config.trace_otlp_flush_interval))
             if config.trace_tail_enabled:
                 # retention propagation: when THIS silo retains a trace it
                 # pulls the remote legs over the control path before export
@@ -1039,14 +1030,17 @@ class Silo:
         self.catalog.start()
         if self.config.metrics_enabled:
             from ..observability.metrics import MetricsSampler
+            from ..observability.stats import open_stage_registry
+
+            # compiles outside any stage span book to this registry
+            # (compile.other.seconds); the first silo to get here installs
+            # the process-wide compile listener
+            open_stage_registry(self.stats)
             if self.config.metrics_otlp_endpoint:
                 from ..observability.export import OtlpMetricsSink
                 self.metrics_sink = OtlpMetricsSink(
                     self.config.metrics_otlp_endpoint,
-                    service_name=self.config.name,
-                    encoding=("protobuf"
-                              if self.config.metrics_otlp_protobuf
-                              else "json"))
+                    service_name=self.config.name)
             self.metrics = MetricsSampler(
                 self, period=self.config.metrics_sample_period,
                 window=self.config.metrics_window,
@@ -1179,6 +1173,8 @@ class Silo:
             self.slo.stop()
             self.slo = None
         if self.metrics is not None:
+            from ..observability.stats import close_stage_registry
+            close_stage_registry(self.stats)
             self.metrics.stop()
             if graceful and self.metrics_sink is not None:
                 # final snapshot so the collector sees the end state

@@ -44,7 +44,7 @@ from ..core.asyncs import ExponentialBackoff, retry
 from ..core.errors import SiloUnavailableError
 from ..core.ids import SiloAddress
 from ..core.message import Category, Direction, Message, recycle_messages
-from ..observability.stats import EGRESS_STATS
+from ..observability.stats import EGRESS_STATS, NO_SPAN, StageSpan
 from .references import GrainFactory
 from .runtime_client import RuntimeClient
 from .wire import (
@@ -1167,10 +1167,14 @@ class SocketFabric:
         async for msgs, bounces in _read_frame_batches(reader, ist,
                                                        silo.ledger, route,
                                                        strict_tail=True):
-            for e in bounces:
-                self._bounce_undecodable(e.message, str(e))
-            if msgs:
-                self._route_inbound_batch(silo, msgs)
+            # one decoded read on the loop: bounces, routing, and every
+            # rt.call enqueue (or inline turn) the hand-off runs
+            with StageSpan(ist, "pump.batch", msgs=len(msgs)) \
+                    if ist is not None else NO_SPAN:
+                for e in bounces:
+                    self._bounce_undecodable(e.message, str(e))
+                if msgs:
+                    self._route_inbound_batch(silo, msgs)
 
     def _route_inbound_batch(self, silo: "Silo", msgs: list) -> None:
         """Batched ``_route_inbound``: messages for a local silo ride ONE

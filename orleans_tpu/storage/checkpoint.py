@@ -32,6 +32,7 @@ import numpy as np
 
 from ..core.errors import InconsistentStateError
 from ..core.ids import GrainId, GrainType
+from ..observability.stats import NO_SPAN, StageSpan
 from .core import GrainStorage
 
 if TYPE_CHECKING:
@@ -194,6 +195,7 @@ class VectorStorageBridge:
         self.grain_type = grain_class.__name__
         self._etags: dict[int, str | None] = {}
         self.storage_conflicts = 0
+        self.flushes = 0  # flush() calls: the stage spans' unit of work
 
     def _grain_id(self, key: int) -> GrainId:
         return GrainId.for_grain(GrainType.of(self.grain_type), int(key))
@@ -233,19 +235,31 @@ class VectorStorageBridge:
         individually, so one bad key cannot wedge write-behind for the
         whole class. Failures re-raise (after re-marking) when ``strict``
         is set OR when the runtime has no dirty tracking to hold the
-        retry — a standalone bridge must never report silent success."""
+        retry — a standalone bridge must never report silent success.
+
+        With the runtime's stage metrics on, three spans of unit
+        ``flush=<n>``: flush.locate and flush.gather (both holding the
+        fence, so ticks wait for them) and flush.write (the per-key
+        writes, interleaved with whatever else the loop runs)."""
         keys = [int(k) for k in keys]
         if not keys:
             return 0
         tbl = self.runtime.table(self.grain_class)
+        st = self.runtime.stats
+        self.flushes += 1
+        n = self.flushes
         # under the tick fence: the gather materializes state rows, which
         # must not race an off-loop tick that has the state donated
         with self.runtime.tick_fence():
-            kept, shards, slots = self._locate(keys, drop_missing=True)
+            with StageSpan(st, "flush.locate", flush=n) \
+                    if st is not None else NO_SPAN:
+                kept, shards, slots = self._locate(keys, drop_missing=True)
             if not kept:
                 return 0
-            host = {f: np.asarray(a[shards, slots])
-                    for f, a in tbl.state.items()}
+            with StageSpan(st, "flush.gather", flush=n, rows=len(kept)) \
+                    if st is not None else NO_SPAN:
+                host = {f: np.asarray(a[shards, slots])
+                        for f, a in tbl.state.items()}
 
         async def write_one(i: int, key: int) -> None:
             state = {f: host[f][i] for f in host}
@@ -284,9 +298,11 @@ class VectorStorageBridge:
                 raise _ConflictReleased(key) from None
             self._etags[key] = etag
 
-        results = await asyncio.gather(
-            *(write_one(i, k) for i, k in enumerate(kept)),
-            return_exceptions=True)
+        with StageSpan(st, "flush.write", nest=False, flush=n) \
+                if st is not None else NO_SPAN:
+            results = await asyncio.gather(
+                *(write_one(i, k) for i, k in enumerate(kept)),
+                return_exceptions=True)
         conflicts = [r.key for r in results
                      if isinstance(r, _ConflictReleased)]
         failed = [k for k, r in zip(kept, results)

@@ -7,14 +7,13 @@ import pytest
 
 import jax.numpy as jnp
 
-from orleans_tpu.observability import Profiler, StatsRegistry, StepTimer, \
-    annotate, traced
+from orleans_tpu.observability import Profiler, StageSpan, StatsRegistry
 
 
 def test_trace_capture_writes_files(tmp_path):
     p = Profiler()
     with p.capture(str(tmp_path)):
-        with annotate("test-span"):
+        with StageSpan(StatsRegistry(), "test-span"):
             jnp.arange(128).sum().block_until_ready()
     assert p.active_dir is None
     dumped = [f for _, _, fs in os.walk(tmp_path) for f in fs]
@@ -32,42 +31,21 @@ def test_double_start_rejected(tmp_path):
     assert p.stop() is None  # idempotent
 
 
-async def test_traced_is_coroutine_aware_and_preserves_metadata():
-    """@traced on an async handler must await inside the annotation (the
-    old wrapper returned the coroutine with the span already closed) and
-    keep the function's metadata via functools.wraps."""
-    import asyncio
-    import inspect
+def test_stage_span_observes_and_nests():
+    """The one span primitive (was StepTimer): a closed span observes
+    ``<name>.seconds`` once, failed steps included, and the thread's
+    current stage is restored."""
+    from orleans_tpu.observability import stats as stats_mod
 
-    @traced("async-work")
-    async def handler(x):
-        """docstring survives"""
-        await asyncio.sleep(0)
-        return x * 2
-
-    assert inspect.iscoroutinefunction(handler)
-    assert handler.__name__ == "handler"
-    assert handler.__doc__ == "docstring survives"
-    assert await handler(3) == 6
-
-    @traced("sync-work")
-    def sync_handler(x):
-        return x + 1
-
-    assert sync_handler.__name__ == "sync_handler"
-    assert sync_handler.__wrapped__(1) == 2  # functools.wraps marker
-    assert sync_handler(1) == 2
-
-
-def test_traced_decorator_and_step_timer():
     stats = StatsRegistry()
-    timer = StepTimer(stats, "tick", warn_threshold=0.0)  # always slow
-
-    @traced("work")
-    def work(x):
-        return x + 1
-
-    with timer.step():
-        assert work(1) == 2
-    assert stats.get("tick.slow") == 1
-    assert sum(stats.histogram("tick.seconds").counts) >= 1
+    with StageSpan(stats, "tick", tick=7) as outer:
+        assert stats_mod._thread.stage is outer
+        with pytest.raises(ValueError):
+            with StageSpan(stats, "tick.inner", tick=7):
+                raise ValueError("a failed step is recorded too")
+        assert stats_mod._thread.stage is outer
+    assert stats_mod._thread.stage is None
+    assert stats.histogram("tick.seconds").total == 1
+    assert stats.histogram("tick.inner.seconds").total == 1
+    assert stats.histogram("tick.seconds").sum >= \
+        stats.histogram("tick.inner.seconds").sum

@@ -1,0 +1,314 @@
+"""Stage spans (observability.stats.StageSpan): one primitive at every
+layer boundary of the served device path — host-clock histogram,
+``otpu:<stage>`` annotation on the profiler's clock, compile attribution.
+
+Counts and containment only; no timing thresholds. One served silo
+(SocketFabric + GatewayClient + write-behind storage) is driven once per
+tick path (off-loop worker on and off) and the per-stage cases read its
+registry.
+"""
+
+import asyncio
+import glob
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from orleans_tpu.dispatch import VectorGrain, actor_method, add_vector_grains
+from orleans_tpu.observability import stats as stats_mod
+from orleans_tpu.observability.stats import (FLUSH_STATS, STAGES, StageSpan,
+                                             StatsRegistry,
+                                             close_stage_registry,
+                                             open_stage_registry)
+from orleans_tpu.parallel import make_mesh
+from orleans_tpu.runtime import GatewayClient, SiloBuilder, SocketFabric
+from orleans_tpu.storage import MemoryStorage
+
+N_KEYS = 16
+ROUNDS = 4
+# stages that exist only with the off-loop tick worker
+WORKER_ONLY = ("engine.worker_queue", "engine.fence_wait",
+               "engine.complete_hop")
+PER_TICK = ("engine.claim", "ingest.staging", "ingest.transfer",
+            "ingest.tick.dispatch", "ingest.tick.sync",
+            "engine.resolve") + WORKER_ONLY
+PER_FLUSH = ("flush", "flush.locate", "flush.gather", "flush.write")
+
+
+class CounterVec(VectorGrain):
+    STATE = {"total": (jnp.int32, ())}
+
+    @staticmethod
+    def initial_state(key_hash):
+        return {"total": jnp.int32(0)}
+
+    @actor_method(args={"x": (jnp.int32, ())})
+    def add(state, args):
+        total = state["total"] + args["x"]
+        return {"total": total}, total
+
+
+def _build(metrics: bool, offloop: bool, storage=None, period: float = 0.05):
+    b = (SiloBuilder().with_name(f"ss-{metrics}-{offloop}")
+         .with_fabric(SocketFabric())
+         .with_config(metrics_enabled=metrics, offloop_tick=offloop))
+    add_vector_grains(b, CounterVec, mesh=make_mesh(1),
+                      dense={CounterVec: 64}, capacity_per_shard=64,
+                      **({"storage": storage, "flush_period": period}
+                         if storage is not None else {}))
+    return b.build()
+
+
+async def _rounds(client, keys, rounds: int = ROUNDS) -> None:
+    refs = [client.get_grain(CounterVec, k) for k in keys]
+    for r in range(rounds):
+        out = await asyncio.gather(*(g.add(x=np.int32(1)) for g in refs))
+        assert [int(v) for v in out] == [r + 1] * len(refs)
+
+
+async def _settle(silo, rows: int) -> None:
+    """Until the write-behind flusher has written ``rows`` rows."""
+    for _ in range(400):
+        if silo.stats.get(FLUSH_STATS["flushed"]) >= rows:
+            return
+        await asyncio.sleep(0.025)
+    raise AssertionError("the flusher never drained")
+
+
+async def _serve(metrics: bool, offloop: bool) -> dict:
+    """Drive one served silo; returns what the cases compare."""
+    silo = _build(metrics, offloop, MemoryStorage())
+    await silo.start()
+    loop_thread = threading.get_ident()
+    observers: set = set()
+    sunk: set = set()
+    observe = silo.stats.observe
+    complete = silo.vector._complete_job
+
+    def spy_observe(key, value):
+        observers.add(threading.get_ident())
+        observe(key, value)
+
+    def spy_complete(job, host, err):
+        sunk.update(k for k, _v in job.stats if isinstance(k, str))
+        complete(job, host, err)
+
+    silo.stats.observe = spy_observe
+    silo.vector._complete_job = spy_complete
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    try:
+        await _rounds(client, range(N_KEYS))
+        await _settle(silo, N_KEYS)
+    finally:
+        await client.close_async()
+        await silo.stop()
+    return {"stats": silo.stats, "observers": observers, "sunk": sunk,
+            "loop_thread": loop_thread,
+            "stage_left": stats_mod._thread.stage}
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["offloop", "inline"])
+def served(request):
+    return request.param, asyncio.run(_serve(True, request.param))
+
+
+def _count(stats, name: str) -> int:
+    h = stats.histograms.get(name)
+    return h.total if h is not None else 0
+
+
+@pytest.mark.parametrize("stage", STAGES)
+def test_stage_observed_once_per_unit_of_work(served, stage):
+    offloop, run = served
+    st = run["stats"]
+    got = _count(st, stage + ".seconds")
+    if stage in WORKER_ONLY and not offloop:
+        assert got == 0  # no worker, no hand-off to time
+    elif stage in PER_TICK:
+        # one claimed batch = one job = one of each tick stage
+        assert got == _count(st, "ingest.tick.seconds") >= ROUNDS
+    elif stage in PER_FLUSH:
+        assert got == st.get(FLUSH_STATS["flushes"]) >= 1
+    elif stage == "pump.batch":
+        # one per decoded socket read that carried messages
+        assert got == _count(st, "ingest.frame_batch.size") >= 1
+    elif stage == "egress.flush":
+        assert got == _count(st, "egress.build.seconds") >= 1
+    elif stage == "recover":
+        assert got == N_KEYS  # every key's first touch, and only that
+    else:
+        raise AssertionError(f"stage {stage} has no case")
+
+
+def test_tick_is_tiled_by_dispatch_and_sync(served):
+    _offloop, run = served
+    h = run["stats"].histograms
+    tick, disp, sync = (h["ingest.tick.seconds"],
+                        h["ingest.tick.dispatch.seconds"],
+                        h["ingest.tick.sync.seconds"])
+    assert tick.total == disp.total == sync.total
+    assert tick.sum >= disp.sum + sync.sum - 1e-9
+
+
+def test_worker_stages_reach_the_registry_through_the_sink(served):
+    offloop, run = served
+    # nothing but the loop thread ever wrote the registry
+    assert run["observers"] == {run["loop_thread"]}
+    worker_side = {f"{s}.seconds" for s in (
+        "engine.fence_wait", "engine.worker_queue", "ingest.staging",
+        "ingest.transfer", "ingest.tick.dispatch", "ingest.tick.sync")}
+    if offloop:
+        assert worker_side <= run["sunk"]
+    else:
+        assert not run["sunk"]  # inline: no job, no sink
+    assert run["stage_left"] is None
+
+
+def test_flush_rows_sum_to_flushed(served):
+    _offloop, run = served
+    st = run["stats"]
+    rows = st.histograms[FLUSH_STATS["rows"]]
+    assert rows.sum == st.get(FLUSH_STATS["flushed"]) >= N_KEYS
+    assert rows.total == st.get(FLUSH_STATS["flushes"])
+    assert st.get("vector.storage.recovered") == 0  # counted, none stored
+
+
+async def test_metrics_off_registers_none_of_the_new_names():
+    silo = _build(False, True, MemoryStorage())
+    await silo.start()
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    try:
+        await _rounds(client, range(4), rounds=2)
+        await _settle(silo, 4)
+    finally:
+        await client.close_async()
+        await silo.stop()
+    names = set(silo.stats.histograms) | set(silo.stats.counters)
+    new = {s + ".seconds" for s in STAGES} | {
+        FLUSH_STATS["rows"], FLUSH_STATS["flushes"]}
+    assert not names & new
+    assert not [n for n in names if n.startswith("compile.")]
+    assert silo.stats.get(FLUSH_STATS["flushed"]) >= 4  # it did flush
+
+
+async def test_compiles_are_booked_to_the_stage_that_compiled():
+    """A gather of a length the process has not seen compiles under
+    flush.gather; a tick at a warm bucket books nothing."""
+    silo = _build(True, True, MemoryStorage(), period=3600.0)
+    await silo.start()
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    bridge = silo.vector_bridges[CounterVec]
+    st = silo.stats
+
+    def compiles(prefix: str) -> int:
+        return sum(h.total for n, h in st.histograms.items()
+                   if n.startswith(prefix))
+
+    try:
+        await _rounds(client, range(8), rounds=1)  # compiles the bucket
+        tick0 = compiles("compile.ingest.") + compiles("compile.engine.")
+        assert tick0 >= 1
+        await _rounds(client, range(8, 16), rounds=1)  # same bucket: warm
+        assert compiles("compile.ingest.") + compiles("compile.engine.") \
+            == tick0
+        for n in (13, 11):  # two gather lengths new to the process
+            before = compiles("compile.flush.gather")
+            assert await bridge.flush(range(n)) == n
+            assert compiles("compile.flush.gather") > before
+        assert compiles("compile.flush.") == compiles("compile.flush.gather")
+        before = compiles("compile.flush.gather")
+        assert await bridge.flush(range(11)) == 11  # a length seen: none
+        assert compiles("compile.flush.gather") == before
+    finally:
+        await client.close_async()
+        await silo.stop()
+
+
+async def test_profiler_capture_carries_dispatch_events_with_their_tick(
+        tmp_path):
+    silo = _build(True, True)
+    await silo.start()
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    try:
+        await _rounds(client, range(8), rounds=1)  # compile outside
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            await _rounds(client, range(8, 16), rounds=3)
+        finally:
+            jax.profiler.stop_trace()
+        ticks_now = silo.vector.ticks
+    finally:
+        await client.close_async()
+        await silo.stop()
+    path = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    found = [dict(e.stats)
+             for p in jax.profiler.ProfileData.from_file(path).planes
+             for ln in p.lines for e in ln.events
+             if e.name == "otpu:ingest.tick.dispatch"]
+    assert len(found) >= 3
+    assert all(0 <= int(s["tick"]) < ticks_now for s in found)
+
+
+async def test_failed_batch_closes_its_stages():
+    """A batch that raises mid-stage records the failed step and leaves
+    the thread with no current stage (inline: this thread)."""
+    silo = _build(True, False)
+    await silo.start()
+    rt = silo.vector
+    try:
+        def boom(*_a, **_k):
+            raise RuntimeError("no kernel")
+
+        rt._kernel = boom
+        fut = rt.call(CounterVec, 3, "add", x=np.int32(1))
+        await rt.flush()
+        with pytest.raises(RuntimeError, match="no kernel"):
+            await fut
+        assert stats_mod._thread.stage is None
+        assert silo.stats.histograms["ingest.transfer.seconds"].total == 1
+        assert "ingest.tick.dispatch.seconds" not in silo.stats.histograms
+    finally:
+        del rt._kernel
+        await silo.stop()
+
+
+async def test_compile_listener_is_installed_once(monkeypatch):
+    calls = []
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener",
+                        calls.append)
+    monkeypatch.setattr(stats_mod, "_listening", False)
+    silos = [_build(True, True), _build(True, False), _build(False, True)]
+    for s in silos:
+        await s.start()
+    try:
+        assert calls == [stats_mod._book_compile]
+    finally:
+        for s in silos:
+            await s.stop()
+    assert stats_mod._thread.home is None
+
+
+def test_compile_outside_any_stage_books_to_other():
+    reg = StatsRegistry()
+    open_stage_registry(reg)
+    try:
+        jax.jit(lambda x: x * 3 + 11)(jnp.arange(5)).block_until_ready()
+        assert reg.histograms["compile.other.seconds"].total >= 1
+        with StageSpan(reg, "unit.step"):
+            jax.jit(lambda x: x * 5 + 13)(jnp.arange(5)).block_until_ready()
+        assert reg.histograms["compile.unit.step.seconds"].total >= 1
+        other = reg.histograms["compile.other.seconds"].total
+    finally:
+        close_stage_registry(reg)
+    jax.jit(lambda x: x * 7 + 17)(jnp.arange(5)).block_until_ready()
+    assert reg.histograms["compile.other.seconds"].total == other

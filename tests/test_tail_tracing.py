@@ -692,168 +692,3 @@ async def test_silo_local_trace_skips_control_path_fanout():
         tids = {s["trace_id"] for s in spans if s["parent_id"] is None}
         assert any(s["kind"] == "server" and s["trace_id"] in tids
                    for s in spans)
-
-
-# ----------------------------------------------------------------------
-# OTLP protobuf encoding (ISSUE 20): opt-in binary wire format built
-# from the SAME request dicts as the JSON path — a generic wire-walk
-# parser (no generated proto classes) proves the framing is valid
-# protobuf and carries the same structure the JSON payload does.
-# ----------------------------------------------------------------------
-def _pb_walk(data: bytes) -> list:
-    """Decode one protobuf message into [(field, wire_type, value)]:
-    varints as ints, length-delimited as raw bytes, fixed64 as 8 bytes.
-    Raises on truncation/invalid tags — the structural validity check."""
-    out = []
-    i = 0
-    while i < len(data):
-        tag = 0
-        shift = 0
-        while True:
-            b = data[i]
-            i += 1
-            tag |= (b & 0x7F) << shift
-            shift += 7
-            if not b & 0x80:
-                break
-        field, wt = tag >> 3, tag & 7
-        if wt == 0:  # varint
-            v = 0
-            shift = 0
-            while True:
-                b = data[i]
-                i += 1
-                v |= (b & 0x7F) << shift
-                shift += 7
-                if not b & 0x80:
-                    break
-        elif wt == 1:  # fixed64
-            v = data[i:i + 8]
-            assert len(v) == 8
-            i += 8
-        elif wt == 2:  # length-delimited
-            n = 0
-            shift = 0
-            while True:
-                b = data[i]
-                i += 1
-                n |= (b & 0x7F) << shift
-                shift += 7
-                if not b & 0x80:
-                    break
-            v = data[i:i + n]
-            assert len(v) == n, "truncated length-delimited field"
-            i += n
-        else:
-            raise AssertionError(f"unexpected wire type {wt}")
-        out.append((field, wt, v))
-    return out
-
-
-def _pb_fields(data: bytes, field: int) -> list:
-    return [v for f, _, v in _pb_walk(data) if f == field]
-
-
-def test_otlp_trace_protobuf_wire_walk():
-    """The binary trace encoding is valid protobuf mirroring the JSON
-    request: ResourceSpans(resource=1, scope_spans=2) > ScopeSpans >
-    Span with ids/name/kind/times/attributes, and the hex trace id
-    round-trips into the Span.trace_id bytes."""
-    from orleans_tpu.observability.export import otlp_trace_protobuf
-
-    req = spans_to_otlp(_mk_span_dicts(2, error_on=1, events_on=1),
-                        service_name="svc")
-    data = otlp_trace_protobuf(req)
-    (rs,) = _pb_fields(data, 1)           # ExportTraceServiceRequest
-    assert _pb_fields(rs, 1)              # resource present
-    (ss,) = _pb_fields(rs, 2)             # one ScopeSpans
-    spans = _pb_fields(ss, 2)
-    assert len(spans) == 2
-    json_root = req["resourceSpans"][0]["scopeSpans"][0]["spans"][0]
-    root, child = spans
-    (tid_bytes,) = _pb_fields(root, 1)
-    assert tid_bytes == bytes.fromhex(json_root["traceId"])
-    assert len(_pb_fields(root, 2)[0]) == 8          # span_id: 8 bytes
-    assert not _pb_fields(root, 4)                   # root: no parent
-    assert len(_pb_fields(child, 4)[0]) == 8         # child parented
-    (name,) = _pb_fields(root, 5)
-    assert name == json_root["name"].encode()
-    # fixed64 start/end nanos match the JSON stamps
-    import struct
-    (start,) = _pb_fields(root, 7)
-    assert struct.unpack("<Q", start)[0] == \
-        int(json_root["startTimeUnixNano"])
-    assert _pb_fields(root, 9)                       # attributes
-    assert _pb_fields(child, 11)                     # child's event
-    assert _pb_fields(child, 15)                     # error → status
-
-
-def test_otlp_metrics_protobuf_wire_walk():
-    """The binary metrics encoding carries sum/gauge/histogram points
-    with the same counts and bounds as the JSON request."""
-    import struct
-
-    from orleans_tpu.observability.export import (otlp_metrics_protobuf,
-                                                  snapshots_to_otlp_metrics)
-    from orleans_tpu.observability.stats import Histogram
-
-    h = Histogram()
-    for v in (0.001, 0.01, 0.01, 0.2):
-        h.observe(v)
-    snap = {"ts": 1234.5, "silo": "s0",
-            "counters": {"msgs": 7}, "gauges": {"backlog": 2.5},
-            "histograms": {"lat": h.summary()}}
-    req = snapshots_to_otlp_metrics([snap], service_name="svc")
-    data = otlp_metrics_protobuf(req)
-    (rm,) = _pb_fields(data, 1)
-    (sm,) = _pb_fields(rm, 2)
-    metrics = _pb_fields(sm, 2)
-    kinds = {}
-    for m in metrics:
-        (name,) = _pb_fields(m, 1)
-        kinds[name.decode()] = {5: "gauge", 7: "sum", 9: "histogram"}[
-            next(f for f, _, _ in _pb_walk(m) if f in (5, 7, 9))]
-    assert kinds == {"msgs": "sum", "backlog": "gauge",
-                     "lat": "histogram"}
-    hist = next(m for m in metrics if _pb_fields(m, 1)[0] == b"lat")
-    (hbody,) = _pb_fields(hist, 9)
-    (point,) = _pb_fields(hbody, 1)
-    (count,) = _pb_fields(point, 4)                  # fixed64 count
-    assert struct.unpack("<Q", count)[0] == 4
-    (bucket_counts,) = _pb_fields(point, 6)          # packed fixed64
-    counts = struct.unpack(f"<{len(bucket_counts) // 8}Q", bucket_counts)
-    assert sum(counts) == 4 and len(counts) == len(h.counts)
-    (bounds,) = _pb_fields(point, 7)                 # packed double
-    n_bounds = len(bounds) // 8
-    assert n_bounds == len(counts) - 1               # +Inf excluded
-
-
-async def test_otlp_sink_encoding_selection(monkeypatch):
-    """encoding="protobuf" flips the Content-Type; unknown encodings are
-    rejected; and when google.protobuf is absent the sink degrades to
-    JSON with a warning instead of dying (the binary path is an
-    optimization, never a dependency)."""
-    from orleans_tpu.observability import export
-
-    sink = OtlpSink("http://127.0.0.1:9/v1/traces", encoding="protobuf")
-    assert sink.encoding == "protobuf"
-    assert sink.content_type == "application/x-protobuf"
-    body = sink._encode(_mk_span_dicts(2))
-    assert _pb_fields(body, 1)  # valid protobuf, not JSON
-    await sink.aclose(flush=False)
-
-    json_sink = OtlpSink("http://127.0.0.1:9/v1/traces")
-    assert json_sink.content_type == "application/json"
-    import json as _json
-    assert _json.loads(json_sink._encode(_mk_span_dicts(1)))
-    await json_sink.aclose(flush=False)
-
-    with pytest.raises(ValueError):
-        OtlpSink("http://127.0.0.1:9/v1/traces", encoding="msgpack")
-
-    monkeypatch.setattr(export, "_HAS_PROTOBUF", False)
-    degraded = OtlpSink("http://127.0.0.1:9/v1/traces",
-                        encoding="protobuf")
-    assert degraded.encoding == "json"
-    assert degraded.content_type == "application/json"
-    await degraded.aclose(flush=False)
