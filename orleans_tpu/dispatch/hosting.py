@@ -176,7 +176,7 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
             from ..observability.stats import (COUNT_BOUNDS, FLUSH_STATS,
                                                StageSpan)
 
-            n = 0
+            n = batched = 0
             first_error: BaseException | None = None
             st = silo.ingest_stats
             span = None
@@ -186,14 +186,17 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
                     continue
                 if st is not None and span is None:
                     # one pass that found dirty rows, held across the
-                    # per-key writes: wall time of the pass (a cancelled
+                    # provider's writes: wall time of the pass (a cancelled
                     # pass records nothing; stop() runs it again)
                     span = StageSpan(
                         st, "flush", nest=False,
                         flush=st.get(FLUSH_STATS["flushes"]) + 1)
+                bridge = silo.vector_bridges[cls]
                 try:
-                    n += await silo.vector_bridges[cls].flush(
-                        keys, strict=strict)
+                    wrote = await bridge.flush(keys, strict=strict)
+                    n += wrote
+                    if bridge.batched:
+                        batched += wrote
                 except asyncio.CancelledError:
                     # cancelled mid-flush: the keys are already drained —
                     # re-mark them so the final stop() drain retries
@@ -216,6 +219,7 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
                                   COUNT_BOUNDS).observe(n)
             if n:
                 silo.stats.increment(FLUSH_STATS["flushed"], n)
+                silo.stats.increment(FLUSH_STATS["batched"], batched)
             if first_error is not None:
                 raise first_error
             return n

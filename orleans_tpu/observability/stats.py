@@ -248,6 +248,9 @@ FLUSH_STATS = {
     "rows": "vector.storage.flush.rows",   # COUNT_BOUNDS: rows per flush
     "flushes": "vector.storage.flushes",   # counter: flushes that wrote
     "flushed": "vector.storage.flushed",   # counter: rows written
+    # counter: of those, rows that went through a provider's own
+    # write_many (not GrainStorage's per-key default)
+    "batched": "vector.storage.flush.batched",
 }
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"

@@ -3,6 +3,7 @@ checkpoint/resume (orbax table snapshots, write-behind row persistence)."""
 
 from .checkpoint import VectorCheckpointer, VectorStorageBridge  # noqa: F401
 from .core import (  # noqa: F401
+    ADOPT_ETAG,
     ErrorInjectionStorage,
     FileStorage,
     GrainStorage,

@@ -32,8 +32,9 @@ import numpy as np
 
 from ..core.errors import InconsistentStateError
 from ..core.ids import GrainId, GrainType
+from ..dispatch.engine import _bucket
 from ..observability.stats import NO_SPAN, StageSpan
-from .core import GrainStorage
+from .core import ADOPT_ETAG, GrainStorage
 
 if TYPE_CHECKING:
     from ..dispatch.engine import VectorRuntime
@@ -41,14 +42,14 @@ if TYPE_CHECKING:
 __all__ = ["VectorCheckpointer", "VectorStorageBridge"]
 
 
-class _ConflictReleased(Exception):
-    """Internal flush marker: this key's etag conflicted (another silo
-    flushed it since we last did), so the local row was released —
-    deactivate-and-rebuild, never overwrite. Not a flush failure."""
-
-    def __init__(self, key: int):
-        super().__init__(key)
-        self.key = key
+@jax.jit
+def _gather_rows(state: dict, index: jax.Array) -> dict:
+    """Rows ``a[index[0], index[1]]`` of every field of a table's state
+    tree, as one program per (table, index length): the write-behind
+    flush pads ``index`` to a power of two, so the programs a process
+    compiles follow the size buckets and not each distinct dirty count."""
+    shards, slots = index[0], index[1]
+    return {f: a[shards, slots] for f, a in state.items()}
 
 
 def _table_meta(tbl) -> dict:
@@ -193,56 +194,154 @@ class VectorStorageBridge:
         self.grain_class = grain_class
         self.storage = storage
         self.grain_type = grain_class.__name__
+        self._gtype = GrainType.of(self.grain_type)
         self._etags: dict[int, str | None] = {}
+        self._ids: dict[int, GrainId] = {}  # beside _etags: one per key seen
         self.storage_conflicts = 0
         self.flushes = 0  # flush() calls: the stage spans' unit of work
+        # observed, not configured: does the provider bring its own
+        # write_many, or does a flush go through the per-key default?
+        self.batched = (type(storage).write_many
+                        is not GrainStorage.write_many)
 
     def _grain_id(self, key: int) -> GrainId:
-        return GrainId.for_grain(GrainType.of(self.grain_type), int(key))
+        gid = self._ids.get(key)
+        if gid is None:
+            gid = self._ids[key] = GrainId.for_grain(self._gtype, int(key))
+        return gid
 
     def _locate(self, keys, drop_missing: bool = False
                 ) -> tuple[list[int], np.ndarray, np.ndarray]:
         """Resolve keys to (surviving_keys, shards, slots). Keys with no
         activation slot raise KeyError, or are dropped with a log when
-        ``drop_missing`` (a released slot has no row left to persist)."""
+        ``drop_missing`` (a released slot has no row left to persist).
+        Dense keys resolve as columns; only hashed keys are looked up one
+        by one."""
         tbl = self.runtime.table(self.grain_class)
-        kept, shards, slots = [], [], []
-        for k in keys:
-            k = int(k)
-            if 0 <= k < tbl.dense_n:
-                shard, slot = k // tbl.dense_per_shard, k % tbl.dense_per_shard
-            elif (loc := tbl.lookup(k)) is not None:
-                shard, slot = loc[0], loc[1]
+        k = np.asarray(keys, np.int64).reshape(-1)
+        per = max(tbl.dense_per_shard, 1)
+        dense = (k >= 0) & (k < tbl.dense_n)
+        shards = np.where(dense, k // per, 0).astype(np.int32)
+        slots = np.where(dense, k % per, 0).astype(np.int32)
+        keep = np.ones(len(k), bool)
+        lookup = tbl.lookup
+        for i in np.flatnonzero(~dense).tolist():
+            loc = lookup(int(k[i]))
+            if loc is not None:
+                shards[i], slots[i] = loc
             elif drop_missing:
                 logging.getLogger("orleans.vector").warning(
                     "write-behind: key %d has no activation slot; dropping",
-                    k)
-                continue
+                    k[i])
+                keep[i] = False
             else:
-                raise KeyError(f"key {k} has no activation slot")
-            kept.append(k)
-            shards.append(shard)
-            slots.append(slot)
-        return kept, np.asarray(shards, np.int32), np.asarray(slots, np.int32)
+                raise KeyError(f"key {k[i]} has no activation slot")
+        if not keep.all():
+            k, shards, slots = k[keep], shards[keep], slots[keep]
+        return k.tolist(), shards, slots
+
+    def _gather(self, tbl, shards: np.ndarray, slots: np.ndarray
+                ) -> dict[str, np.ndarray]:
+        """The rows at (shards, slots) as host columns: one compiled
+        gather over the whole state tree, its index array padded to the
+        engine's power-of-two bucket (slot (0, 0) always exists; the
+        padding rows are sliced off on the host). Call under the tick
+        fence."""
+        n = len(shards)
+        index = np.zeros((2, _bucket(n)), np.int32)
+        index[0, :n] = shards
+        index[1, :n] = slots
+        host = jax.device_get(_gather_rows(tbl.state, index))
+        return {f: v[:n] for f, v in host.items()}
+
+    @staticmethod
+    def _rows(host: dict[str, np.ndarray], n: int):
+        """Columns to per-row state dicts of Python-native values, lazily:
+        one ``tolist()`` per scalar field up front, and each row's dict
+        (and the list of a vector field) built when the consumer asks for
+        it, so that a consumer that lets go of a row before it takes the
+        next keeps the collector out of the pass — thousands of
+        containers held at once are thousands of survivors, promoted and
+        traversed again. Bool, integer and float dtypes survive the trip
+        to Python and back bit for bit (f32 -> float -> f32 and
+        i32 -> int -> i32 are exact; a signalling NaN comes back quiet);
+        a field of any other dtype keeps its numpy values."""
+        if not host:
+            return ({} for _ in range(n))
+        fields = tuple(host)
+        cols = [c if c.dtype.kind not in "biuf"
+                else c.tolist() if c.ndim == 1
+                else map(np.ndarray.tolist, c)
+                for c in host.values()]
+        return (dict(zip(fields, vals)) for vals in zip(*cols))
+
+    def _entries(self, kept: list[int], host: dict[str, np.ndarray]):
+        """``write_many``'s entries for the located keys, one at a time:
+        (grain id, row, the etag this bridge remembers or ADOPT_ETAG)."""
+        etag_of, gid = self._etags.get, self._grain_id
+        for key, row in zip(kept, self._rows(host, len(kept))):
+            etag = etag_of(key)
+            yield gid(key), row, ADOPT_ETAG if etag is None else etag
+
+    def _release_conflicted(self, tbl, key: int) -> None:
+        # another silo flushed this key since our last write: an
+        # ownership move happened (partition-era vote, failover,
+        # re-range). Reference semantics
+        # (InsideRuntimeClient.cs:390-402): the conflicted
+        # activation DEACTIVATES and rebuilds from storage on
+        # next touch — never overwrite. Overwriting would let a
+        # stale ex-owner silently REVERT durable state the live
+        # owner wrote (fatal once the key goes quiet: no later
+        # flush corrects it); releasing loses at most this
+        # silo's not-yet-durable tail, which is the documented
+        # write-behind loss window. The stale etag must also be
+        # dropped or it would wedge this key's flushes forever
+        self.storage_conflicts += 1
+        self._etags.pop(key, None)
+        if 0 <= key < tbl.dense_n:
+            tbl.dense_active[key] = False
+        else:
+            tbl.release(key)
+        logging.getLogger("orleans.vector").info(
+            "write-behind: etag conflict on key %d — row "
+            "released for rebuild from storage", key)
 
     async def flush(self, keys: Iterable[int], strict: bool = False) -> int:
-        """Write-behind: persist the current device rows for ``keys``.
-        One batched device→host gather, then per-actor etag'd writes.
+        """Write-behind: persist the current device rows for ``keys`` in
+        one columnar pass: one compiled device→host gather (``_gather``),
+        the columns turned into rows as the provider consumes them
+        (``_rows``), and one ``storage.write_many`` with each key's
+        remembered etag (or ``ADOPT_ETAG`` where this bridge has none: a
+        fresh bridge after a checkpoint restore has no etag memory but IS
+        the legitimate writer — the device row is the truth being
+        flushed).
+
+        What the loop does meanwhile is the provider's choice. One that
+        overrides ``write_many`` (``MemoryStorage``) takes the whole
+        batch in a single synchronous pass: no coroutine or task per row,
+        and nothing else runs on the loop until it returns. Every other
+        provider gets the base class's default, one concurrent
+        ``read``/``write`` per key, and the loop interleaves wherever
+        those suspend (never, under an eager task factory, for a
+        provider that does not await).
 
         Per-key failure isolation: keys whose activation slot is gone
         (released) are dropped with a log — there is no row left to
         persist — and keys whose storage write fails are re-marked dirty
         individually, so one bad key cannot wedge write-behind for the
-        whole class. Failures re-raise (after re-marking) when ``strict``
+        whole class. An etag conflict is not a failure: the row is
+        released for rebuild from storage (``_release_conflicted``).
+        Failures re-raise (after re-marking) when ``strict``
         is set OR when the runtime has no dirty tracking to hold the
         retry — a standalone bridge must never report silent success.
 
         With the runtime's stage metrics on, three spans of unit
         ``flush=<n>``: flush.locate and flush.gather (both holding the
-        fence, so ticks wait for them) and flush.write (the per-key
-        writes, interleaved with whatever else the loop runs)."""
-        keys = [int(k) for k in keys]
-        if not keys:
+        fence, so ticks wait for them) and flush.write (building the
+        rows and the provider's ``write_many``)."""
+        keys = np.asarray(keys if isinstance(keys, np.ndarray)
+                          else list(keys), np.int64)
+        if not keys.size:
             return 0
         tbl = self.runtime.table(self.grain_class)
         st = self.runtime.stats
@@ -258,61 +357,26 @@ class VectorStorageBridge:
                 return 0
             with StageSpan(st, "flush.gather", flush=n, rows=len(kept)) \
                     if st is not None else NO_SPAN:
-                host = {f: np.asarray(a[shards, slots])
-                        for f, a in tbl.state.items()}
-
-        async def write_one(i: int, key: int) -> None:
-            state = {f: host[f][i] for f in host}
-            etag = self._etags.get(key)
-            if etag is None:
-                # adopt the stored etag (a fresh bridge after a checkpoint
-                # restore has no etag memory but IS the legitimate writer —
-                # the device row is the truth being flushed)
-                _, etag = await self.storage.read(
-                    self.grain_type, self._grain_id(key))
-            try:
-                etag = await self.storage.write(
-                    self.grain_type, self._grain_id(key), state, etag)
-            except InconsistentStateError:
-                # another silo flushed this key since our last write: an
-                # ownership move happened (partition-era vote, failover,
-                # re-range). Reference semantics
-                # (InsideRuntimeClient.cs:390-402): the conflicted
-                # activation DEACTIVATES and rebuilds from storage on
-                # next touch — never overwrite. Overwriting would let a
-                # stale ex-owner silently REVERT durable state the live
-                # owner wrote (fatal once the key goes quiet: no later
-                # flush corrects it); releasing loses at most this
-                # silo's not-yet-durable tail, which is the documented
-                # write-behind loss window. The stale etag must also be
-                # dropped or it would wedge this key's flushes forever
-                self.storage_conflicts += 1
-                self._etags.pop(key, None)
-                if 0 <= key < tbl.dense_n:
-                    tbl.dense_active[key] = False
-                else:
-                    tbl.release(key)
-                logging.getLogger("orleans.vector").info(
-                    "write-behind: etag conflict on key %d — row "
-                    "released for rebuild from storage", key)
-                raise _ConflictReleased(key) from None
-            self._etags[key] = etag
+                host = self._gather(tbl, shards, slots)
 
         with StageSpan(st, "flush.write", nest=False, flush=n) \
                 if st is not None else NO_SPAN:
-            results = await asyncio.gather(
-                *(write_one(i, k) for i, k in enumerate(kept)),
-                return_exceptions=True)
-        conflicts = [r.key for r in results
-                     if isinstance(r, _ConflictReleased)]
-        failed = [k for k, r in zip(kept, results)
-                  if isinstance(r, BaseException)
-                  and not isinstance(r, _ConflictReleased)]
+            results = await self.storage.write_many(
+                self.grain_type, self._entries(kept, host))
+        etags = self._etags
+        failed, first, conflicts = [], None, 0
+        for key, r in zip(kept, results):
+            if not isinstance(r, BaseException):
+                etags[key] = r
+            elif isinstance(r, InconsistentStateError):
+                self._release_conflicted(tbl, key)
+                conflicts += 1
+            else:
+                failed.append(key)
+                if first is None:
+                    first = r
         if failed:
             self.runtime._mark_dirty(self.grain_class, failed)
-            first = next(r for r in results
-                         if isinstance(r, BaseException)
-                         and not isinstance(r, _ConflictReleased))
             logging.getLogger("orleans.vector").warning(
                 "write-behind: %d/%d key writes failed (re-marked): %r",
                 len(failed), len(kept), first)
@@ -321,7 +385,7 @@ class VectorStorageBridge:
                 # demanded completeness — the final stop() drain): surface
                 # the failure instead of reporting partial success
                 raise first
-        return len(kept) - len(failed) - len(conflicts)
+        return len(kept) - len(failed) - conflicts
 
     async def load(self, keys: Iterable[int]) -> list[int]:
         """Resume: read stored rows and scatter them into the table.
@@ -363,7 +427,11 @@ class VectorStorageBridge:
         # rehydrated rows)
         with self.runtime.tick_fence():
             for f, arr in tbl.state.items():
-                vals = np.stack([np.asarray(s[f]) for _, s, _ in found])
+                # in the table's own dtype: a record holds Python-native
+                # values (exact for the dtypes _rows converts) or, from
+                # before the columnar flush, numpy ones
+                vals = np.stack([np.asarray(s[f], arr.dtype)
+                                 for _, s, _ in found])
                 tbl.state[f] = tbl._put(arr.at[shards, slots].set(
                     jax.numpy.asarray(vals)))
         return fkeys

@@ -9,6 +9,17 @@ Etag protocol: every stored record carries an opaque etag; writes must present
 the etag from the last read/write or fail with InconsistentStateError, which
 deactivates the activation (InsideRuntimeClient.cs:390-402) — resume = rebuild
 from storage on the next call.
+
+Batched write: ``GrainStorage.write_many(grain_type, entries)`` takes an
+iterable of (grain id, state, etag) entries of one grain type and returns,
+per entry, the new etag or the exception — the same per-key compare-and-swap,
+with ``ADOPT_ETAG`` standing for "whatever etag the store holds". The write-behind
+flush of the device tier (``storage.checkpoint.VectorStorageBridge``) writes
+through it. Its default in the base class is one concurrent ``read``/``write``
+per key, so a provider that knows only per-key operations (``FileStorage``,
+the fault- and latency-injecting wrappers, a user's own) keeps its per-key
+latency, faults and interleaving; ``MemoryStorage`` overrides it with a single
+synchronous pass over its dict.
 """
 
 from __future__ import annotations
@@ -30,7 +41,22 @@ if TYPE_CHECKING:
 __all__ = [
     "GrainStorage", "MemoryStorage", "FileStorage", "StorageManager",
     "StateStorageBridge", "ErrorInjectionStorage", "LatencyStorage",
+    "ADOPT_ETAG",
 ]
+
+
+class _AdoptEtag:
+    """``ADOPT_ETAG``: in a ``write_many`` entry, "present whatever etag the
+    store holds for this key" — the writer has no etag memory but is the
+    legitimate writer."""
+
+    __slots__ = ()
+
+    def __repr__(self) -> str:
+        return "ADOPT_ETAG"
+
+
+ADOPT_ETAG = _AdoptEtag()
 
 
 class GrainStorage:
@@ -51,6 +77,27 @@ class GrainStorage:
     async def clear(self, grain_type: str, grain_id: GrainId,
                     etag: str | None) -> None:
         raise NotImplementedError
+
+    async def write_many(self, grain_type: str, entries) -> list:
+        """CAS-write many records of one grain type. ``entries`` is an
+        iterable of ``(grain_id, state, etag)``, ``etag`` as in ``write``
+        or ``ADOPT_ETAG``; it may be a generator that builds each state
+        as it is asked for, so iterate it once. Returns a list with one
+        item per entry, in order: the new etag, or the exception that
+        entry raised (``InconsistentStateError`` on an etag mismatch) —
+        one entry's failure never fails another's.
+
+        This default is per-key: every entry runs its own ``read`` (only
+        to adopt) and ``write`` concurrently, so latency, injected faults
+        and interleaving stay those of the provider's per-key methods. A
+        provider that can do better in bulk overrides it."""
+        async def one(grain_id: GrainId, state: Any, etag) -> str:
+            if etag is ADOPT_ETAG:
+                _, etag = await self.read(grain_type, grain_id)
+            return await self.write(grain_type, grain_id, state, etag)
+
+        return await asyncio.gather(*(one(*e) for e in entries),
+                                    return_exceptions=True)
 
 
 def _key(grain_type: str, grain_id: GrainId) -> tuple:
@@ -86,6 +133,41 @@ class MemoryStorage(GrainStorage):
         new_etag = f"e{next(self._etag_seq)}"
         self._data[k] = (serialize(state), new_etag)
         return new_etag
+
+    def __init_subclass__(cls, **kwargs) -> None:
+        # write_many below goes to the dict directly: a subclass that
+        # brings its own read or write (to count, fail or delay) and no
+        # write_many of its own gets the per-key default back, so its
+        # methods see every record
+        super().__init_subclass__(**kwargs)
+        if "write_many" not in vars(cls) and vars(cls).keys() & {
+                "read", "write"}:
+            cls.write_many = GrainStorage.write_many
+
+    async def write_many(self, grain_type, entries):
+        """The whole batch in one synchronous pass over the dict: no
+        coroutine per key, an adopting entry needs no read, and each
+        state is serialised and let go before the next is taken."""
+        data, seq = self._data, self._etag_seq
+        out = []
+        for grain_id, state, etag in entries:
+            k = _key(grain_type, grain_id)
+            cur = data.get(k)
+            cur_etag = cur[1] if cur else None
+            if etag is not ADOPT_ETAG and etag != cur_etag:
+                out.append(InconsistentStateError(
+                    f"etag mismatch for {grain_id}", stored_etag=cur_etag,
+                    current_etag=etag))
+                continue
+            try:
+                blob = serialize(state)
+            except Exception as e:  # noqa: BLE001 — this entry's result
+                out.append(e)
+                continue
+            new_etag = f"e{next(seq)}"
+            data[k] = (blob, new_etag)
+            out.append(new_etag)
+        return out
 
     async def clear(self, grain_type, grain_id, etag):
         k = _key(grain_type, grain_id)

@@ -175,20 +175,21 @@ class TestVectorStorageBridge:
         # the rest of the batch still persists
         rt = _runtime(8)
         rt.enable_dirty_tracking()
-        storage = MemoryStorage()
+
+        # a subclass that brings its own write gets the per-key default
+        # write_many back (MemoryStorage's batched pass never calls write)
+        class FlakyStorage(MemoryStorage):
+            async def write(self, grain_type, grain_id, state, etag):
+                if grain_id.key == 2:
+                    raise RuntimeError("injected storage fault")
+                return await super().write(grain_type, grain_id, state, etag)
+
+        storage = FlakyStorage()
         bridge = VectorStorageBridge(rt, CounterGrain, storage)
+        assert not bridge.batched
         tbl = rt.table(CounterGrain)
         for k in (1, 2, 3):
             tbl.lookup_or_allocate(k)
-
-        real_write = storage.write
-
-        async def flaky_write(grain_type, grain_id, state, etag):
-            if grain_id.key == 2:
-                raise RuntimeError("injected storage fault")
-            return await real_write(grain_type, grain_id, state, etag)
-
-        storage.write = flaky_write
         rt.drain_dirty(CounterGrain)  # clear allocation dirt
         assert await bridge.flush([1, 2, 3]) == 2
         # only the failed key was re-marked for the next period

@@ -27,6 +27,7 @@ from orleans_tpu.observability.stats import (FLUSH_STATS, STAGES, StageSpan,
 from orleans_tpu.parallel import make_mesh
 from orleans_tpu.runtime import GatewayClient, SiloBuilder, SocketFabric
 from orleans_tpu.storage import MemoryStorage
+from orleans_tpu.storage.checkpoint import _gather_rows
 
 N_KEYS = 16
 ROUNDS = 4
@@ -197,7 +198,7 @@ async def test_metrics_off_registers_none_of_the_new_names():
 
 
 async def test_compiles_are_booked_to_the_stage_that_compiled():
-    """A gather of a length the process has not seen compiles under
+    """A gather in a size bucket the process has not seen compiles under
     flush.gather; a tick at a warm bucket books nothing."""
     silo = _build(True, True, MemoryStorage(), period=3600.0)
     await silo.start()
@@ -216,13 +217,15 @@ async def test_compiles_are_booked_to_the_stage_that_compiled():
         await _rounds(client, range(8, 16), rounds=1)  # same bucket: warm
         assert compiles("compile.ingest.") + compiles("compile.engine.") \
             == tick0
-        for n in (13, 11):  # two gather lengths new to the process
+        _gather_rows.clear_cache()  # whatever other tests left behind
+        for n in (13, 17):  # two gather buckets (16, 32) new to the process
             before = compiles("compile.flush.gather")
             assert await bridge.flush(range(n)) == n
             assert compiles("compile.flush.gather") > before
         assert compiles("compile.flush.") == compiles("compile.flush.gather")
         before = compiles("compile.flush.gather")
-        assert await bridge.flush(range(11)) == 11  # a length seen: none
+        for n in (11, 16, 9, 31):  # new lengths inside those buckets: none
+            assert await bridge.flush(range(n)) == n
         assert compiles("compile.flush.gather") == before
     finally:
         await client.close_async()
