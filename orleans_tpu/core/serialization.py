@@ -329,7 +329,17 @@ def _load_hotwire():
 
     hw.configure(GrainId, cat_members, SiloAddress, ActivationId,
                  ActivationAddress, _escape_dumps, _restricted_pickle_loads)
+    hw.configure_arrays(np.ndarray, np.generic, _nd_restore)
     return hw
+
+
+def _nd_restore(code: str, shape: tuple, data: bytes, scalar: bool):
+    """Rebuild one numpy value from hotwire's array tag: what pickle would
+    have given back — an array of its own (writable) memory, or the numpy
+    scalar — without pickle on the wire. ``code`` is a little-endian
+    dtype string of bool / int / uint / float items (``"<i4"``)."""
+    a = np.frombuffer(data, code)
+    return a[0] if scalar else a.reshape(shape).copy()
 
 
 _hotwire = _load_hotwire()

@@ -51,6 +51,8 @@ _QUEUE_WAIT = _INGEST["queue_wait"]
 _TICK = _INGEST["tick"]
 _MESSAGES = _INGEST["messages"]
 _WORKER_QUEUE = "engine.worker_queue.seconds"
+_DEFERRED = "engine.deferred"               # counter: msgs deferred >= once
+_DEFER_WAIT = "engine.defer_wait.seconds"   # first deferral -> claimed
 _COMPLETE_HOP = "engine.complete_hop.seconds"
 # worker-side ledger stamp (cost attribution, observability.ledger): the
 # payload rides the job's deferred-stats list and replays loop-side in
@@ -175,7 +177,8 @@ class _StagingSet:
     ingest never allocates — and never touches a buffer whose device
     upload could still be in flight."""
 
-    __slots__ = ("slots", "khash", "fresh", "valid", "args", "used", "sink")
+    __slots__ = ("slots", "khash", "fresh", "valid", "args", "used", "sink",
+                 "scalars", "arrays")
 
     def __init__(self, n: int, B: int, sink: int, schema: dict):
         self.slots = np.full((n, B), sink, dtype=np.int32)
@@ -184,6 +187,13 @@ class _StagingSet:
         self.valid = np.zeros((n, B), dtype=bool)
         self.args = {f: np.zeros((n, B, *shape), dtype=dtype)
                      for f, (dtype, shape) in schema.items()}
+        # the fill loop's two kinds of field: scalars are assigned as
+        # they come; an array field may arrive as ``bytes`` (the wire's
+        # native tag for a byte string), which numpy cannot assign to a
+        # row, so those carry what ``np.frombuffer`` needs
+        self.scalars = [(f, a) for f, a in self.args.items() if a.ndim == 2]
+        self.arrays = [(f, a, a.dtype, a.shape[2:], a[0, 0].nbytes)
+                       for f, a in self.args.items() if a.ndim > 2]
         self.used = [0] * n  # lanes filled per shard on the LAST use
         self.sink = sink     # the junk row every idle lane points at
 
@@ -227,14 +237,15 @@ class _Pending:
     in-process calls."""
 
     __slots__ = ("key_hash", "shard", "slot", "fresh", "args", "future",
-                 "t_enq", "trace", "origin")
+                 "t_enq", "trace", "origin", "t_defer")
 
-    def __init__(self, key_hash, shard, slot, fresh, args, future,
+    def __init__(self, key_hash, shard, slot, args, future,
                  t_enq=0.0, trace=None, origin=None):
         self.key_hash = key_hash
         self.shard = shard
         self.slot = slot
-        self.fresh = fresh
+        self.fresh = False  # decided when a tick claims it (_claim)
+        self.t_defer = 0.0  # perf_counter of the first deferral
         self.args = args
         self.future = future
         self.t_enq = t_enq
@@ -511,16 +522,20 @@ class VectorRuntime:
         if 0 <= key_hash < tbl.dense_n:
             shard = key_hash // tbl.dense_per_shard
             slot = key_hash % tbl.dense_per_shard
-            # first touch of a dense-provisioned key still needs its
-            # on-device initial_state (the OnActivate analog)
-            fresh = not bool(tbl.dense_active[key_hash])
-            tbl.dense_active[key_hash] = True
+            # first WRITE to a dense-provisioned key activates it; the
+            # row still needs its on-device initial_state (the
+            # OnActivate analog), which the claim hands out (_claim)
+            if not m.read_only and not tbl.dense_active[key_hash]:
+                tbl.dense_active[key_hash] = True
+                tbl.uninit.add(key_hash)
         else:
             shard, slot, fresh = tbl.lookup_or_allocate(key_hash)
+            if fresh:
+                tbl.uninit.add(key_hash)
         loop = asyncio.get_running_loop()
         fut = loop.create_future()
         self.pending.setdefault((grain_class, method), []).append(
-            _Pending(key_hash, shard, slot, fresh, args, fut,
+            _Pending(key_hash, shard, slot, args, fut,
                      time.monotonic()
                      if (self.stats is not None
                          or self.shed_trend is not None) else 0.0))
@@ -562,6 +577,7 @@ class VectorRuntime:
         # all-failed group never leaves an empty pending entry behind (a
         # tick over it would crash first-batch schema inference)
         dense_n, per = tbl.dense_n, tbl.dense_per_shard
+        writes, active, uninit = not m.read_only, tbl.dense_active, tbl.uninit
         futs: list = []
         idx = -1
         for key_hash, args, want_future in items:
@@ -574,10 +590,13 @@ class VectorRuntime:
                 if 0 <= key_hash < dense_n:
                     shard = key_hash // per
                     slot = key_hash % per
-                    fresh = not bool(tbl.dense_active[key_hash])
-                    tbl.dense_active[key_hash] = True
+                    if writes and not active[key_hash]:
+                        active[key_hash] = True
+                        uninit.add(key_hash)
                 else:
                     shard, slot, fresh = tbl.lookup_or_allocate(key_hash)
+                    if fresh:
+                        uninit.add(key_hash)
             except Exception as e:  # noqa: BLE001 — schema violation or
                 # slot-allocation failure: scoped to THIS item (a raise
                 # escaping mid-loop would error-bounce the whole group
@@ -587,7 +606,7 @@ class VectorRuntime:
                 continue
             if pend is None:
                 pend = self.pending.setdefault((grain_class, method), [])
-            pend.append(_Pending(key_hash, shard, slot, fresh, args, fut,
+            pend.append(_Pending(key_hash, shard, slot, args, fut,
                                  t_enq,
                                  traces[idx] if traces is not None else None,
                                  origin))
@@ -928,7 +947,7 @@ class VectorRuntime:
             else:
                 self._record_tick_span(job.span, job.ready)
                 self._resolve_batch(job.ready, job.per_shard, host,
-                                    job.tick)
+                                    job.tick, job.cls, job.method)
         except BaseException as e2:  # noqa: BLE001 — fail futures, not loop
             log.exception("vector tick completion failed for %s.%s",
                           job.cls.__name__, job.method)
@@ -1015,17 +1034,51 @@ class VectorRuntime:
                items: list[_Pending]) -> list[_Pending]:
         """Turn-semantics claim, always loop-side (it mutates
         ``self.pending``): one message per slot per tick; same-slot
-        conflicts defer to the next tick."""
+        conflicts defer to the next tick.
+
+        Freshness is decided here and not at enqueue, because claims
+        happen in the order in which the kernels run (the worker is
+        FIFO). A row is initialised by the first WRITING method claimed
+        for it: the enqueue of that write put the key in ``tbl.uninit``,
+        and until a write is claimed every lane for the key starts from
+        ``initial_state`` — a read claimed ahead of it, in this tick or
+        an earlier one, included. A read-only method writes nothing
+        back, so it neither activates a dense key nor clears the mark: a
+        read of a record nothing has written derives its initial state
+        again, and leaves nothing dirty behind."""
+        tbl = self.tables[cls]
+        writes = not tbl.methods[method].read_only
+        active, dense_n, uninit = tbl.dense_active, tbl.dense_n, tbl.uninit
+        st = self.stats
+        now = time.perf_counter() if st is not None else 0.0
         claimed: set[tuple[int, int]] = set()
         ready: list[_Pending] = []
+        deferred: list[_Pending] | None = None
+        first_deferrals = 0
         for p in items:
             loc = (p.shard, p.slot)
             if loc in claimed:
-                self.pending.setdefault((cls, method), []).append(p)
+                if deferred is None:
+                    deferred = self.pending.setdefault((cls, method), [])
+                deferred.append(p)
                 self.conflicts_deferred += 1
+                if st is not None and not p.t_defer:
+                    p.t_defer = now
+                    first_deferrals += 1
                 continue
             claimed.add(loc)
+            k = p.key_hash
+            if uninit and k in uninit:
+                p.fresh = True
+                if writes:
+                    uninit.discard(k)
+            elif not writes and 0 <= k < dense_n and not active[k]:
+                p.fresh = True
+            if p.t_defer:
+                st.observe(_DEFER_WAIT, now - p.t_defer)
             ready.append(p)
+        if st is not None:
+            st.increment(_DEFERRED, first_deferrals)  # 0 too: it exists
         return ready
 
     def _run_batch(self, cls: type, method: str, ready: list[_Pending],
@@ -1047,11 +1100,15 @@ class VectorRuntime:
                 StageSpan.unwind()  # a loop callback starts with none open
             raise
         self._record_tick_span(span, ready)
-        self._resolve_batch(ready, per_shard, host, self.ticks)
+        self._resolve_batch(ready, per_shard, host, self.ticks, cls, method)
 
     def _resolve_batch(self, ready: list[_Pending], per_shard,
-                       host, tick: int = 0) -> None:
+                       host, tick: int, cls: type, method: str) -> None:
         st = self.stats
+        if st is not None:
+            # the mix that reached the device, by (class, method); their
+            # sum is ``ingest.messages``
+            st.increment(f"{_MESSAGES}.{cls.__name__}.{method}", len(ready))
         with StageSpan(st, "engine.resolve", tick=tick) \
                 if st is not None else NO_SPAN:
             for s, ps in enumerate(per_shard):
@@ -1124,6 +1181,7 @@ class VectorRuntime:
         slots, khash = stg.slots, stg.khash
         fresh, valid = stg.fresh, stg.valid
         args_stacked = stg.args
+        scalars, arrays = stg.scalars, stg.arrays
         for s, ps in enumerate(per_shard):
             stg.used[s] = len(ps)
             for i, p in enumerate(ps):
@@ -1133,8 +1191,20 @@ class VectorRuntime:
                 khash[s, i] = p.key_hash & 0x7FFFFFFF
                 fresh[s, i] = p.fresh
                 valid[s, i] = True
-                for fname in schema:
-                    args_stacked[fname][s, i] = p.args[fname]
+                args = p.args
+                for fname, buf in scalars:
+                    buf[s, i] = args[fname]
+                for fname, buf, dtype, shape, nbytes in arrays:
+                    v = args[fname]
+                    if type(v) is bytes:
+                        # one memcpy into the staging row, no Python
+                        # object per element
+                        if len(v) != nbytes:
+                            raise ValueError(
+                                f"{cls.__name__}.{method}: {fname!r} "
+                                f"takes {nbytes} bytes, got {len(v)}")
+                        v = np.frombuffer(v, dtype).reshape(shape)
+                    buf[s, i] = v
         self.staging_fill = len(ready)
         if lp is not None:
             # staging done: operand upload + kernel dispatch next
@@ -1236,8 +1306,11 @@ class VectorRuntime:
             # (free on CPU, where the transfer copies synchronously).
             # This sync is ALSO the off-loop staging pin: the worker runs
             # batches FIFO, so by the time a staging set rotates back its
-            # tick has provably synced here.
-            jax.block_until_ready(new_state)
+            # tick has provably synced here. (A read-only kernel returns
+            # no state: its operands are not donated, so they are what
+            # there is to wait for.)
+            jax.block_until_ready(
+                kernel_args[1:] if m.read_only else new_state)
         if st is not None:
             # tick closes AFTER the host transfer for the same reason the
             # span timing does: jax dispatch is async, and the np.asarray
@@ -2318,7 +2391,10 @@ class VectorRuntime:
                 lambda ir, r: sel(fresh_l, ir, r), init_rows, rows)
             new_rows, results = jax.vmap(handler)(rows, args_l)
             if read_only:
-                out_state = state
+                # no state output: a table passed through a jit that
+                # does not donate it comes back as a COPY, a whole-table
+                # read and write a tick and a second table's memory
+                out_state = ()
             else:
                 write = valid_l
                 new_state_l = jax.tree_util.tree_map(
@@ -2386,8 +2462,11 @@ class VectorRuntime:
 
                 def one(carry, args_k):
                     return scan_step(carry, slots, valid, args_k)
-                return lax.scan(one, state, args_rounds,
-                                unroll=max(1, self.scan_unroll))
+                out_state, results = lax.scan(
+                    one, state, args_rounds,
+                    unroll=max(1, self.scan_unroll))
+                # as in local_step: nothing was written, return no table
+                return (() if read_only else out_state), results
 
             body = scanned
         else:

@@ -110,6 +110,11 @@ class ShardedActorTable:
         self.dense_n = 0  # keys [0, dense_n) are dense-mapped
         self.dense_per_shard = 0
         self.dense_active = np.zeros(0, dtype=bool)
+        # keys the per-key path has activated (a dense key's first write
+        # enqueued, a hashed key given its slot) for whose row no writing
+        # tick has been claimed yet: their lanes start from
+        # initial_state (VectorRuntime._claim)
+        self.uninit: set[int] = set()
 
         # device state: [n_shards, capacity+1, *shape]; row `capacity` is the
         # padding write sink
@@ -355,6 +360,7 @@ class ShardedActorTable:
         self.free[loc[0]].append(loc[1])
         self.device_dir.remove(key_hash)
         self.route_hash.pop(key_hash, None)
+        self.uninit.discard(key_hash)
         return True
 
     def move_rows(self, keys, dest_shards) -> int:

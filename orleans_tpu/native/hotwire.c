@@ -42,6 +42,7 @@
 #define HW_MAGIC 0xA7
 #define HW_VERSION 0x01
 #define HW_MAX_DEPTH 200
+#define MAX_ND_BYTES (1ULL << 40)  /* sanity cap on an announced array */
 
 /* value tags */
 enum {
@@ -64,6 +65,9 @@ enum {
     T_ACTIVATION_ID = 0x0F,  /* value varint */
     T_ACTIVATION_ADDR = 0x10,/* silo value, grain value, activation value */
     T_PICKLE = 0x11,   /* varint len + pickle bytes (restricted loader) */
+    T_NDARRAY = 0x12,  /* kind byte ('b' bool,'i','u','f'), itemsize byte,
+                          flags byte (1 = numpy scalar), ndim varint, dims
+                          varints, raw little-endian C-order data */
 };
 
 /* ------------------------------------------------------------------ */
@@ -77,6 +81,11 @@ typedef struct {
     PyObject *act_addr_cls;
     PyObject *pickle_dumps;      /* callable(obj) -> bytes */
     PyObject *pickle_loads;      /* callable(bytes) -> obj (restricted) */
+    /* numpy values ride as raw buffers (configure_arrays): exactly
+     * np.ndarray, and np.generic scalars; rebuilt by nd_restore(code,
+     * shape, data, is_scalar).  Unset = they take the pickle escape. */
+    PyObject *nd_array_cls, *nd_scalar_cls, *nd_restore;
+    unsigned long long escapes;  /* values that took T_PICKLE, both ways */
     /* interned field-name strings for fast instance-dict fills */
     PyObject *s_category, *s_type_code, *s_key, *s_key_ext, *s_hash64;
     PyObject *s_host, *s_port, *s_generation, *s_mesh_index, *s_uh;
@@ -158,11 +167,70 @@ static int enc_pickle(W *w, PyObject *obj) {
     }
     PyObject *data = PyObject_CallOneArg(g_state.pickle_dumps, obj);
     if (!data) return -1;
+    g_state.escapes++;
     char *p; Py_ssize_t n;
     if (PyBytes_AsStringAndSize(data, &p, &n) < 0) { Py_DECREF(data); return -1; }
     int rc = (w_byte(w, T_PICKLE) < 0 || w_varint(w, (uint64_t)n) < 0 ||
               w_raw(w, p, n) < 0) ? -1 : 0;
     Py_DECREF(data);
+    return rc;
+}
+
+/* A numpy array or scalar as its raw buffer.  Returns 1 when written, 0
+ * when this value is not one the tag carries (the caller escapes it to
+ * pickle), -1 on error.  Carried: native little-endian bool / int / uint
+ * / float items of 1, 2, 4 or 8 bytes, in C order on the wire.  An array
+ * in any other order is packed here (one .copy()), and here only: the
+ * TPU hands a wide result batch back column-major from 128 lanes up, so
+ * each reply -- one row of it -- arrives strided, and its producers
+ * (VectorRuntime._execute_batch, the write-behind gather) pass it on as
+ * it comes. */
+static int enc_ndarray(W *w, PyObject *obj, int is_scalar) {
+#if PY_BIG_ENDIAN
+    return 0;
+#endif
+    Py_buffer v;
+    PyObject *packed = NULL;  /* a C-order copy of a strided array */
+    if (PyObject_GetBuffer(obj, &v, PyBUF_FORMAT | PyBUF_C_CONTIGUOUS) < 0) {
+        PyErr_Clear();  /* strided, or a dtype with no buffer form */
+        if (is_scalar) return 0;
+        packed = PyObject_CallMethod(obj, "copy", NULL);
+        if (!packed) return -1;
+        if (PyObject_GetBuffer(packed, &v,
+                               PyBUF_FORMAT | PyBUF_C_CONTIGUOUS) < 0) {
+            PyErr_Clear();
+            Py_DECREF(packed);
+            return 0;
+        }
+    }
+    int rc = 0;
+    const char *f = v.format ? v.format : "B";
+    if (*f == '<' || *f == '=' || *f == '@' || *f == '|') f++;
+    char kind = 0;
+    if (f[0] && !f[1]) {
+        if (f[0] == '?') kind = 'b';
+        else if (strchr("bhilq", f[0])) kind = 'i';
+        else if (strchr("BHILQ", f[0])) kind = 'u';
+        else if (strchr("efd", f[0])) kind = 'f';
+    }
+    Py_ssize_t isz = v.itemsize;
+    /* a numeric numpy scalar exports ndim 0; datetime64, bytes_ and the
+       like export their bytes as a 1-d 'B' buffer and are not carried */
+    if (kind && (isz == 1 || isz == 2 || isz == 4 || isz == 8) &&
+        v.ndim <= 32 && (v.ndim == 0 || (v.shape && !is_scalar))) {
+        rc = -1;
+        if (w_byte(w, T_NDARRAY) == 0 && w_byte(w, (uint8_t)kind) == 0 &&
+            w_byte(w, (uint8_t)isz) == 0 &&
+            w_byte(w, is_scalar ? 1 : 0) == 0 &&
+            w_varint(w, (uint64_t)v.ndim) == 0) {
+            rc = 1;
+            for (int i = 0; i < v.ndim && rc == 1; i++)
+                if (w_varint(w, (uint64_t)v.shape[i]) < 0) rc = -1;
+            if (rc == 1 && w_raw(w, (const char *)v.buf, v.len) < 0) rc = -1;
+        }
+    }
+    PyBuffer_Release(&v);
+    Py_XDECREF(packed);
     return rc;
 }
 
@@ -347,8 +415,17 @@ static int enc_value(W *w, PyObject *obj, int depth) {
             return enc_obj_field(w, obj, g_state.s_activation, depth + 1);
         }
     }
-    /* anything else (enums, user dataclasses, exceptions, ndarrays):
-       per-value restricted-pickle escape */
+    if (g_state.nd_restore) {
+        int is_scalar = 0;
+        if ((PyObject *)t == g_state.nd_array_cls ||
+            (is_scalar = PyType_IsSubtype(
+                t, (PyTypeObject *)g_state.nd_scalar_cls))) {
+            int rc = enc_ndarray(w, obj, is_scalar);
+            if (rc != 0) return rc < 0 ? -1 : 0;
+        }
+    }
+    /* anything else (enums, user dataclasses, exceptions, arrays the
+       tag above does not carry): per-value restricted-pickle escape */
     return enc_pickle(w, obj);
 }
 
@@ -625,6 +702,56 @@ static PyObject *dec_value(R *r, int depth) {
         r->p += n;
         PyObject *v = PyObject_CallOneArg(g_state.pickle_loads, b);
         Py_DECREF(b);
+        g_state.escapes++;
+        return v;
+    }
+    case T_NDARRAY: {
+        if (!g_state.nd_restore) goto unconfigured;
+        if (r_need(r, 3) < 0) return NULL;
+        uint8_t kind = r->p[0], isz = r->p[1], flags = r->p[2];
+        r->p += 3;
+        uint64_t ndim;
+        if (r_varint(r, &ndim) < 0) return NULL;
+        if (!strchr("biuf", kind) || !kind ||
+            !(isz == 1 || isz == 2 || isz == 4 || isz == 8) ||
+            ndim > 32 || flags > 1) {
+            PyErr_SetString(PyExc_ValueError, "hotwire: bad array header");
+            return NULL;
+        }
+        PyObject *shape = PyTuple_New((Py_ssize_t)ndim);
+        if (!shape) return NULL;
+        uint64_t count = 1;
+        for (uint64_t i = 0; i < ndim; i++) {
+            uint64_t d;
+            if (r_varint(r, &d) < 0) { Py_DECREF(shape); return NULL; }
+            /* the data must fit in what is left of the buffer, which also
+               keeps the running product from overflowing */
+            if (d > (uint64_t)MAX_ND_BYTES ||
+                (d && count > (uint64_t)MAX_ND_BYTES / d)) {
+                Py_DECREF(shape);
+                PyErr_SetString(PyExc_ValueError,
+                                "hotwire: array too large");
+                return NULL;
+            }
+            count *= d;
+            PyObject *di = PyLong_FromUnsignedLongLong(d);
+            if (!di) { Py_DECREF(shape); return NULL; }
+            PyTuple_SET_ITEM(shape, (Py_ssize_t)i, di);
+        }
+        uint64_t nbytes = count * isz;
+        if (nbytes > (uint64_t)(r->end - r->p)) {
+            Py_DECREF(shape);
+            PyErr_SetString(PyExc_ValueError, "hotwire: truncated buffer");
+            return NULL;
+        }
+        char code[4] = { '<', (char)kind, (char)('0' + isz), 0 };
+        if (isz == 1) code[0] = '|';
+        PyObject *v = PyObject_CallFunction(
+            g_state.nd_restore, "sOy#O", code, shape,
+            (const char *)r->p, (Py_ssize_t)nbytes,
+            flags ? Py_True : Py_False);
+        Py_DECREF(shape);
+        if (v) r->p += nbytes;
         return v;
     }
     default:
@@ -711,6 +838,33 @@ static PyObject *hw_configure(PyObject *self, PyObject *args) {
 #undef INTERN
     s->configured = 1;
     Py_RETURN_NONE;
+}
+
+/* configure_arrays(ndarray_cls, scalar_cls, restore): numpy values ride
+ * T_NDARRAY from here on; restore(code, shape, data, is_scalar) rebuilds
+ * one.  Kept apart from configure() so that the id types never wait for
+ * numpy. */
+static PyObject *hw_configure_arrays(PyObject *self, PyObject *args) {
+    PyObject *arr_cls, *scalar_cls, *restore;
+    if (!PyArg_ParseTuple(args, "OOO", &arr_cls, &scalar_cls, &restore))
+        return NULL;
+    if (!PyType_Check(arr_cls) || !PyType_Check(scalar_cls) ||
+        !PyCallable_Check(restore)) {
+        PyErr_SetString(PyExc_TypeError,
+                        "configure_arrays(type, type, callable)");
+        return NULL;
+    }
+    hw_state *s = &g_state;
+    Py_INCREF(arr_cls); Py_XSETREF(s->nd_array_cls, arr_cls);
+    Py_INCREF(scalar_cls); Py_XSETREF(s->nd_scalar_cls, scalar_cls);
+    Py_INCREF(restore); Py_XSETREF(s->nd_restore, restore);
+    Py_RETURN_NONE;
+}
+
+/* pickle_escapes() -> int: values this process has sent through, or
+ * taken out of, the per-value pickle escape since the module loaded. */
+static PyObject *hw_pickle_escapes(PyObject *self, PyObject *noargs) {
+    return PyLong_FromUnsignedLongLong(g_state.escapes);
 }
 
 /* Encode one already-fetched header-field value: top-level int
@@ -1735,6 +1889,12 @@ static PyMethodDef hw_methods[] = {
     {"configure", hw_configure, METH_VARARGS,
      "configure(GrainId, cat_members, SiloAddress, ActivationId, "
      "ActivationAddress, pickle_dumps, restricted_loads)"},
+    {"configure_arrays", hw_configure_arrays, METH_VARARGS,
+     "configure_arrays(ndarray, generic, restore): numpy arrays and "
+     "scalars ride as raw buffers, not through the pickle escape."},
+    {"pickle_escapes", hw_pickle_escapes, METH_NOARGS,
+     "pickle_escapes() -> int: values that took the per-value pickle "
+     "escape, encode and decode, since the module loaded."},
     {NULL, NULL, 0, NULL},
 };
 

@@ -134,6 +134,7 @@ EGRESS_STATS = {
     "build": "egress.build.seconds",
     "dwell": "egress.dwell.seconds",
     "encode": "egress.encode.seconds",
+    "encode_bytes": "egress.encode.bytes",    # SIZE_BOUNDS, per encode
     "group": "egress.flush_group.size",       # COUNT_BOUNDS histogram
     "responses": "egress.responses",          # counter: responses batched
     # counter: messages dropped at a FULL egress shard ring (bounded
@@ -218,6 +219,10 @@ SLO_STATS = {
 #
 #   pump.batch            one decoded socket read routed (loop)
 #   engine.claim          _tick's claim + submit loop (loop)
+#   engine.defer_wait     a message's first conflict deferral -> the
+#                         claim that takes it (histogram only, not in
+#                         STAGES: it spans ticks; beside it the counter
+#                         engine.deferred, messages deferred at least once)
 #   engine.worker_queue   _submit_job -> worker dequeue (histogram only:
 #                         the wait crosses threads)
 #   engine.fence_wait     worker dequeue -> tick fence acquired (worker)

@@ -41,6 +41,8 @@ from ..observability.stats import INGEST_STATS as _INGEST
 from ..observability.stats import SIZE_BOUNDS as _SIZE_BOUNDS
 
 _EGRESS_ENCODE = _EGRESS["encode"]
+_EGRESS_BYTES = _EGRESS["encode_bytes"]
+_PICKLED = "wire.pickled_values"   # counter: values through the escape
 _DECODE_SECONDS = _INGEST["decode"]
 _DECODE_BYTES = _INGEST["decode_bytes"]
 _FRAMES = _INGEST["frames"]
@@ -190,6 +192,11 @@ _HW_BATCH = _HW_FRAMES and hasattr(_ser._hotwire, "pack_batch")
 # memcpys the pre-encoded invariant runs and patches only the varying
 # fields — byte-identical to pack_frame (property-tested).
 _HW_TMPL = _HW_BATCH and hasattr(_ser._hotwire, "pack_batch_tmpl")
+# How many values this process has put through (or taken out of) the
+# per-value restricted-pickle escape; a metrics-enabled batch encode or
+# decode books its share as ``wire.pickled_values``. The pickle-only
+# fallback build has no escape to count: every body is one pickle.
+_escapes = getattr(_ser._hotwire, "pickle_escapes", None) or (lambda: 0)
 
 # The per-message (varying) header fields of a templated frame:
 # correlation id, the grain/activation endpoints, the per-class method
@@ -421,6 +428,7 @@ def encode_message_batch(msgs: list, bounce, native: bool = True,
     hw = _ser._hotwire if native else None
     if hw is not None and _HW_BATCH:
         now = time.monotonic()
+        esc0 = _escapes() if stats is not None else 0
         use_tmpl = templates and _HW_TMPL
         # ordered (template | None, items) runs: FIFO on the wire is
         # preserved because runs flush in arrival order
@@ -465,6 +473,9 @@ def encode_message_batch(msgs: list, bounce, native: bool = True,
                         bounce(m, e)
         if stats is not None and chunks:
             stats.observe(_EGRESS_ENCODE, time.monotonic() - now)
+            stats.histogram_with(_EGRESS_BYTES, _SIZE_BOUNDS).observe(
+                sum(map(len, chunks)))
+            stats.increment(_PICKLED, _escapes() - esc0)
         return chunks
     chunks = []
     for m in msgs:
@@ -531,6 +542,7 @@ def decode_frames(buf, stats=None) -> tuple[int, list, list]:
     batching degree lands in ``frame_batch``. Every decoded envelope is
     stamped with the same post-decode ``received_at``."""
     t0 = time.monotonic() if stats is not None else 0.0
+    esc0 = _escapes() if stats is not None else 0
     msgs: list[Message] = []
     bounces: list[_BodyDecodeError] = []
     consumed = 0
@@ -574,6 +586,7 @@ def decode_frames(buf, stats=None) -> tuple[int, list, list]:
         stats.observe(_DECODE_SECONDS, now - t0)
         stats.histogram_with(_DECODE_BYTES, _SIZE_BOUNDS).observe(consumed)
         stats.increment(_FRAMES, n)
+        stats.increment(_PICKLED, _escapes() - esc0)
         stats.histogram_with(_FRAME_BATCH, _COUNT_BOUNDS).observe(n)
         for m in msgs:
             m.received_at = now

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import math
 from typing import TYPE_CHECKING, Iterable
 
 import jax
@@ -40,6 +41,10 @@ if TYPE_CHECKING:
     from ..dispatch.engine import VectorRuntime
 
 __all__ = ["VectorCheckpointer", "VectorStorageBridge"]
+
+# a flushed row's vector field of up to this many values becomes a Python
+# list; a wider one stays a numpy row (VectorStorageBridge._rows)
+_LIST_MAX = 16
 
 
 @jax.jit
@@ -265,11 +270,16 @@ class VectorStorageBridge:
         traversed again. Bool, integer and float dtypes survive the trip
         to Python and back bit for bit (f32 -> float -> f32 and
         i32 -> int -> i32 are exact; a signalling NaN comes back quiet);
-        a field of any other dtype keeps its numpy values."""
+        a field of any other dtype keeps its numpy values, and so does a
+        vector field wider than ``_LIST_MAX`` values: its row stays one
+        numpy row (a view of the pass's column), which the wire codec
+        writes as one buffer, where a list would be a Python object and
+        a tag per value."""
         if not host:
             return ({} for _ in range(n))
         fields = tuple(host)
         cols = [c if c.dtype.kind not in "biuf"
+                or math.prod(c.shape[1:]) > _LIST_MAX
                 else c.tolist() if c.ndim == 1
                 else map(np.ndarray.tolist, c)
                 for c in host.values()]

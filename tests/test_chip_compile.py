@@ -171,6 +171,48 @@ def test_scan_kernel_compiles_for_v5e(one_chip, presence_1m):
 
 
 # ---------------------------------------------------------------------------
+# the YCSB record's kernels over a 1M-row, 1 GiB table, one chip
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("method,B", [("update", 1024), ("read", 1024),
+                                      ("update", 8), ("read", 8)])
+def test_ycsb_tick_kernels_touch_rows_not_the_table(one_chip, method, B):
+    """``fields`` is u8[1024] a row because the chip's default layout of
+    that leaf is row-major: the update scatters in place (the table
+    aliases, no table-sized temporary) and the read returns no table. A
+    leaf the chip lays out otherwise (u8[1000], i32[250]) costs two
+    copies of the 1 GiB table a tick, and shows here as a 1 GiB temp."""
+    from orleans_tpu.dispatch import VectorRuntime
+    from orleans_tpu.parallel import make_mesh
+
+    from ycsb_tpu import RecordVectorGrain as Record
+    # the kernel builder needs the class and the mesh, not a 1 GiB table
+    rt = VectorRuntime(mesh=make_mesh(1), capacity_per_shard=64)
+    rt.table(Record)
+    rows = (1 << 20) + 1
+    state = {"fields": _struct((1, rows, 1024), jnp.uint8, one_chip),
+             "ver": _struct((1, rows), jnp.int32, one_chip)}
+    lane = (1, B)
+    args = {"field": _struct(lane, jnp.int32, one_chip),
+            "value": _struct((*lane, 100), jnp.uint8, one_chip)} \
+        if method == "update" else {}
+    kern = rt._build_kernel(Record, method, donate_operands=True)
+    compiled = kern.lower(
+        state, _struct(lane, jnp.int32, one_chip),
+        _struct(lane, jnp.int32, one_chip),
+        _struct(lane, jnp.bool_, one_chip),
+        _struct(lane, jnp.bool_, one_chip), args).compile()
+    mem = compiled.memory_analysis()
+    table = rows * 1028
+    assert mem.temp_size_in_bytes < 64 << 20
+    if method == "update":
+        assert mem.alias_size_in_bytes >= table     # in place
+    else:
+        assert mem.alias_size_in_bytes == 0
+        assert mem.output_size_in_bytes < 4 << 20   # replies, no table
+
+
+# ---------------------------------------------------------------------------
 # four chips: the shard_map kernel and the exchange
 # ---------------------------------------------------------------------------
 
