@@ -53,6 +53,7 @@ _MESSAGES = _INGEST["messages"]
 _WORKER_QUEUE = "engine.worker_queue.seconds"
 _DEFERRED = "engine.deferred"               # counter: msgs deferred >= once
 _DEFER_WAIT = "engine.defer_wait.seconds"   # first deferral -> claimed
+_HELD = "engine.held"                       # counter: msgs held >= once
 _COMPLETE_HOP = "engine.complete_hop.seconds"
 # worker-side ledger stamp (cost attribution, observability.ledger): the
 # payload rides the job's deferred-stats list and replays loop-side in
@@ -64,6 +65,21 @@ log = logging.getLogger("orleans.vector")
 __all__ = ["VectorRuntime", "VectorActorRef"]
 
 MIN_BUCKET = 8
+
+# How many of one (class, method) group's jobs the off-loop worker may
+# hold, unresolved, before ``_tick`` stops claiming that group. One: a
+# job costs the worker milliseconds whatever it carries, so every job
+# should take all that arrived while the one before it ran. At 2 (one
+# running, one ready behind it, the ``_StagingSet`` pair's depth) the
+# second job is claimed the moment anything is pending and carries next
+# to nothing, so wide and narrow jobs alternate; read on the chip
+# (PERF.md §6, PR 29), 2 lost to 1 in every cell: a third of the calls/s
+# where one group carries single calls, a third of the median latency
+# under two groups, nothing gained where frames arrive in batches — the
+# worker's wait through the completion hop is time the loop, which
+# shares the interpreter lock with it, uses. A constant, not an option:
+# nothing a deployment knows changes it.
+_HANDOFF_DEPTH = 1
 
 
 def _bucket(n: int) -> int:
@@ -237,7 +253,7 @@ class _Pending:
     in-process calls."""
 
     __slots__ = ("key_hash", "shard", "slot", "fresh", "args", "future",
-                 "t_enq", "trace", "origin", "t_defer")
+                 "t_enq", "trace", "origin", "t_defer", "held")
 
     def __init__(self, key_hash, shard, slot, args, future,
                  t_enq=0.0, trace=None, origin=None):
@@ -246,6 +262,7 @@ class _Pending:
         self.slot = slot
         self.fresh = False  # decided when a tick claims it (_claim)
         self.t_defer = 0.0  # perf_counter of the first deferral
+        self.held = False   # counted in engine.held (metrics on only)
         self.args = args
         self.future = future
         self.t_enq = t_enq
@@ -391,6 +408,17 @@ class VectorRuntime:
         # donation per table (tick N+1 runs strictly after tick N's sync
         # proved N's uploads complete, so staging lanes never rotate back
         # to "filling" under an in-flight transfer).
+        # The hand-off is BOUNDED and completion-driven: _tick claims a
+        # (class, method) group only while the worker holds fewer than
+        # _HANDOFF_DEPTH (1, its reasons beside it) of that group's
+        # jobs; a held group's calls wait in self.pending, in arrival
+        # order, later enqueues append behind them, and _complete_job
+        # re-arms the claim. A job costs the worker milliseconds
+        # whatever it carries, so what waits must wait where it can
+        # coalesce — here, not as one-call jobs in the worker's FIFO —
+        # and a closed loop of single calls rides wide ticks. Groups
+        # overlap each other at the worker; _inflight_groups is the
+        # per-group count the claim reads.
         self.offloop_tick = bool(getattr(options, "offloop_tick", False)) \
             if options is not None else False
         self._fence = threading.RLock()
@@ -406,6 +434,8 @@ class VectorRuntime:
         # migration moving one mid-flight would let the worker's scatter
         # land in the abandoned source row
         self._inflight_keys: dict[type, dict[int, int]] = {}
+        # (class, method) -> that group's jobs with the worker, unresolved
+        self._inflight_groups: dict[tuple[type, str], int] = {}
         # lax.scan unroll for scanned (call_batch_rounds) kernels: each
         # scan step carries a fixed per-iteration cost (loop bookkeeping,
         # staged-payload dynamic slicing) that dominates small-population
@@ -769,8 +799,11 @@ class VectorRuntime:
         from ..observability.profiling import LOOP_CATEGORY
         self._loop = asyncio.get_running_loop()
         self._worker_q = _queue.SimpleQueue()
-        self._quiesced = asyncio.Event()
-        self._quiesced.set()
+        if self._quiesced is None:
+            # kept across a worker restart: held calls outlive
+            # shutdown_worker, and flush() may be waiting on it
+            self._quiesced = asyncio.Event()
+            self._quiesced.set()
         # completion callbacks run loop-side in THIS prebuilt context so
         # the profiler books them to tick_schedule — the same category
         # the inline path's resolution work carries. Scheduling from the
@@ -844,6 +877,8 @@ class VectorRuntime:
         self._inflight += 1
         self._inflight_msgs += len(job.ready)
         self._quiesced.clear()
+        group = (job.cls, job.method)
+        self._inflight_groups[group] = self._inflight_groups.get(group, 0) + 1
         ctr = self._inflight_keys.setdefault(job.cls, {})
         for p in job.ready:
             ctr[p.key_hash] = ctr.get(p.key_hash, 0) + 1
@@ -906,9 +941,13 @@ class VectorRuntime:
         the sampled device-tick span (the collector is loop-confined;
         the worker only stamped timings), and — in a finally, so no
         resolve/record error can ever wedge it — release the in-flight
-        key fence and re-arm the quiescence event. A loop-side failure
-        here fails the batch's futures like the inline path's tick
-        except does; it never leaves callers hanging."""
+        key fence, re-arm the quiescence event and give the job's group
+        its place with the worker back: the group's count drops, and a
+        tick is scheduled if anything is pending, because a group that
+        ``_tick`` held at ``_HANDOFF_DEPTH`` waits for exactly this
+        (an errored batch releases its group like any other). A
+        loop-side failure here fails the batch's futures like the inline
+        path's tick except does; it never leaves callers hanging."""
         st = self.stats
         try:
             if st is not None and job.t_hand:
@@ -967,6 +1006,14 @@ class VectorRuntime:
                         ctr[p.key_hash] = left
             if self._inflight == 0:
                 self._quiesced.set()
+            group = (job.cls, job.method)
+            left = self._inflight_groups.get(group, 0) - 1
+            if left <= 0:
+                self._inflight_groups.pop(group, None)
+            else:
+                self._inflight_groups[group] = left
+            if self.pending:
+                self._schedule_tick(self._loop)
 
     async def flush(self) -> None:
         """Run ticks until all pending work (incl. conflict-deferred and
@@ -984,6 +1031,17 @@ class VectorRuntime:
 
     # ------------------------------------------------------------------
     def _tick(self) -> None:
+        """One claim pass over ``self.pending``. Inline, every pending
+        group is claimed and run here. Off-loop the hand-off to the
+        worker is bounded: a group with ``_HANDOFF_DEPTH`` jobs (one)
+        still with the worker is HELD — its items stay in
+        ``self.pending`` in arrival order and later enqueues append
+        behind them, so they coalesce into one job when
+        ``_complete_job`` re-arms the claim.
+        The pass reschedules itself only for a group it could claim now
+        (conflict-deferred items of a group with room); it never spins
+        on held groups, and ``self.ticks`` counts only passes that
+        claimed something."""
         self._tick_scheduled = False
         if not self.pending:
             return
@@ -993,10 +1051,33 @@ class VectorRuntime:
             # re-segmented below (claiming, conflict defer, rescheduling,
             # worker hand-off) is tick scheduling work on the loop
             lp.set_category("tick_schedule")
-        work, self.pending = self.pending, {}
         offloop = self.offloop_tick
-        tracer = self.tracer
         st = self.stats
+        busy = self._inflight_groups
+        held = 0
+        if offloop and busy:
+            work = {}
+            for g, items in self.pending.items():
+                if busy.get(g, 0) < _HANDOFF_DEPTH:
+                    work[g] = items
+                elif st is not None:
+                    # first holds: a hold marks everything present, and
+                    # the list is in arrival order, so the marked are a
+                    # prefix
+                    for p in reversed(items):
+                        if p.held:
+                            break
+                        p.held = True
+                        held += 1
+            for g in work:
+                del self.pending[g]
+        else:
+            work, self.pending = self.pending, {}
+        if st is not None:
+            st.increment(_HELD, held)  # 0 too: it exists
+        if not work:
+            return  # every group held: a completion re-arms the claim
+        tracer = self.tracer
         tick = self.ticks
         for (cls, method), items in work.items():
             with StageSpan(st, "engine.claim", tick=tick) \
@@ -1027,7 +1108,8 @@ class VectorRuntime:
                     if p.future is not None and not p.future.done():
                         p.future.set_exception(e)
         self.ticks += 1
-        if self.pending:  # conflict-deferred work → next tick
+        # conflict-deferred work → next tick, unless its group is now held
+        if any(busy.get(g, 0) < _HANDOFF_DEPTH for g in self.pending):
             self._schedule_tick(asyncio.get_running_loop())
 
     def _claim(self, cls: type, method: str,

@@ -223,6 +223,10 @@ SLO_STATS = {
 #                         claim that takes it (histogram only, not in
 #                         STAGES: it spans ticks; beside it the counter
 #                         engine.deferred, messages deferred at least once)
+#   engine.held           counter only, no span: messages whose (class,
+#                         method) group _tick held at least once because
+#                         the worker still had a job of it (the bounded
+#                         hand-off); their wait shows in ingest.queue_wait
 #   engine.worker_queue   _submit_job -> worker dequeue (histogram only:
 #                         the wait crosses threads)
 #   engine.fence_wait     worker dequeue -> tick fence acquired (worker)

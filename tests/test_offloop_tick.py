@@ -16,17 +16,23 @@ The hard invariants under test (ISSUE 9 tentpole):
 * ``flush()`` drains worker-side in-flight batches (and stays the
   historical tick-and-yield spin on the inline path);
 * the batched client path honors ``ORLEANS_TPU_DEBUG_POOL=1`` pool
-  discipline end to end.
+  discipline end to end;
+* the hand-off to the worker is bounded (ISSUE 29): a (class, method)
+  group with a job at the worker is held in ``pending`` and coalesces;
+  completion re-arms the claim. Those tests hold the worker with the tick
+  fence or a failing batch, never with time.
 """
 
 import asyncio
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from orleans_tpu.core.message import set_debug_pool
 from orleans_tpu.dispatch import (VectorGrain, VectorRuntime,
                                   actor_method, add_vector_grains)
+from orleans_tpu.dispatch.engine import _HANDOFF_DEPTH as DEPTH
 from orleans_tpu.parallel import make_mesh
 from orleans_tpu.runtime import ClusterClient, Grain, SiloBuilder
 
@@ -375,3 +381,287 @@ async def test_call_batch_partial_gateway_failure_isolated():
         await client.close_async()
         for s in silos:
             await s.stop()
+
+
+# ---------------------------------------------------------------------------
+# the bounded, completion-driven hand-off (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+def _offloop_rt(offloop: bool = True) -> VectorRuntime:
+    from orleans_tpu.config import DispatchOptions
+    return VectorRuntime(mesh=make_mesh(1),
+                         options=DispatchOptions(capacity_per_shard=256,
+                                                 offloop_tick=offloop))
+
+
+def _spy(rt: VectorRuntime) -> tuple[list, list]:
+    """Record every job handed to the worker and every ``_tick``
+    callback the loop runs."""
+    jobs, ticks = [], []
+    submit, tick = rt._submit_job, rt._tick
+
+    def submit_spy(job):
+        jobs.append(job)
+        submit(job)
+
+    def tick_spy():
+        ticks.append(rt.ticks)
+        tick()
+
+    rt._submit_job, rt._tick = submit_spy, tick_spy
+    return jobs, ticks
+
+
+async def _spin(n: int = 30) -> None:
+    for _ in range(n):
+        await asyncio.sleep(0)
+
+
+def _enqueue(rt: VectorRuntime, entry: str, key: int, x: float):
+    if entry == "call":
+        return rt.call(CounterVec, key, "add", x=x)
+    if entry == "call_group":
+        return rt.call_group(CounterVec, "add", [(key, {"x": x}, True)])[0]
+    return rt.call_packed(CounterVec, "add", [key], {"x": [x]}, [True])[0]
+
+
+@pytest.mark.parametrize("entry,n", [("call", 8), ("call", 40),
+                                     ("call_group", 24),
+                                     ("call_packed", 24)])
+async def test_held_calls_coalesce(entry, n):
+    """N single calls over N loop iterations while the worker is held:
+    DEPTH jobs of one call reach the worker, the other N - DEPTH wait in
+    ``pending`` and ride ONE job when the first completes — DEPTH + 1
+    jobs, not N — and every answer is the inline path's."""
+    inline = _offloop_rt(False)
+    want = [_enqueue(inline, entry, k, float(k + 1)) for k in range(n)]
+    await inline.flush()
+    want = [float(f.result()) for f in want]
+
+    rt = _offloop_rt()
+    try:
+        await rt.call(CounterVec, 999, "add", x=0.0)  # worker up, compiled
+        jobs, _ticks = _spy(rt)
+        with rt.tick_fence():
+            futs = []
+            for k in range(n):
+                futs.append(_enqueue(rt, entry, k, float(k + 1)))
+                await asyncio.sleep(0)
+            await _spin()
+            assert [len(j.ready) for j in jobs] == [1] * DEPTH
+            assert len(rt.pending[(CounterVec, "add")]) == n - DEPTH
+            assert rt.queue_depth() == n
+        got = [float(v) for v in await asyncio.gather(*futs)]
+        assert got == want
+        assert [len(j.ready) for j in jobs] == [1] * DEPTH + [n - DEPTH]
+        assert not rt.pending and not rt._inflight_groups
+        assert rt._inflight == 0
+    finally:
+        rt.shutdown_worker()
+
+
+@pytest.mark.parametrize("raises", [False, True],
+                         ids=["completes", "raises"])
+async def test_completion_rearms_held_group(raises):
+    """A held group costs the loop nothing while it waits — ``_tick``
+    does not reschedule itself and ``rt.ticks`` stands still — and the
+    completion of one of its jobs claims it, also when that batch
+    raised (its callers get the error, the held ones their answers)."""
+    rt = _offloop_rt()
+    try:
+        await rt.call(CounterVec, 999, "add", x=0.0)
+        jobs, ticks = _spy(rt)
+        if raises:
+            execute = rt._execute_batch
+
+            def failing(cls, method, ready, *a, **kw):
+                if ready[0].key_hash == 0:
+                    raise RuntimeError("boom")
+                return execute(cls, method, ready, *a, **kw)
+
+            rt._execute_batch = failing
+        with rt.tick_fence():
+            futs = []
+            for k in range(6):
+                futs.append(rt.call(CounterVec, k, "add", x=1.0))
+                await asyncio.sleep(0)
+            await _spin()
+            n_ticks, rt_ticks = len(ticks), rt.ticks
+            assert len(jobs) == DEPTH
+            await _spin(50)
+            # nothing was enqueued, nothing completed: no callback ran
+            assert len(ticks) == n_ticks and rt.ticks == rt_ticks
+            # an enqueue still schedules one pass; it claims nothing and
+            # does not count as a tick
+            futs.append(rt.call(CounterVec, 6, "add", x=1.0))
+            await _spin(50)
+            assert len(ticks) == n_ticks + 1 and rt.ticks == rt_ticks
+            assert len(jobs) == DEPTH
+        out = await asyncio.gather(*futs, return_exceptions=True)
+        if raises:
+            assert isinstance(out[0], RuntimeError)
+            out = out[1:]
+        assert [float(v) for v in out] == [1.0] * len(out)
+        assert len(jobs) == DEPTH + 1
+        assert len(jobs[DEPTH].ready) == 7 - DEPTH
+        assert not rt.pending and not rt._inflight_groups
+        assert rt._inflight == 0 and rt._quiesced.is_set()
+    finally:
+        rt.shutdown_worker()
+
+
+@pytest.mark.parametrize("burst", [1, 3, 8])
+async def test_per_key_order_across_deferral_and_hold(burst):
+    """One key's calls, sent in bursts (the burst's later calls are
+    conflict-deferred) while the group is held and after: replies come in
+    send order with totals 1..n, and a read enqueued after an update's
+    future resolved sees that update."""
+    rt = _offloop_rt()
+    try:
+        await rt.call(CounterVec, 999, "add", x=0.0)
+        jobs, _ticks = _spy(rt)
+        order: list[int] = []
+        futs = []
+
+        def send(i: int, key: int = 7):
+            f = rt.call(CounterVec, key, "add", x=1.0)
+            f.add_done_callback(lambda _f, i=i: order.append(i))
+            futs.append(f)
+
+        n = 0
+        with rt.tick_fence():
+            for _ in range(4):
+                for _ in range(burst):
+                    send(n)
+                    n += 1
+                # other keys keep the group's place at the worker taken
+                rt.call(CounterVec, 100 + n, "add", x=1.0)
+                await asyncio.sleep(0)
+            await _spin()
+            assert len(jobs) == DEPTH
+            assert rt._inflight_groups == {(CounterVec, "add"): DEPTH}
+        for _ in range(burst):  # and a burst while the backlog drains
+            send(n)
+            n += 1
+        got = [float(v) for v in await asyncio.gather(*futs)]
+        assert got == [float(i + 1) for i in range(n)]
+        assert order == list(range(n))
+        # one message per key per tick held throughout
+        for j in jobs:
+            keys = [p.key_hash for p in j.ready]
+            assert len(keys) == len(set(keys))
+        last = await rt.call(CounterVec, 7, "add", x=1.0)
+        assert float(await rt.call(CounterVec, 7, "read")) == float(last)
+        await rt.flush()
+        assert not rt.pending and not rt._inflight_groups
+    finally:
+        rt.shutdown_worker()
+
+
+@pytest.mark.parametrize("drain", ["flush", "shutdown_worker", "gather"])
+async def test_held_groups_drain(drain):
+    """Held calls are pending work like any other: ``flush()`` returns
+    only after they ran; ``shutdown_worker()`` ends the thread after the
+    jobs it holds and the held calls start a fresh one; the migration
+    fence (``pending_key_hashes``) covers held keys all along."""
+    rt = _offloop_rt()
+    try:
+        await rt.call(CounterVec, 999, "add", x=0.0)
+        first = rt._worker
+        with rt.tick_fence():
+            futs = []
+            for k in range(10):
+                futs.append(rt.call(CounterVec, k, "add", x=2.0))
+                await asyncio.sleep(0)
+            await _spin()
+            assert len(rt.pending[(CounterVec, "add")]) == 10 - DEPTH
+            assert set(range(10)) <= rt.pending_key_hashes(CounterVec)
+        if drain == "flush":
+            await rt.flush()
+            assert all(f.done() for f in futs)
+        elif drain == "shutdown_worker":
+            rt.shutdown_worker()
+            assert rt._worker is None and not first.is_alive()
+            # the jobs it held ran; their completions wait for the loop
+            assert len(rt.pending[(CounterVec, "add")]) == 10 - DEPTH
+            assert set(range(DEPTH, 10)) <= rt.pending_key_hashes(CounterVec)
+        out = await asyncio.wait_for(asyncio.gather(*futs), 30.0)
+        assert [float(v) for v in out] == [2.0] * 10
+        await rt.flush()
+        assert not rt.pending and not rt._inflight_groups
+        assert not rt.pending_key_hashes(CounterVec) & set(range(10))
+        assert rt._inflight == 0 and rt.queue_depth() == 0
+    finally:
+        rt.shutdown_worker()
+
+
+@pytest.mark.parametrize("other", ["read", "add_other_class"])
+async def test_busy_group_does_not_hold_another(other):
+    """The bound is per (class, method): while one group is held, a call
+    of another method — or of another class — goes straight to the
+    worker."""
+    class OtherVec(CounterVec):
+        pass
+
+    rt = _offloop_rt()
+    try:
+        await rt.call(CounterVec, 999, "add", x=0.0)
+        jobs, _ticks = _spy(rt)
+        with rt.tick_fence():
+            futs = []
+            for k in range(5):
+                futs.append(rt.call(CounterVec, k, "add", x=1.0))
+                await asyncio.sleep(0)
+            await _spin()
+            assert len(jobs) == DEPTH
+            if other == "read":
+                group = (CounterVec, "read")
+                futs.append(rt.call(CounterVec, 50, "read"))
+            else:
+                group = (OtherVec, "add")
+                futs.append(rt.call(OtherVec, 50, "add", x=1.0))
+            await _spin()
+            assert len(jobs) == DEPTH + 1
+            assert (jobs[DEPTH].cls, jobs[DEPTH].method) == group
+            assert rt._inflight_groups == {(CounterVec, "add"): DEPTH,
+                                           group: 1}
+            assert len(rt.pending[(CounterVec, "add")]) == 5 - DEPTH
+            assert group not in rt.pending
+        await asyncio.gather(*futs)
+        assert not rt.pending and not rt._inflight_groups
+    finally:
+        rt.shutdown_worker()
+
+
+@pytest.mark.parametrize("hold", [False, True], ids=["no_hold", "hold"])
+async def test_engine_held_counter(hold):
+    """``engine.held`` counts a message at its first hold, once however
+    many passes find it held, and reads 0 — not absent — where nothing
+    was held."""
+    b = (SiloBuilder().with_name(f"ot-held-{hold}").add_grains(EchoGrain)
+         .with_config(offloop_tick=True, metrics_enabled=True))
+    add_vector_grains(b, CounterVec, mesh=make_mesh(1),
+                      dense={CounterVec: 64})
+    silo = b.build()
+    await silo.start()
+    try:
+        rt = silo.vector
+        for k in range(4):  # one call in flight at a time: never held
+            await rt.call(CounterVec, k, "add", x=1.0)
+        counters = silo.stats.counters
+        assert counters["engine.held"] == 0
+        if hold:
+            with rt.tick_fence():
+                futs = []
+                for k in range(12):
+                    futs.append(rt.call(CounterVec, k, "add", x=1.0))
+                    await asyncio.sleep(0)
+                await _spin()
+                # each pass found the earlier ones held again
+                assert counters["engine.held"] == 12 - DEPTH
+            await asyncio.gather(*futs)
+            await rt.flush()
+            assert counters["engine.held"] == 12 - DEPTH
+            assert counters["ingest.messages"] == 16
+    finally:
+        await silo.stop()
