@@ -101,16 +101,12 @@ async def connect_clients(ep: str, n: int) -> list:
 
 async def run(seconds: float = 2.0, concurrency: int = 32,
               n_grains: int = 64, n_keys: int = 64,
-              batched: bool = True, offloop: bool = True,
               call_batch: bool = False,
               call_batch_size: int = 16,
-              egress: bool = True, ingress_loops: int = 1,
+              ingress_loops: int = 1,
               egress_shards: int = 0, n_clients: int = 1) -> dict:
     """One silo over real TCP, metrics on, mixed host + device traffic;
-    returns the stage breakdown in the BENCH extra. ``batched=False``
-    flips the silo to the per-frame ingest path, ``offloop=False`` to
-    the loop-inline device tick, ``egress=False`` to the per-message
-    response path (the three A/B levers).
+    returns the stage breakdown in the BENCH extra.
     ``call_batch=True`` switches the vector workers from per-message
     awaited pings to deliberate ``client.call_batch`` groups of
     ``call_batch_size`` — the sender-side half of the pump share.
@@ -130,8 +126,7 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
     b = (SiloBuilder().with_name("ingest-silo").with_fabric(fabric)
          .add_grains(EchoGrain)
          .with_config(metrics_enabled=True, metrics_sample_period=0.25,
-                      batched_ingress=batched, offloop_tick=offloop,
-                      batched_egress=egress, ingress_loops=ingress_loops,
+                      ingress_loops=ingress_loops,
                       egress_shards=egress_shards))
     add_vector_grains(b, EchoVec, mesh=make_mesh(1),
                       dense={EchoVec: n_keys})
@@ -139,8 +134,6 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
     await silo.start()
     clients = await connect_clients(silo.silo_address.endpoint, n_clients)
     client = clients[0]
-    for c in clients:
-        c.batched_egress = egress  # client-correlation half of the lever
     try:
         host_refs = [clients[k % len(clients)].get_grain(EchoGrain, k)
                      for k in range(n_grains)]
@@ -202,8 +195,7 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
         batch_h = hists.get(INGEST_STATS["frame_batch"], {})
         # response-path decomposition (EGRESS_STATS, the egress twin):
         # summed stage seconds + the share of total instrumented wall the
-        # response leg takes — the number the batched-egress work lands
-        # against, like queue_wait was for ingress
+        # response leg takes
         egress_seconds = {}
         for stage in EGRESS_STAGES:
             h = hists.get(EGRESS_STATS[stage], {})
@@ -222,8 +214,7 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
         "vs_baseline": None,
         "extra": {
             "seconds": seconds, "concurrency": concurrency,
-            "batched": batched, "offloop": offloop,
-            "call_batch": call_batch, "egress": egress,
+            "call_batch": call_batch,
             "ingress_loops": ingress_loops,
             "egress_shards": egress_shards, "n_clients": n_clients,
             "calls": calls,
@@ -269,8 +260,8 @@ async def _drain(silo) -> None:
 
 async def run_ab(n_msgs: int = 512, seconds: float = 1.5,
                  host_every: int = 8) -> dict:
-    """Batched-vs-per-frame ingest hand-off A/B (the PR-7 lever, measured
-    at the boundary the queue-wait attribution blamed).
+    """Batched ingest hand-off against a plain per-message reference,
+    measured at the boundary the queue-wait attribution blamed.
 
     One silo, mixed messaging+vector traffic: a wire batch of ``n_msgs``
     ONE_WAY requests (1-in-``host_every`` host-tier pings, the rest
@@ -278,7 +269,7 @@ async def run_ab(n_msgs: int = 512, seconds: float = 1.5,
     pre-encoded once, then injected repeatedly for ``seconds`` through
     each hand-off:
 
-      per_frame   the PR-6 path: Python length-prefix walk, one
+      per_frame   the reference: Python length-prefix walk, one
                   decode_message + one MessageCenter.deliver per frame
                   (addressing + rt.call per message)
       batched     ONE decode_frames pass (a single unpack_batch C call)
@@ -403,7 +394,7 @@ async def run_call_batch_ab(seconds: float = 1.5, workers: int = 16,
     from orleans_tpu.dispatch import add_vector_grains
     from orleans_tpu.parallel import make_mesh
 
-    # the run_egress_ab GC discipline (collect + FREEZE): in a full-suite
+    # GC discipline (collect + FREEZE): in a full-suite
     # run a gen-2 collection can trigger inside ONE side's timed window
     # and which side draws it shifts with every suite-size change —
     # park the pre-existing heap so in-measure collections scan only
@@ -477,101 +468,6 @@ async def run_call_batch_ab(seconds: float = 1.5, workers: int = 16,
     }
 
 
-async def run_egress_ab(seconds: float = 1.5, workers: int = 16,
-                        n_keys: int = 64, batch: int = 16,
-                        ingress_loops: int = 1,
-                        egress_shards: int = 0) -> dict:
-    """Batched vs per-message RESPONSE path, vector-only closed loop over
-    real TCP (the ISSUE-10 lever, isolated the same way the call_batch
-    A/B isolated the sender side): identical ``call_batch`` senders drive
-    identical device-tier traffic against two silos that differ ONLY in
-    ``batched_egress`` — per-message, every resolved future fans out its
-    own send_response → transmit → encode → client-route write; batched,
-    one inbound batch's responses group per origin and ride ONE
-    encode_message_batch write (header-prefix template) plus one
-    client-side receive_response_batch correlation pass. Ratio-based, so
-    interpreter/container speed cancels. ``ingress_loops``/
-    ``egress_shards`` apply to BOTH sides (measure the batched-egress
-    lever under multi-loop/sharded-egress configurations; the
-    sharded-egress A/B itself lives in
-    ``loop_attribution.run_egress_shards_ab``)."""
-    import numpy as np
-
-    from orleans_tpu.dispatch import add_vector_grains
-    import gc
-
-    from orleans_tpu.parallel import make_mesh
-
-    async def measure(egress: bool) -> float:
-        # GC discipline, stronger than bench_profiling_overhead's
-        # pre-collect: this bench allocates hard enough (two silos +
-        # numpy payload per message) that a gen-2 collection TRIGGERS
-        # inside the 1.5s timed window, and in a long-lived CI process
-        # (~600 tests of heap by floor time) its pause lands 15-20% on
-        # whichever side draws it — measured 0.80-0.87x in-suite vs
-        # 1.25-1.9x isolated. collect + FREEZE parks the pre-existing
-        # heap in the permanent generation so in-measure collections
-        # scan only this bench's young objects; unfreeze restores it.
-        gc.collect()
-        gc.freeze()
-        try:  # freeze bracketed immediately: a failed start/connect
-            # must not leave the process heap permanently frozen
-            EchoVec = _make_vector_grain()
-            fabric = SocketFabric()
-            b = (SiloBuilder().with_name("eg-ab").with_fabric(fabric)
-                 .add_grains(EchoGrain)
-                 .with_config(batched_egress=egress,
-                              ingress_loops=ingress_loops,
-                              egress_shards=egress_shards))
-            add_vector_grains(b, EchoVec, mesh=make_mesh(1),
-                              dense={EchoVec: n_keys})
-            silo = b.build()
-            await silo.start()
-            # silo bracketed from HERE: a connect() failure must still
-            # stop it (threads/sockets otherwise leak into every later
-            # floor in the process)
-            client = None
-            try:
-                client = await GatewayClient(
-                    [silo.silo_address.endpoint]).connect()
-                client.batched_egress = egress  # correlation half
-                refs = [client.get_grain(EchoVec, k)
-                        for k in range(n_keys)]
-                await asyncio.gather(*(v.ping(x=np.int32(0))
-                                       for v in refs[:8]))
-                stop_at = time.perf_counter() + seconds
-                cb_count = [0]
-                w = batched_vec_sender(client, EchoVec, n_keys, batch,
-                                       stop_at, cb_count)
-                t0 = time.perf_counter()
-                await asyncio.gather(*(w(i) for i in range(workers)))
-                return cb_count[0] / (time.perf_counter() - t0)
-            finally:
-                if client is not None:
-                    await client.close_async()
-                await silo.stop()
-        finally:
-            gc.unfreeze()
-
-    per_msg = await measure(False)
-    batched = await measure(True)
-    ratio = batched / per_msg if per_msg else 0.0
-    return {
-        "metric": "batched_egress_speedup",
-        "value": round(ratio, 2),
-        "unit": "x (vector-only closed loop, batched vs per-message "
-                "responses)",
-        "vs_baseline": None,
-        "extra": {
-            "per_message_msgs_per_sec": round(per_msg, 1),
-            "batched_msgs_per_sec": round(batched, 1),
-            "workers": workers, "batch": batch, "n_keys": n_keys,
-            "seconds": seconds, "ingress_loops": ingress_loops,
-            "egress_shards": egress_shards,
-        },
-    }
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seconds", type=float, default=3.0)
@@ -580,17 +476,6 @@ def main() -> None:
                     help="run the batched-vs-per-frame hand-off A/B")
     ap.add_argument("--call-batch-ab", action="store_true",
                     help="run the call_batch-vs-per-message sender A/B")
-    ap.add_argument("--egress-ab", action="store_true",
-                    help="run the batched-vs-per-message response-path A/B")
-    ap.add_argument("--per-message-egress", action="store_true",
-                    help="attribution with batched egress OFF (the "
-                         "response-path share baseline)")
-    ap.add_argument("--per-frame", action="store_true",
-                    help="attribution with batched ingress OFF (the "
-                         "share-comparison baseline)")
-    ap.add_argument("--inline-tick", action="store_true",
-                    help="attribution with the off-loop tick OFF (the "
-                         "loop-inline A/B baseline)")
     ap.add_argument("--call-batch", action="store_true",
                     help="vector senders use deliberate client-side "
                          "call_batch groups instead of per-message pings")
@@ -603,17 +488,10 @@ def main() -> None:
         print(json.dumps(asyncio.run(run_ab(seconds=a.seconds))))
     elif a.call_batch_ab:
         print(json.dumps(asyncio.run(run_call_batch_ab(seconds=a.seconds))))
-    elif a.egress_ab:
-        print(json.dumps(asyncio.run(run_egress_ab(
-            seconds=a.seconds, ingress_loops=a.ingress_loops,
-            egress_shards=a.egress_shards))))
     else:
         print(json.dumps(asyncio.run(run(
             a.seconds, a.concurrency,
-            batched=not a.per_frame,
-            offloop=not a.inline_tick,
             call_batch=a.call_batch,
-            egress=not a.per_message_egress,
             ingress_loops=a.ingress_loops,
             egress_shards=a.egress_shards))))
 

@@ -10,12 +10,10 @@ with the host-loop occupancy profiler on (``profiling_enabled``), then
 reads the per-category loop shares back out:
 
     turns                    host grain turns
-    tick_schedule/staging/
-    tick_transfer/tick_sync  the device tick, segmented — with the
-                             off-loop tick pipeline (PR 9, the default)
-                             only tick_schedule remains on the loop;
-                             ``offloop=False`` restores the inline path
-                             where staging/transfer/sync book here
+    tick_schedule            the device tick's loop side: the claim, the
+                             hand-off to the tick worker and the
+                             completion (staging, transfer, dispatch and
+                             sync run on the worker, off the loop)
     pump                     socket reads + wire decode + batched routing
     client                   client-side gateway machinery (pumps,
                              senders, reconnector) — first-class since
@@ -63,16 +61,15 @@ class LocalEchoGrain(EchoGrain):
 
 async def run(seconds: float = 2.0, concurrency: int = 32,
               n_grains: int = 64, n_keys: int = 64,
-              offloop: bool = True, call_batch: bool = False,
+              call_batch: bool = False,
               call_batch_size: int = 16, ingress_loops: int = 1,
               egress_shards: int = 0, n_clients: int = 1,
               worker_procs: int = 1,
               prefer_local_hosts: bool = False) -> dict:
     """One silo over real TCP, profiling on, mixed host + device traffic
     at closed-loop saturation; returns the loop-occupancy breakdown.
-    ``offloop=False`` restores the loop-inline device tick (the A/B
-    lever this harness exists to measure); ``call_batch=True`` switches
-    the vector senders to deliberate client-side wire batches;
+    ``call_batch=True`` switches the vector senders to deliberate
+    client-side wire batches;
     ``ingress_loops>=2`` runs the multi-loop silo (sharded ingress pump
     threads — ISSUE 11) and ``n_clients`` controls how many gateway
     connections feed it (each pins to one ingress loop, so the
@@ -96,7 +93,7 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
     b = (SiloBuilder().with_name("loop-silo").with_fabric(fabric)
          .add_grains(Host)
          .with_config(profiling_enabled=True, profiling_window=0.25,
-                      offloop_tick=offloop, ingress_loops=ingress_loops,
+                      ingress_loops=ingress_loops,
                       egress_shards=egress_shards,
                       worker_procs=worker_procs))
     add_vector_grains(b, EchoVec, mesh=make_mesh(1),
@@ -205,7 +202,7 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
         "vs_baseline": None,
         "extra": {
             "seconds": seconds, "concurrency": concurrency,
-            "offloop": offloop, "call_batch": call_batch,
+            "call_batch": call_batch,
             "ingress_loops": ingress_loops,
             "egress_shards": egress_shards, "n_clients": n_clients,
             "worker_procs": worker_procs,
@@ -217,7 +214,6 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
             "shares_sum": round(sum(shares.values()), 4),
             "seconds_by_category": sec,
             "device_tick_share": tick_total,
-            "device_sync_share": shares.get("tick_sync", 0.0),
             "turns_share": shares.get("turns", 0.0),
             "pump_share": shares.get("pump", 0.0),
             "egress_share": shares.get("egress", 0.0),
@@ -225,61 +221,6 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
             "client_share": shares.get("client", 0.0),
             "observability_share": shares.get("observability", 0.0),
             "top_callbacks_last_window": top,
-        },
-    }
-
-
-async def run_ab(seconds: float = 2.0, concurrency: int = 32) -> dict:
-    """Off-loop tick + call_batch A/B on identical mixed TCP traffic
-    (the ISSUE 9 acceptance point, all ratios):
-
-      inline       offloop_tick=False, per-message senders (the PR-8
-                   baseline split)
-      offloop      offloop_tick=True, per-message senders — the tick
-                   slice (staging/transfer/sync) leaves the loop
-      offloop+cb   offloop + deliberate client-side call_batch — the
-                   per-message routing share of the pump collapses to
-                   per-batch work
-
-    Emits throughput ratios and the loop tick-share drop. Ratio-based on
-    purpose: absolute rates on a shared-core container are noise."""
-    inline = await run(seconds, concurrency, offloop=False)
-    off = await run(seconds, concurrency, offloop=True)
-    off_cb = await run(seconds, concurrency, offloop=True,
-                       call_batch=True)
-
-    def tick(r):
-        return r["extra"]["device_tick_share"]
-
-    def rate(r):
-        return r["extra"]["calls_per_sec"]
-
-    ratio = rate(off) / rate(inline) if rate(inline) else 0.0
-    return {
-        "metric": "offloop_tick_speedup",
-        "value": round(ratio, 3),
-        "unit": "x (offloop vs inline, same traffic)",
-        "vs_baseline": None,
-        "extra": {
-            "seconds": seconds, "concurrency": concurrency,
-            "inline": {"calls_per_sec": rate(inline),
-                       "tick_share": tick(inline),
-                       "shares": inline["extra"]["shares"]},
-            "offloop": {"calls_per_sec": rate(off),
-                        "tick_share": tick(off),
-                        "shares": off["extra"]["shares"]},
-            "offloop_call_batch": {
-                "calls_per_sec": rate(off_cb),
-                "tick_share": tick(off_cb),
-                "pump_share": off_cb["extra"]["pump_share"],
-                "shares": off_cb["extra"]["shares"]},
-            "tick_share_ratio": round(
-                tick(off) / tick(inline), 3) if tick(inline) else 0.0,
-            "call_batch_speedup_vs_inline": round(
-                rate(off_cb) / rate(inline), 3) if rate(inline) else 0.0,
-            "pump_share_ratio_cb_vs_offloop": round(
-                off_cb["extra"]["pump_share"] / off["extra"]["pump_share"],
-                3) if off["extra"]["pump_share"] else 0.0,
         },
     }
 
@@ -469,8 +410,6 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seconds", type=float, default=3.0)
     ap.add_argument("--concurrency", type=int, default=32)
-    ap.add_argument("--inline-tick", action="store_true",
-                    help="loop-inline device tick (the A/B baseline)")
     ap.add_argument("--call-batch", action="store_true",
                     help="vector senders use client-side call_batch")
     ap.add_argument("--ingress-loops", type=int, default=1,
@@ -479,8 +418,6 @@ def main() -> None:
                     help="sharded egress: N egress shard loops")
     ap.add_argument("--clients", type=int, default=1,
                     help="gateway connections feeding the silo")
-    ap.add_argument("--ab", action="store_true",
-                    help="run the inline/offloop/call_batch A/B sweep")
     ap.add_argument("--multiloop-ab", action="store_true",
                     help="run the 1-vs-2 ingress-loop A/B (ISSUE 11)")
     ap.add_argument("--egress-shards-ab", action="store_true",
@@ -505,11 +442,9 @@ def main() -> None:
             a.seconds, a.concurrency,
             loops=a.ingress_loops if a.ingress_loops > 1 else 2,
             n_clients=a.clients if a.clients > 1 else 2))))
-    elif a.ab:
-        print(json.dumps(asyncio.run(run_ab(a.seconds, a.concurrency))))
     else:
         print(json.dumps(asyncio.run(run(
-            a.seconds, a.concurrency, offloop=not a.inline_tick,
+            a.seconds, a.concurrency,
             call_batch=a.call_batch, ingress_loops=a.ingress_loops,
             egress_shards=a.egress_shards, n_clients=a.clients,
             worker_procs=a.worker_procs,

@@ -65,9 +65,8 @@ async def bench_host_tier(n_grains: int, concurrency: int,
     # bench_profiling_overhead discipline, hoisted): in a long-lived CI
     # process (~700 tests of heap by floor time) a gen-2 collection
     # landing inside ONE side's timed window skews the pair's ratio by
-    # 15-30% — far more than any tax the floors guard. collect + FREEZE
-    # (the run_egress_ab discipline, hoisted for the same reason): the
-    # bench allocates hard enough that a gen-2 collection can TRIGGER
+    # 15-30% — far more than any tax the floors guard. collect + FREEZE:
+    # the bench allocates hard enough that a gen-2 collection can TRIGGER
     # inside the timed window regardless of phase, and which side draws
     # it shifts with every suite-size change — freezing parks the
     # pre-existing heap in the permanent generation so in-measure

@@ -68,15 +68,9 @@ def main() -> None:
     # ingest attribution: socket -> decode/enqueue/queue-wait ->
     # staging/transfer/tick stage breakdown (shares sum to 1.0 of the
     # measured ingest wall — the substrate the ingest-wall work lands
-    # on), emitted batched AND per-frame at the same concurrency so the
-    # queue-wait share drop is read side by side (PR 7: below
-    # saturation the share falls ~0.92 -> ~0.75; at closed-loop
-    # saturation wait is Little's-law-bound and only the absolute
-    # per-message wait drops)
+    # on)
     print(json.dumps(asyncio.run(ingest_attribution.run(
         seconds=2.0, concurrency=8))))
-    print(json.dumps(asyncio.run(ingest_attribution.run(
-        seconds=2.0, concurrency=8, batched=False))))
     # batched-vs-per-frame ingest hand-off A/B (one decode_frames +
     # deliver_batch vs N decode_message + deliver for identical bytes;
     # CI floor 1.5x in test_floor_batched_ingest, measured 3-5x)
@@ -85,15 +79,9 @@ def main() -> None:
     # loop attribution: per-category occupancy of the silo's event loop
     # at closed-loop saturation (c=32 mixed host+vector over TCP) — the
     # measured split behind "residual queue-wait is loop contention":
-    # turns vs device tick (schedule/staging/transfer/SYNC) vs pump vs
+    # turns vs the device tick's loop side (tick_schedule) vs pump vs
     # observability vs idle, shares summing to ~1.0 of loop wall time
     print(json.dumps(asyncio.run(loop_attribution.run(
-        seconds=2.0, concurrency=32))))
-    # off-loop tick + call_batch A/B (ISSUE 9): inline vs off-loop vs
-    # off-loop+call_batch on identical mixed TCP traffic — loop tick
-    # share collapses off-loop (measured 0.11 -> <0.01), throughput
-    # ratios floored in test_floor_offloop_tick
-    print(json.dumps(asyncio.run(loop_attribution.run_ab(
         seconds=2.0, concurrency=32))))
     # multi-loop silo A/B (ISSUE 11): 1 vs 2 ingress pump loops on
     # identical mixed TCP traffic over 2 gateway connections — the
@@ -132,11 +120,6 @@ def main() -> None:
     # (isolates the sender-side win from the mixed harness's host/vec
     # mix shift; measured ~1.5-1.8x, CI floor 1.2x)
     print(json.dumps(asyncio.run(ingest_attribution.run_call_batch_ab(
-        seconds=1.5))))
-    # batched egress vs per-message responses, vector-only closed loop
-    # (ISSUE 10: response groups per origin + header-prefix template +
-    # batched client correlation; measured ~1.25-1.8x, CI floor 1.2x)
-    print(json.dumps(asyncio.run(ingest_attribution.run_egress_ab(
         seconds=1.5))))
     # profiler overhead as a ratio vs a bare silo (per-callback
     # interposition + category accounting; CI floor 0.85)

@@ -57,15 +57,13 @@ class ClusterOptions:
 @dataclass
 class MessagingOptions:
     """MessagingOptions / SiloMessagingOptions: timeouts, queue limits,
-    stuck-turn age limit (MaxRequestProcessingTime), and the batched
-    ingress pipeline switch (``batched_ingress=False`` restores the
-    per-frame decode + per-message hand-off — the A/B lever; wire bytes
-    are identical either way)."""
+    the stuck-turn age limit (MaxRequestProcessingTime), and the two
+    host-parallelism forks (``ingress_loops``/``egress_shards``,
+    ``worker_procs``)."""
 
     response_timeout: float = 30.0
     max_enqueued_requests: int = 5000
     max_request_processing_time: float = 60.0
-    batched_ingress: bool = True
     # multi-loop silo ingress (runtime.multiloop): N >= 2 spawns N
     # dedicated pump threads with their own event loops (sharded
     # ingress + SPSC hand-off rings, PING/SYSTEM bypassing the rings);
@@ -79,14 +77,10 @@ class MessagingOptions:
     # bypasses the rings per-message. 0 (default) keeps every sender
     # and encode on the main loop bit for bit — the A/B lever
     egress_shards: int = 0
-    # batched response egress (runtime.egress flush accumulator +
-    # header-prefix wire template): ``batched_egress=False`` restores
-    # the per-message send_response → transmit path — the A/B lever
-    # symmetric with ``batched_ingress``
-    batched_egress: bool = True
-    # off-loop device-tick pipeline (dispatch.engine tick worker):
-    # ``offloop_tick=False`` restores the loop-inline tick — the A/B
-    # lever paired with ``batched_ingress``
+    # where a claimed device tick runs: True (the served path) on the
+    # engine's tick worker; False on the event loop, in place. Kept by
+    # issue 30's rule, because on the chip it read as a trade and not
+    # a loss (PERF.md section 6, PR 30; ROADMAP D2 has what is next)
     offloop_tick: bool = True
     # multi-process silo (runtime.multiproc): N >= 2 forks N single-GIL
     # worker processes that each bind the SAME advertised endpoint via
@@ -438,7 +432,7 @@ class StreamOptions:
     stream_fanout delivery lever on the persistent providers' vector
     path — dense bulk items ride broadcast edge exchanges instead of
     per-consumer call_batch ticks. OFF (default) keeps the per-consumer
-    path bit for bit: the A/B lever, symmetric with ``batched_ingress``.
+    path bit for bit: the A/B lever.
     ``device_cache_capacity`` bounds each device namespace's
     :class:`~orleans_tpu.streams.cache.PooledQueueCache` in batches
     (producers backpressure at 75% occupancy through the queue-wait-
@@ -485,12 +479,6 @@ class DispatchOptions:
 
     capacity_per_shard: int = 1024
     exchange_capacity: int = 256
-    # off-loop tick worker for STANDALONE VectorRuntime(options=...)
-    # construction (silo-hosted runtimes take the lever from
-    # SiloConfig.offloop_tick / MessagingOptions.offloop_tick instead).
-    # Default False: a bare engine keeps today's synchronous loop-inline
-    # tick, which direct drivers (tests, bulk benchmarks) rely on.
-    offloop_tick: bool = False
 
     def validate(self) -> None:
         _positive(self, "capacity_per_shard", "exchange_capacity")
@@ -504,11 +492,9 @@ _FLAT_MAP = {
     "max_enqueued_requests": (MessagingOptions, "max_enqueued_requests"),
     "max_request_processing_time": (MessagingOptions,
                                     "max_request_processing_time"),
-    "batched_ingress": (MessagingOptions, "batched_ingress"),
     "ingress_loops": (MessagingOptions, "ingress_loops"),
     "egress_shards": (MessagingOptions, "egress_shards"),
     "worker_procs": (MessagingOptions, "worker_procs"),
-    "batched_egress": (MessagingOptions, "batched_egress"),
     "offloop_tick": (MessagingOptions, "offloop_tick"),
     "turn_warning_length": (SchedulingOptions, "turn_warning_length"),
     "detect_deadlocks": (SchedulingOptions, "detect_deadlocks"),

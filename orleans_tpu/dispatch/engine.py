@@ -30,6 +30,7 @@ import queue as _queue
 import threading
 import time
 import warnings
+import weakref
 from functools import partial
 from typing import Any
 
@@ -42,7 +43,6 @@ from ..compile_cache import ensure_compile_cache
 from ..core.ids import GrainId
 from ..observability.stats import INGEST_STATS as _INGEST
 from ..observability.stats import NO_SPAN, StageSpan
-from ..observability.stats import observe_or_defer as _emit
 from ..parallel.mesh import SILO_AXIS, make_mesh
 from .table import ShardedActorTable
 from .vector_grain import ActorMethod, VectorGrain
@@ -300,6 +300,21 @@ class _TickJob:
         self.t_hand = 0.0
 
 
+def _worker_main(ref: "weakref.ref[VectorRuntime]",
+                 q: "_queue.SimpleQueue") -> None:
+    """The tick worker's thread: jobs FIFO until the stop sentinel. It
+    holds the runtime only while a job runs. The finished job stays
+    referenced here until the next one arrives, so its arrays are freed
+    on this thread and not on the loop's."""
+    while True:
+        job = q.get()
+        rt = ref()
+        if job is None or rt is None:
+            return
+        rt._run_job(job)
+        del rt
+
+
 class VectorActorRef:
     """Typed handle to one device-tier activation (GrainReference analog)."""
 
@@ -382,23 +397,20 @@ class VectorRuntime:
         # the on-device per-slot cost twin (table.record_cost)
         self.ledger = None
         self.track_cost = False
-        # host-loop occupancy profiler (observability.profiling), set by
-        # the owning silo when profiling_enabled: each tick callback is
-        # segmented into tick_schedule / tick_staging / tick_transfer /
-        # tick_sync occupancy slices — tick_sync (host materialize, where
-        # async device dispatch is actually paid) is the loop time the
-        # off-loop-sync lever would reclaim
+        # host-loop occupancy profiler, injected by the owning silo when
+        # profiling_enabled: the two loop-side callbacks of a tick (the
+        # claim in _tick, the completion in _complete_job) book their
+        # time to its tick_schedule category
         self.loop_prof = None
         # stateless-worker (mesh-replicated) hosts per class — see
         # dispatch.replicated (StatelessWorkerPlacement.cs:6 on device)
         self._replicated_hosts: dict[type, Any] = {}
-        # off-loop tick pipeline (SiloConfig.offloop_tick /
-        # DispatchOptions.offloop_tick): when enabled, claimed batches run
-        # on a dedicated per-engine worker thread — staging fill, operand
-        # upload, kernel dispatch, and the host materialize sync all leave
-        # the event loop; the loop-side _tick shrinks to claim/conflict-
-        # defer plus a queue hand-off, and futures resolve back on the
-        # loop via call_soon_threadsafe. The _fence is the tick-
+        # the tick worker: claimed batches run on a dedicated per-engine
+        # thread, started lazily by the first claimed job — staging fill,
+        # operand upload, kernel dispatch and the host materialize sync
+        # all happen off the event loop; the loop-side _tick is claim/
+        # conflict-defer plus a queue hand-off, and futures resolve back
+        # on the loop via call_soon_threadsafe. The _fence is the tick-
         # serialization lock: the worker holds it for the whole batch
         # (donated state + donated staging operands are in flight), and
         # loop-side table mutation/materialization — grow(), shard moves,
@@ -419,14 +431,19 @@ class VectorRuntime:
         # and a closed loop of single calls rides wide ticks. Groups
         # overlap each other at the worker; _inflight_groups is the
         # per-group count the claim reads.
-        self.offloop_tick = bool(getattr(options, "offloop_tick", False)) \
-            if options is not None else False
+        # offloop_tick is the one lever left (hosting.install sets it from
+        # SiloConfig.offloop_tick): False runs each claimed job on the
+        # loop instead, synchronously, through the same _run_job and
+        # _complete_job. It stays because its reading on the chip was a
+        # trade, not a loss (PERF.md section 6, PR 30: hot-record tail
+        # against the median); a bare runtime ticks on its worker.
+        self.offloop_tick = True
         self._fence = threading.RLock()
         self._worker: threading.Thread | None = None
         self._worker_q: "_queue.SimpleQueue | None" = None
+        self._worker_stop: "weakref.finalize | None" = None
         self._loop: asyncio.AbstractEventLoop | None = None
         self._quiesced: asyncio.Event | None = None
-        self._complete_ctx = None  # tick_schedule-labeled completion ctx
         self._inflight = 0        # jobs handed to the worker, unresolved
         self._inflight_msgs = 0   # messages inside those jobs
         # class -> {key_hash: count} for in-flight jobs: these keys are
@@ -502,7 +519,7 @@ class VectorRuntime:
                 # tick-serialization fence: table-level state mutators/
                 # materializers (grow, move_rows, snapshot/restore,
                 # read_row) serialize against worker-side batch execution
-                # through the engine's lock (uncontended no-op inline)
+                # through the engine's lock
                 self.tables[cls].fence = self._fence
                 if self.track_load:
                     self.tables[cls].enable_hit_tracking()
@@ -723,7 +740,7 @@ class VectorRuntime:
         off-loop batch (which holds the fence for its whole duration),
         so an unfenced pop could orphan a list the worker is about to
         append to — keys written by that batch would silently never
-        flush. Uncontended no-op on the inline path."""
+        flush."""
         with self._fence:
             batches = self._dirty.pop(cls, None)
         if not batches:
@@ -780,100 +797,102 @@ class VectorRuntime:
             self._tick_scheduled = True
             loop.call_soon(self._tick)
 
-    # -- off-loop tick worker ------------------------------------------
+    # -- tick worker ---------------------------------------------------
     def tick_fence(self):
         """The tick-serialization fence (a reentrant lock usable as a
         context manager): loop-side code that mutates or materializes
         table state outside the tick path — rebalance shard moves,
         checkpoint capture, write-behind gathers — takes it around the
         touch so it can never interleave with a worker-side batch whose
-        donated state/staging upload is still in flight. Uncontended
-        (and effectively free) on the inline path."""
+        donated state/staging upload is still in flight."""
         return self._fence
+
+    def _bind_loop(self) -> None:
+        loop = asyncio.get_running_loop()
+        if loop is not self._loop:
+            # first job, or a bare runtime carried into a second event
+            # loop (one asyncio.run after another): completions post to
+            # the loop that claims, and the quiescence event belongs to
+            # it. Held calls outlive shutdown_worker and flush() may be
+            # waiting on the event, so it survives a worker restart on
+            # the same loop.
+            self._loop = loop
+            self._quiesced = asyncio.Event()
+            if not self._inflight:
+                self._quiesced.set()
 
     def _ensure_worker(self) -> None:
         if self._worker is not None:
             return
-        import contextvars
-
-        from ..observability.profiling import LOOP_CATEGORY
-        self._loop = asyncio.get_running_loop()
-        self._worker_q = _queue.SimpleQueue()
-        if self._quiesced is None:
-            # kept across a worker restart: held calls outlive
-            # shutdown_worker, and flush() may be waiting on it
-            self._quiesced = asyncio.Event()
-            self._quiesced.set()
-        # completion callbacks run loop-side in THIS prebuilt context so
-        # the profiler books them to tick_schedule — the same category
-        # the inline path's resolution work carries. Scheduling from the
-        # worker thread would otherwise capture an unset context and the
-        # per-batch resolve/replay would book to "other", biasing the
-        # inline-vs-offloop tick-share A/B exactly where it is read.
-        self._complete_ctx = contextvars.Context()
-        self._complete_ctx.run(LOOP_CATEGORY.set, "tick_schedule")
-        t = threading.Thread(target=self._worker_main,
+        q = self._worker_q = _queue.SimpleQueue()
+        # the idle thread holds only a weak reference to the runtime, and
+        # collecting the runtime posts the stop sentinel: an abandoned
+        # runtime's worker exits instead of pinning it for the life of
+        # the process
+        self._worker_stop = weakref.finalize(self, q.put, None)
+        t = threading.Thread(target=_worker_main,
+                             args=(weakref.ref(self), q),
                              name="orleans-tick-worker", daemon=True)
         self._worker = t
         t.start()
 
     def shutdown_worker(self, timeout: float = 10.0) -> None:
-        """Stop the off-loop tick worker (silo stop): jobs already queued
-        finish FIFO, then the thread exits. Completion callbacks posted
-        to the loop still run when control next returns to it. Idempotent
-        and a no-op on the inline path; a later tick after shutdown would
-        lazily start a fresh worker (restart-in-process)."""
+        """Stop the tick worker (silo stop): jobs already queued finish
+        FIFO, then the thread exits. Completion callbacks posted to the
+        loop still run when control next returns to it. Idempotent; a
+        later tick after shutdown lazily starts a fresh worker
+        (restart-in-process)."""
         w, self._worker = self._worker, None
         if w is None:
             return
+        self._worker_stop.detach()
         self._worker_q.put(None)
         w.join(timeout)
 
-    def _worker_main(self) -> None:
-        q = self._worker_q
-        while True:
-            job = q.get()
-            if job is None:
-                return
-            host = err = None
-            st = self.stats
-            wait = None
-            try:
-                if st is not None:
-                    # the job waited while this thread ran the previous
-                    # tick; from here it waits for whoever holds the
-                    # fence (a write-behind gather, a snapshot)
-                    wait = StageSpan(st, "engine.fence_wait", job.stats,
-                                     tick=job.tick)
-                    job.stats.append((_WORKER_QUEUE, wait.t0 - job.t_hand))
-                # the fence is held for the WHOLE batch: donated tbl.state
-                # and donated staging operands are in flight until the
-                # sync at the end of _execute_batch proves the uploads
-                # completed
-                with self._fence:
-                    if wait is not None:
-                        wait.close()
-                    job.per_shard, host, job.span = self._execute_batch(
-                        job.cls, job.method, job.ready, None,
-                        trace_roll=job.trace, sink=job.stats,
-                        tick=job.tick)
-            except BaseException as e:  # noqa: BLE001 — futures fail loop-side
-                err = e
+    def _run_job(self, job: _TickJob, offloop: bool = True) -> None:
+        """One claimed batch under the tick fence, then its completion:
+        on the worker, posted back to the loop; with the lever off, on
+        the loop and called in place."""
+        host = err = None
+        st = self.stats
+        wait = None
+        try:
             if st is not None:
-                if err is not None:
-                    StageSpan.unwind()
-                job.t_hand = time.perf_counter()
-            try:
-                self._loop.call_soon_threadsafe(
-                    self._complete_job, job, host, err,
-                    context=self._complete_ctx)
-            except RuntimeError:
-                # loop closed (ungraceful stop): the runtime client is
-                # breaking outstanding futures; nothing left to resolve
-                return
+                # the job waited while this thread ran the previous
+                # tick; from here it waits for whoever holds the
+                # fence (a write-behind gather, a snapshot)
+                wait = StageSpan(st, "engine.fence_wait", job.stats,
+                                 tick=job.tick)
+                job.stats.append((_WORKER_QUEUE, wait.t0 - job.t_hand))
+            # the fence is held for the WHOLE batch: donated tbl.state
+            # and donated staging operands are in flight until the
+            # sync at the end of _execute_batch proves the uploads
+            # completed
+            with self._fence:
+                if wait is not None:
+                    wait.close()
+                job.per_shard, host, job.span = self._execute_batch(
+                    job.cls, job.method, job.ready, job.stats,
+                    trace_roll=job.trace, tick=job.tick)
+        except BaseException as e:  # noqa: BLE001 — futures fail loop-side
+            err = e
+        if st is not None:
+            if err is not None:
+                StageSpan.unwind()
+            job.t_hand = time.perf_counter()
+        if not offloop:
+            self._complete_job(job, host, err)
+            return
+        try:
+            self._loop.call_soon_threadsafe(
+                self._complete_job, job, host, err)
+        except RuntimeError:
+            # loop closed (ungraceful stop): the runtime client is
+            # breaking outstanding futures; nothing left to resolve
+            pass
 
     def _submit_job(self, job: _TickJob) -> None:
-        self._ensure_worker()
+        self._bind_loop()
         self._inflight += 1
         self._inflight_msgs += len(job.ready)
         self._quiesced.clear()
@@ -884,12 +903,14 @@ class VectorRuntime:
             ctr[p.key_hash] = ctr.get(p.key_hash, 0) + 1
         if self.stats is not None:
             job.t_hand = time.perf_counter()
-        self._worker_q.put(job)
+        if self.offloop_tick:
+            self._ensure_worker()
+            self._worker_q.put(job)
 
     def _record_tick_span(self, span, ready: list, error: bool = False
                           ) -> None:
-        """Loop-side record of a device-tick span from worker- (or
-        inline-) stamped timings; ``span`` = (name, wall_start,
+        """Loop-side record of a device-tick span from worker-stamped
+        timings; ``span`` = (name, wall_start,
         duration[, batch_wall, batch_mono]) or None. The error form is
         what tail retention keys on, so failing sampled ticks stay
         visible in retained traces.
@@ -946,9 +967,14 @@ class VectorRuntime:
         tick is scheduled if anything is pending, because a group that
         ``_tick`` held at ``_HANDOFF_DEPTH`` waits for exactly this
         (an errored batch releases its group like any other). A
-        loop-side failure here fails the batch's futures like the inline
-        path's tick except does; it never leaves callers hanging."""
+        loop-side failure here fails the batch's futures; it never
+        leaves callers hanging."""
         st = self.stats
+        lp = self.loop_prof
+        if lp is not None:
+            # resolving futures and replaying the worker's observations
+            # is tick scheduling work on the loop, like the claim
+            lp.set_category("tick_schedule")
         try:
             if st is not None and job.t_hand:
                 # the hop: this callback waited behind whatever the loop
@@ -1017,10 +1043,9 @@ class VectorRuntime:
 
     async def flush(self) -> None:
         """Run ticks until all pending work (incl. conflict-deferred and
-        worker-side in-flight batches) drains. Identical to the
-        historical tick-and-yield spin on the inline path; with the
-        off-loop worker it awaits the worker's quiescence event between
-        rounds instead of busy-spinning the loop."""
+        worker-side in-flight batches) drains: between rounds it awaits
+        the worker's quiescence event instead of busy-spinning the
+        loop."""
         while self.pending or self._inflight:
             if self.pending:
                 self._tick()
@@ -1031,8 +1056,7 @@ class VectorRuntime:
 
     # ------------------------------------------------------------------
     def _tick(self) -> None:
-        """One claim pass over ``self.pending``. Inline, every pending
-        group is claimed and run here. Off-loop the hand-off to the
+        """One claim pass over ``self.pending``. The hand-off to the
         worker is bounded: a group with ``_HANDOFF_DEPTH`` jobs (one)
         still with the worker is HELD — its items stay in
         ``self.pending`` in arrival order and later enqueues append
@@ -1047,15 +1071,13 @@ class VectorRuntime:
             return
         lp = self.loop_prof
         if lp is not None:
-            # this call_soon callback IS the device tick: everything not
-            # re-segmented below (claiming, conflict defer, rescheduling,
-            # worker hand-off) is tick scheduling work on the loop
+            # claiming, conflict defer, rescheduling and the worker
+            # hand-off are tick scheduling work on the loop
             lp.set_category("tick_schedule")
-        offloop = self.offloop_tick
         st = self.stats
         busy = self._inflight_groups
         held = 0
-        if offloop and busy:
+        if busy:
             work = {}
             for g, items in self.pending.items():
                 if busy.get(g, 0) < _HANDOFF_DEPTH:
@@ -1071,7 +1093,7 @@ class VectorRuntime:
                         held += 1
             for g in work:
                 del self.pending[g]
-        else:
+        else:  # nothing in flight
             work, self.pending = self.pending, {}
         if st is not None:
             st.increment(_HELD, held)  # 0 too: it exists
@@ -1083,8 +1105,8 @@ class VectorRuntime:
             with StageSpan(st, "engine.claim", tick=tick) \
                     if st is not None else NO_SPAN:
                 ready = self._claim(cls, method, items)
-                # device-tick sampling rolls HERE (loop-side) on both
-                # paths: the worker must not touch the collector. A batch
+                # device-tick sampling rolls HERE (loop-side): the
+                # worker must not touch the collector. A batch
                 # carrying request trace contexts (threaded over the
                 # cross-process staging ring or the vector bridge) records
                 # regardless of the roll: header presence IS the upstream
@@ -1092,21 +1114,12 @@ class VectorRuntime:
                 roll = bool(ready) and tracer is not None and (
                     tracer.sample()
                     or any(p.trace is not None for p in ready))
-                if ready and offloop:
-                    self._submit_job(_TickJob(cls, method, ready, roll,
-                                              tick))
-            if not ready or offloop:
-                continue
-            try:
-                self._run_batch(cls, method, ready, trace_roll=roll)
-            except Exception as e:  # noqa: BLE001 — fail the futures, not the loop
-                log.exception("vector tick failed for %s.%s",
-                              cls.__name__, method)
-                self._record_tick_span(getattr(e, "_tick_span", None),
-                                       ready, error=True)
-                for p in ready:
-                    if p.future is not None and not p.future.done():
-                        p.future.set_exception(e)
+                if ready:
+                    job = _TickJob(cls, method, ready, roll, tick)
+                    self._submit_job(job)
+            if ready and not self.offloop_tick:
+                # the lever: the job runs here, outside the claim's span
+                self._run_job(job, offloop=False)
         self.ticks += 1
         # conflict-deferred work → next tick, unless its group is now held
         if any(busy.get(g, 0) < _HANDOFF_DEPTH for g in self.pending):
@@ -1163,27 +1176,6 @@ class VectorRuntime:
             st.increment(_DEFERRED, first_deferrals)  # 0 too: it exists
         return ready
 
-    def _run_batch(self, cls: type, method: str, ready: list[_Pending],
-                   trace_roll: bool = False) -> None:
-        """Inline (on-loop) batch execution — the ``offloop_tick=False``
-        path, semantically today's tick. Runs under the tick fence like
-        the worker path: the loop being the only ticker does NOT make
-        the donated state safe — checkpoint capture() is documented
-        callable from any thread, and a worker batch may still be in
-        flight when offloop_tick is flipped off (restart-in-process).
-        Uncontended reentrant acquire is ~100ns against a multi-ms tick."""
-        try:
-            with self._fence:
-                per_shard, host, span = self._execute_batch(
-                    cls, method, ready, self.loop_prof,
-                    trace_roll=trace_roll, tick=self.ticks)
-        except BaseException:
-            if self.stats is not None:
-                StageSpan.unwind()  # a loop callback starts with none open
-            raise
-        self._record_tick_span(span, ready)
-        self._resolve_batch(ready, per_shard, host, self.ticks, cls, method)
-
     def _resolve_batch(self, ready: list[_Pending], per_shard,
                        host, tick: int, cls: type, method: str) -> None:
         st = self.stats
@@ -1201,17 +1193,13 @@ class VectorRuntime:
         self.messages_processed += len(ready)
 
     def _execute_batch(self, cls: type, method: str, ready: list[_Pending],
-                       lp, trace_roll: bool = False, sink: list | None = None,
-                       tick: int = 0):
+                       sink: list, trace_roll: bool = False, tick: int = 0):
         """Staging fill → operand upload → kernel dispatch → host
-        materialize sync for one claimed, conflict-free batch. Runs on
-        the loop (inline path; ``lp`` is the loop profiler, ``sink``
-        None — observations go straight to the registry) or on the
-        off-loop tick worker (``lp`` None — worker wall time is not loop
-        time and the profiler's attribution state is loop-confined;
-        ``sink`` = the job's deferred-stats list — timings are STAMPED
-        here off-loop but recorded loop-side in _complete_job, because
-        StatsRegistry/Histogram/QueueWaitTrend are not thread-safe).
+        materialize sync for one claimed, conflict-free batch, on the
+        tick worker. ``sink`` is the job's deferred-stats list: every
+        observation is STAMPED here and recorded loop-side in
+        _complete_job, because StatsRegistry/Histogram/QueueWaitTrend/
+        the ledger are loop-confined.
         With metrics on the batch is four contiguous stage spans of unit
         ``tick`` — ingest.staging, ingest.transfer, ingest.tick.dispatch,
         ingest.tick.sync — the last two tiling ingest.tick; a raising
@@ -1221,11 +1209,6 @@ class VectorRuntime:
         tick (recorded by the caller on the loop) or None."""
         st = self.stats
         led = self.ledger
-        if lp is not None:
-            # loop occupancy: staging-fill from here; the label tuple
-            # names this batch in the flight recorder's top-K and is only
-            # string-joined on admission — every tick pays no format
-            lp.set_category("tick_staging", ("tick", cls.__name__, method))
         now_mono = batch_wall = 0.0
         stage = None
         if st is not None:
@@ -1288,9 +1271,6 @@ class VectorRuntime:
                         v = np.frombuffer(v, dtype).reshape(shape)
                     buf[s, i] = v
         self.staging_fill = len(ready)
-        if lp is not None:
-            # staging done: operand upload + kernel dispatch next
-            lp.set_category("tick_transfer")
         if inferred:
             m.args_schema = schema  # needed by the kernel builder
         t_tick = 0.0
@@ -1302,20 +1282,15 @@ class VectorRuntime:
             # enqueued by non-call paths carry no stamp and are skipped
             for p in ready:
                 if p.t_enq:
-                    _emit(sink, st, _QUEUE_WAIT,
-                          max(0.0, now_mono - p.t_enq))
+                    sink.append((_QUEUE_WAIT,
+                                 max(0.0, now_mono - p.t_enq)))
         if self.shed_trend is not None:
             # feed the load-shed trend with this batch's mean queue wait
-            # (deferred to the loop-side completion on the worker path:
-            # QueueWaitTrend is not thread-safe, and the dispatcher feeds
-            # it from the loop)
+            # (QueueWaitTrend is not thread-safe, and the dispatcher
+            # feeds it from the loop)
             stamped = [now_mono - p.t_enq for p in ready if p.t_enq]
             if stamped:
-                mean = max(0.0, sum(stamped) / len(stamped))
-                if sink is not None:
-                    sink.append((None, mean))
-                else:
-                    self.shed_trend.note(mean)
+                sink.append((None, max(0.0, sum(stamped) / len(stamped))))
         span_name = span_start = t_span0 = None
         try:
             # operand buffers are donated: these device arrays are fresh
@@ -1352,7 +1327,7 @@ class VectorRuntime:
                 # a sampled tick whose kernel raised still records an
                 # errored device span (tail retention keys on the error
                 # attr) — the collector is loop-confined, so the timing
-                # rides the exception to the loop-side completion/except
+                # rides the exception to the loop-side completion
                 # (best-effort: an exception type rejecting attributes
                 # just loses the span, never the error)
                 try:
@@ -1372,12 +1347,6 @@ class VectorRuntime:
                 count=len(ready)))
         if self.track_load:
             tbl.record_hits(slots, valid)
-        if lp is not None:
-            # THE distinct device-sync occupancy (inline path only): jax
-            # dispatch is async, so the host materialize below is where
-            # device execution is actually paid on the loop — the slice
-            # the off-loop worker removes from the loop entirely
-            lp.set_category("tick_sync")
         host = jax.tree_util.tree_map(np.asarray, results)
         if not jax.tree_util.tree_leaves(host):
             # result-less method: no np.asarray above synced anything, so
@@ -1386,7 +1355,7 @@ class VectorRuntime:
             # backends (TPU) the operands' host→device upload must have
             # provably completed before the numpy buffers are reused
             # (free on CPU, where the transfer copies synchronously).
-            # This sync is ALSO the off-loop staging pin: the worker runs
+            # This sync is ALSO the staging pin: the worker runs
             # batches FIFO, so by the time a staging set rotates back its
             # tick has provably synced here. (A read-only kernel returns
             # no state: its operands are not donated, so they are what
@@ -1397,18 +1366,15 @@ class VectorRuntime:
             # tick closes AFTER the host transfer for the same reason the
             # span timing does: jax dispatch is async, and the np.asarray
             # sync is where device execution is actually paid
-            _emit(sink, st, _TICK, stage.t0 + stage.close() - t_tick)
-            if sink is not None:
-                sink.append((_MESSAGES, len(ready)))
-            else:
-                st.increment(_MESSAGES, len(ready))
+            sink.append((_TICK, stage.t0 + stage.close() - t_tick))
+            sink.append((_MESSAGES, len(ready)))
         if led is not None:
             # cost-attribution epilogue: every resident row is charged
             # this tick's wall (row-seconds = rows × wall); the per-slot
             # device twin folds the same batch via record_cost (the
             # _accumulate_hits scatter with the µs charge as scale).
-            # Worker path stamps the payload for loop-side replay —
-            # same discipline as the stage observations above.
+            # The payload is stamped for loop-side replay — same
+            # discipline as the stage observations above.
             tick_s = max(0.0, time.perf_counter() - t_tick)
             payload = (cls.__name__, method, len(ready), tick_s,
                        tuple(f"{cls.__name__}#{p.key_hash}"
@@ -1420,10 +1386,7 @@ class VectorRuntime:
                 # 5-tuples so merged snapshots are stable across versions
                 payload = payload + (
                     tuple(p.origin for p in ready),)
-            if sink is not None:
-                sink.append((_LEDGER, payload))
-            else:
-                led.charge_tick(payload)
+            sink.append((_LEDGER, payload))
             if self.track_cost:
                 tbl.record_cost(slots, valid, int(tick_s * 1e6))
         span = None
@@ -1434,9 +1397,6 @@ class VectorRuntime:
             # the batch-start stamps parent traced items' child spans.
             span = (span_name, span_start, time.perf_counter() - t_span0,
                     batch_wall, now_mono)
-        if lp is not None:
-            # sync paid: future resolution is scheduling work again
-            lp.set_category("tick_schedule")
         return per_shard, host, span
 
     # ------------------------------------------------------------------

@@ -60,9 +60,6 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
             silo.vector = VectorRuntime(
                 mesh=mesh, capacity_per_shard=capacity_per_shard,
                 options=options)
-        # off-loop tick pipeline: silo-hosted runtimes take the lever
-        # from SiloConfig (the A/B switch; DispatchOptions.offloop_tick
-        # only governs standalone engines)
         silo.vector.offloop_tick = silo.config.offloop_tick
         if silo.tracer is not None:
             silo.vector.tracer = silo.tracer  # device ticks join the traces

@@ -160,13 +160,11 @@ class MetricsSampler:
                             lambda: len(silo.tracer.pending))
             self.add_source("trace.retained_spans",
                             lambda: len(silo.tracer.spans))
-        if silo.message_center.egress is not None:
-            # batched egress: the last response flush-group size — the
-            # hand-off-unit twin of vector.staging_fill (a sustained 1
-            # means responses are not grouping; the pipeline engages but
-            # pays its overhead without the batching win)
-            self.add_source("vector.egress_group",
-                            lambda: silo.message_center.egress.last_group)
+        # the last response flush-group size — the hand-off-unit twin of
+        # vector.staging_fill (a sustained 1 means responses are not
+        # grouping)
+        self.add_source("vector.egress_group",
+                        lambda: silo.message_center.egress.last_group)
         if silo.vector is not None:
             self._install_vector_sources()
         if silo.stream_providers:

@@ -28,8 +28,8 @@ actually goes, continuously and cheaply enough to leave on:
   :data:`LOOP_CATEGORY` contextvar (task steps run in the task's context,
   so one ``enter``/``mark_loop_category`` at the top of a turn/pump task
   labels every later step of that task); instrumented sites segment
-  finer with :meth:`LoopProfiler.set_category` (the engine splits one
-  tick callback into schedule/staging/transfer/sync slices).
+  finer with :meth:`LoopProfiler.set_category` (the engine books its
+  claim and completion callbacks to ``tick_schedule``).
 * **Flight recorder**: per-window occupancy slices plus the top-K
   slowest callbacks (category + grain class/method label when the turn
   declared one) land in a bounded ring; :meth:`LoopProfiler.trigger`
@@ -85,18 +85,15 @@ __all__ = ["Profiler", "LoopProfiler", "LOOP_CATEGORIES", "LOOP_CATEGORY",
 # Host-loop occupancy profiler
 # ---------------------------------------------------------------------------
 
-# the named occupancy buckets loop time is attributed into. "tick_sync" is
-# the distinct device-sync category — host materialize/block_until_ready,
-# where asynchronously-dispatched device execution is actually paid — the
-# slice the "move the tick's device sync off-loop" lever would reclaim.
+# the named occupancy buckets loop time is attributed into (staging,
+# operand upload, kernel dispatch and the host materialize of a device
+# tick run on the engine's worker thread: they are not loop time; with
+# the ``offloop_tick`` lever off the whole job books to tick_schedule)
 LOOP_CATEGORIES = (
     "turns",          # host grain turns (dispatcher._run_turn)
     "timers",         # __timer__ tick turns + timer machinery
-    "tick_schedule",  # engine tick dispatch: claiming, conflict defer,
-                      # future resolution
-    "tick_staging",   # pending invocations -> host staging arrays
-    "tick_transfer",  # host arrays -> device operands + kernel dispatch
-    "tick_sync",      # host materialize: where device execution is paid
+    "tick_schedule",  # engine tick: claiming, conflict defer, the worker
+                      # hand-off, and future resolution at completion
     "pump",           # socket pump + wire decode + batched routing
     "egress",         # outbound wire: response/request encode + sender
                       # writes (per-endpoint sender tasks, gateway
@@ -299,14 +296,13 @@ class LoopProfiler:
     def set_category(self, category: str, label=None, *,
                      _perf=time.perf_counter) -> None:
         """Attribute loop time from here to the next boundary to
-        ``category`` (segmenting WITHIN the current callback — the engine
-        splits one tick callback into staging/transfer/sync). Outside a
+        ``category`` (segmenting WITHIN the current callback). Outside a
         wrapped callback this is a no-op: there is no loop time to
         attribute, and a stale mark must not accrue. ``label`` may be a
         string or a tuple of parts — tuples are joined with "." only if
         the callback actually lands in the top-K record (the per-turn
         hot path never pays the format). Accrual is inlined — this runs
-        several times per device tick and twice per host turn."""
+        twice per device tick and twice per host turn."""
         if not self._depth or self.closed:
             return
         now = _perf()

@@ -113,10 +113,8 @@ class Dispatcher:
         # Silo._install_loop_profiler when profiling_enabled, else None —
         # the per-turn guard is one attribute load
         self._loop_prof = None
-        # batched response egress (runtime.egress.EgressBatcher): set by
-        # the Silo ctor when batched_egress is on, else None —
-        # send_response pays one attribute check on the per-message path
-        self._egress = None
+        # the message center's response accumulator (runtime.egress)
+        self._egress = silo.message_center.egress
         # in-flight device-tier state recoveries: (class, key_hash) →
         # future; concurrent calls for one recovering key share the load
         self._vector_recoveries: dict = {}
@@ -482,7 +480,7 @@ class Dispatcher:
             if owner is not None and owner != my_addr:
                 if msg.target_silo is None or msg.target_silo != my_addr:
                     # unaddressed gateway ingress: address like the
-                    # per-frame _route (send_message, no forward budget
+                    # per-message _route (send_message, no forward budget
                     # burned in steady state)
                     try:
                         msg.target_silo = None
@@ -1335,18 +1333,15 @@ class Dispatcher:
             self.silo.message_center.send_message(msg)
 
     def send_response(self, request: Message, response: Message) -> None:
-        """SendResponse:769 — batched egress joins remote-bound responses
-        to the per-destination flush accumulator (runtime.egress), so the
-        N responses of one inbound batch ride one fabric hand-off per
+        """SendResponse:769 — remote-bound APPLICATION responses join the
+        per-destination flush accumulator (runtime.egress), so the N
+        responses of one inbound batch ride one fabric hand-off per
         origin; local responses keep the synchronous loopback
-        (``transmit`` short-circuits into receive_message) and the
-        ``batched_egress=False`` A/B lever restores the per-message path
-        bit for bit."""
+        (``transmit`` short-circuits into receive_message)."""
         if request.direction == Direction.ONE_WAY:
             return
         response.target_silo = request.sending_silo
-        eg = self._egress
-        if eg is not None and response.category == Category.APPLICATION \
+        if response.category == Category.APPLICATION \
                 and response.target_silo is not None and \
                 response.target_silo != self.silo.silo_address:
             # APPLICATION responses only: PING/SYSTEM responses
@@ -1356,7 +1351,7 @@ class Dispatcher:
             # whole callback run, and a probe response delayed past the
             # probe timeout gets a healthy silo voted dead (the same
             # QoS split the reference's category queues exist for)
-            eg.add(response.target_silo, response)
+            self._egress.add(response.target_silo, response)
             return
         self.transmit(response)
 
@@ -1367,16 +1362,10 @@ class Dispatcher:
         Groups ride the egress accumulator and flush at this
         batch-completion boundary — one ``MessageCenter.send_batch`` per
         destination — instead of waiting for the armed end-of-burst
-        flush; without the batcher it degrades to per-message
-        ``send_response`` exactly."""
-        eg = self._egress
-        if eg is None:
-            for request, response in items:
-                self.send_response(request, response)
-            return
+        flush."""
         for request, response in items:
             self.send_response(request, response)
-        eg.flush()
+        self._egress.flush()
 
     # ==================================================================
     # Rejection / forwarding (TryForwardRequest:526)

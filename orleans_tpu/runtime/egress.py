@@ -33,10 +33,6 @@ the END of the loop's current ready run, which under saturation can
 exceed a probe timeout (observed as a false-death vote spiral in the
 chaos soak before the split). This is the same QoS split the
 category-partitioned inbound queues exist for.
-
-``SiloConfig.batched_egress=False`` never constructs one of these —
-``Dispatcher.send_response`` then takes the per-message path bit for
-bit, the A/B lever symmetric with ``batched_ingress``.
 """
 
 from __future__ import annotations
@@ -57,9 +53,9 @@ __all__ = ["EgressBatcher"]
 
 class EgressBatcher:
     """Per-destination response groups with an armed end-of-burst flush
-    (see module docstring). One per MessageCenter when
-    ``batched_egress`` is on; the dispatcher's ``send_response`` feeds
-    it for every remote-bound response."""
+    (see module docstring). One per MessageCenter; the dispatcher's
+    ``send_response`` feeds it for every remote-bound APPLICATION
+    response."""
 
     __slots__ = ("center", "groups", "_armed", "stats", "last_group",
                  "_sharded_dest")

@@ -106,7 +106,7 @@ per-endpoint sender write still ran on the main loop:
 
 ``egress_shards = 0`` (the default) constructs NONE of this: senders,
 encode, and client-route writes stay on the main loop bit for bit (the
-A/B lever, symmetric with ``batched_egress``/``ingress_loops``).
+A/B lever, symmetric with ``ingress_loops``).
 
 ``SiloConfig.ingress_loops = 1`` (the default) constructs NONE of this:
 the silo keeps today's in-loop ``asyncio.start_server`` pump bit for
@@ -1153,8 +1153,7 @@ class EgressShard:
             msgs,
             lambda m, e: fabric._client_encode_error(addr, writer, m, e,
                                                      native),
-            native=native, stats=None, templates=fabric.response_templates,
-            tmpl_cache=self.tmpl_cache)
+            native=native, stats=None, tmpl_cache=self.tmpl_cache)
         if chunks:
             if stamps is not None:
                 stamps.append((_EGRESS_ENCODE_STAT,

@@ -162,10 +162,6 @@ class RuntimeClient:
         # the last event-loop yield — bounds the forced-yield cadence when
         # the loop has nothing else ready
         self.hot_calls_since_yield = 0
-        # batched response correlation (receive_response_batch): the
-        # client-side half of the batched-egress A/B lever — off restores
-        # per-message receive_response for every delivered batch
-        self.batched_egress = True
 
     def enable_tracing(self, sample_rate: float = 1.0,
                        buffer_size: int = 4096, name: str = "client", *,
@@ -873,10 +869,6 @@ class RuntimeClient:
         anything else (observer notifications) takes the subclass's
         per-message ``deliver`` in arrival order. Only meaningful on
         client subclasses that define ``deliver``."""
-        if not self.batched_egress:
-            for m in msgs:
-                self.deliver(m)  # type: ignore[attr-defined]
-            return
         run: list | None = None
         for m in msgs:
             if m.direction == Direction.RESPONSE:
@@ -892,9 +884,9 @@ class RuntimeClient:
             self.receive_response_batch(run)
 
     def receive_response_batch(self, msgs: list) -> None:
-        """Batched response correlation — the client-side leg of batched
-        egress: N ``CallbackData`` lookups resolve in one pass and the
-        common SUCCESS/ERROR terminals defer their freelist releases into
+        """Batched response correlation — the client-side leg of the
+        response path: N ``CallbackData`` lookups resolve in one pass and
+        the common SUCCESS/ERROR terminals defer their freelist releases into
         ONE sweep per batch (request shell + response envelope each
         released exactly once, after every future has resolved), instead
         of per-message dict/recycle churn. Rejections (resend backoff,

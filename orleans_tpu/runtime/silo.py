@@ -35,6 +35,7 @@ from .cancellation import TokenInterner
 from .catalog import Catalog
 from .context import current_activation, current_call_chain
 from .dispatcher import Dispatcher
+from .egress import EgressBatcher
 from .hotlane import marker_ids as _marker_ids
 from .hotlane import try_hot_invoke as _hot_invoke
 from .invoker import InvokerTable
@@ -113,11 +114,6 @@ class SiloConfig:
     # where the queue stays short but every message waits long
     load_shedding_queue_wait: float = 0.0
     load_shedding_window: float = 5.0
-    # batched ingress (the batched-ingress pipeline, wire.decode_frames →
-    # MessageCenter.deliver_batch → grouped vector enqueue): off = the
-    # per-frame decode + per-message hand-off (the A/B lever; bytes on
-    # the wire are identical either way)
-    batched_ingress: bool = True
     # multi-loop silo ingress (runtime.multiloop): N >= 2 spawns N
     # dedicated ingress pump threads, each running its own event loop
     # with its own (vectored, hotwire.sock_recv_batch) socket pump; the
@@ -152,22 +148,13 @@ class SiloConfig:
     # today's single-process path bit for bit (the A/B lever). Requires
     # a SocketFabric and a file-backed membership table.
     worker_procs: int = 1
-    # batched egress (the response-path twin of batched_ingress):
-    # responses resolved from one inbound batch group per origin in a
-    # per-destination flush accumulator (runtime.egress.EgressBatcher)
-    # and ride ONE MessageCenter.send_batch → encode_message_batch write
-    # per destination (header-prefix template on the native build),
-    # instead of N per-message send_response → transmit hops. Off = the
-    # per-message response path bit for bit (the A/B lever; wire bytes
-    # are identical either way)
-    batched_egress: bool = True
-    # off-loop device-tick pipeline (dispatch.engine): the staging fill,
-    # operand upload, kernel dispatch, and host materialize sync of every
-    # vector tick run on a dedicated worker thread behind a tick-
+    # where a claimed device tick runs (dispatch.engine): True — the
+    # served path — on the engine's tick worker, behind the tick-
     # serialization fence, so host turns and the socket pump interleave
-    # with device hand-off instead of queueing behind it. Off = today's
-    # loop-inline tick (the A/B lever; results and turn semantics are
-    # identical either way)
+    # with device hand-off; False on the event loop, in place. The one
+    # old-path switch PR 30 kept: on the chip it read as a trade (hot-
+    # record tail against the median at the same rate, PERF.md section
+    # 6), not a loss; ROADMAP D2 has what is next
     offloop_tick: bool = True
     collection_age: float = 2 * 3600.0
     collection_quantum: float = 60.0
@@ -361,10 +348,11 @@ class MessageCenter:
         # ingest stage metrics (INGEST_STATS): cached so _route pays one
         # attribute load when metrics are off
         self._istats = silo.ingest_stats
-        # batched response egress (runtime.egress.EgressBatcher): set by
-        # the Silo ctor when batched_egress is on, else None — the
-        # per-message send path pays one attribute check
-        self.egress = None
+        # response egress (runtime.egress): responses resolved from one
+        # inbound batch group per destination and ride one fabric
+        # hand-off — the dispatcher's send_response feeds it, the armed
+        # flush drains it at batch-completion boundaries
+        self.egress = EgressBatcher(self)
 
     def start(self) -> None:
         self.running = True
@@ -374,11 +362,10 @@ class MessageCenter:
             self._pumps.append(loop.create_task(self._pump(cat)))
 
     def stop(self) -> None:
-        if self.egress is not None:
-            # hand any accumulated response groups to the fabric before
-            # the center stops accepting work (the armed flush callback
-            # may never run once the loop moves on to teardown)
-            self.egress.flush()
+        # hand any accumulated response groups to the fabric before the
+        # center stops accepting work (the armed flush callback may never
+        # run once the loop moves on to teardown)
+        self.egress.flush()
         self.running = False
         for t in self._pumps:
             t.cancel()
@@ -472,12 +459,8 @@ class MessageCenter:
             for m in msgs:
                 if m.received_at is None:  # socket arrivals pre-stamped
                     m.received_at = now
-        if not self.silo.config.batched_ingress or \
-                self.silo.config.load_shedding_enabled or \
+        if self.silo.config.load_shedding_enabled or \
                 any(q.qsize() for q in self.inbound.values()):
-            # per-message fall-back: the RECEIVING silo's A/B lever is
-            # honored even when a co-hosted batched-mode silo's fabric
-            # pump accepted the connection and grouped the read
             for m in msgs:
                 self.deliver(m)
             return
@@ -497,8 +480,7 @@ class MessageCenter:
         cat_counts: dict = {}
         # silo-to-silo responses arriving in one wire batch correlate in
         # one pass (receive_response_batch: one freelist-release sweep)
-        # when the batched response path is on; per-message otherwise
-        responses: list | None = [] if silo.config.batched_egress else None
+        responses: list = []
         for m in msgs:
             if ist is not None and m.received_at is not None:
                 # ingest enqueue stage (~0 inline) — one clock read for
@@ -507,13 +489,13 @@ class MessageCenter:
                 ist.observe(_INGEST_ENQUEUE, now - m.received_at)
                 m.received_at = now
             cat_counts[m.category] = cat_counts.get(m.category, 0) + 1
-            if responses is not None and m.direction == Direction.RESPONSE:
+            if m.direction == Direction.RESPONSE:
                 # grouped correlation: futures resolve via call_soon
                 # either way, so deferring these past the batch's
                 # requests reorders nothing observable
                 responses.append(m)
                 continue
-            if m.direction != Direction.RESPONSE and vifaces:
+            if vifaces:
                 vcls = vifaces.get(m.interface_name)
                 if vcls is not None:
                     # device-tier call: group — ownership/recovery checks
@@ -526,8 +508,7 @@ class MessageCenter:
                     g.append(m)
                     continue
             try:
-                if m.direction != Direction.RESPONSE and (
-                        m.target_silo is None or m.target_silo != my_addr):
+                if m.target_silo is None or m.target_silo != my_addr:
                     m.target_silo = None
                     silo.dispatcher.send_message(m)
                 else:
@@ -598,7 +579,7 @@ class MessageCenter:
         """Outbound to another silo/client via the fabric
         (MessageCenter.SendMessage:177-191)."""
         eg = self.egress
-        if eg is not None and eg.groups:
+        if eg.groups:
             # per-destination FIFO guard: a response group still pending
             # for this destination must reach the fabric BEFORE this
             # per-message send, or the send overtakes responses that
@@ -935,14 +916,6 @@ class Silo:
         self.runtime_client.tracer = self.tracer
         self.message_center = MessageCenter(self)
         self.dispatcher = Dispatcher(self)
-        if config.batched_egress:
-            # batched response egress (runtime.egress): responses
-            # resolved from one inbound batch group per destination and
-            # ride one fabric hand-off — send_response feeds it, the
-            # armed flush drains it at batch-completion boundaries
-            from .egress import EgressBatcher
-            self.message_center.egress = EgressBatcher(self.message_center)
-            self.dispatcher._egress = self.message_center.egress
         self.catalog = Catalog(self)
         # per-(grain_class, method) invoker table (runtime.invoker): built
         # once per class, consumed by the dispatcher's invoke engine and

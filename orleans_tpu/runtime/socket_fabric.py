@@ -49,15 +49,11 @@ from .references import GrainFactory
 from .runtime_client import RuntimeClient
 from .wire import (
     FrameError,
-    WireDecodeError,
-    _BodyDecodeError,
     decode_frames,
     decode_handshake,
-    decode_message,
     encode_handshake,
     encode_message,
     encode_message_batch,
-    frame_stream,
     leads_hostile_frame,
     read_frame,
     writev_leftover,
@@ -145,12 +141,11 @@ async def _read_frame_batches(reader: asyncio.StreamReader, ist=None,
     yielding ``(msgs, bounces)``; the partial tail of a frame stays
     buffered for the next read. Raises :class:`FrameError` when a hostile
     (oversized) announcement leads the remaining buffer — frames decoded
-    ahead of it were already yielded, matching the per-frame path's
-    deliver-then-drop behavior, and the link drops without waiting for
-    bytes the peer may never send. EOF mid-frame raises
-    ``IncompleteReadError`` under ``strict_tail`` (silo links surface the
-    torn tail) or just ends the pump (gateway: a torn tail is a clean
-    close)."""
+    ahead of it were already yielded (deliver, then drop), and the link
+    drops without waiting for bytes the peer may never send. EOF
+    mid-frame raises ``IncompleteReadError`` under ``strict_tail`` (silo
+    links surface the torn tail) or just ends the pump (gateway: a torn
+    tail is a clean close)."""
     buf = bytearray()
     while True:
         chunk = await reader.read(chunk_size)
@@ -171,7 +166,6 @@ async def _read_frame_batches(reader: asyncio.StreamReader, ist=None,
             yield msgs, bounces
         if leads_hostile_frame(buf):
             raise FrameError("oversized frame announced")
-
 
 
 # a peer that accepts TCP but never sends its handshake reply is wedged:
@@ -350,8 +344,7 @@ class _Sender:
             est = None
         chunks = encode_message_batch(
             batch, self.fabric.bounce_unencodable,
-            native=self.peer_native, stats=est,
-            templates=self.fabric.response_templates)
+            native=self.peer_native, stats=est)
         if not chunks:
             return
         led = self.fabric.ledger
@@ -391,7 +384,6 @@ class _Sender:
         chunks = encode_message_batch(
             batch, _bounce,
             native=self.peer_native, stats=None,
-            templates=fab.response_templates,
             tmpl_cache=shard.tmpl_cache)
         if chunks and stamps is not None and any(
                 m.direction == Direction.RESPONSE for m in batch):
@@ -520,12 +512,6 @@ class SocketFabric:
         # (same sharing rule as egress_stats): senders/client routes
         # charge wire bytes per route through it
         self.ledger = None
-        # header-prefix wire templates for response batches
-        # (wire.encode_message_batch templates= switch): cleared when any
-        # local silo runs batched_egress=False so the A/B lever also
-        # restores the per-frame header encode (bytes are identical
-        # either way — this only flips WHICH encoder produced them)
-        self.response_templates = True
         # sharded egress (runtime.multiloop.EgressShardPool): constructed
         # by register_silo when a local silo has egress_shards >= 1;
         # None = every sender/encode/write stays on the main loop
@@ -600,8 +586,6 @@ class SocketFabric:
             self.egress_stats = silo.stats
         if self.ledger is None and silo.ledger is not None:
             self.ledger = silo.ledger
-        if not silo.config.batched_egress:
-            self.response_templates = False
         sock = self._listen_socks.get(addr.endpoint)
         if sock is None:
             raise SiloUnavailableError(
@@ -986,7 +970,7 @@ class SocketFabric:
                 msgs, lambda m, e: log.warning(
                     "unencodable message to client %s during egress "
                     "teardown: %s", addr, e),
-                native=native, templates=self.response_templates)
+                native=native)
             if chunks:
                 self._marshal_client_write(writer, b"".join(chunks))
             return
@@ -997,8 +981,7 @@ class SocketFabric:
                 msgs,
                 lambda m, e: self._client_encode_error(addr, writer, m, e,
                                                        native),
-                native=native, stats=self.egress_stats,
-                templates=self.response_templates)
+                native=native, stats=self.egress_stats)
             if not chunks:
                 return
             if self.ledger is not None:
@@ -1093,8 +1076,8 @@ class SocketFabric:
                     # owner can relay responses produced elsewhere
                     self.route_notify(peer_addr, True)
             # ingest stage metrics (observability.stats.INGEST_STATS):
-            # decode is timed inside decode_frames/decode_message (which
-            # also stamp the envelope's received_at) and frames-per-read
+            # decode is timed inside decode_frames (which
+            # also stamps the envelope's received_at) and frames-per-read
             # lands in the batch histogram. The later stages (enqueue/
             # queue_wait) are observed downstream where the envelope is
             # provably still live — routing can consume a message
@@ -1108,32 +1091,8 @@ class SocketFabric:
                 # re-labels itself) — are pump work on the loop
                 from ..observability.profiling import mark_loop_category
                 mark_loop_category("pump")
-            if silo.config.batched_ingress:
-                await self._pump_batched(silo, reader, ist,
-                                         route=f"in:{peer_addr}")
-            else:
-                # per-frame hand-off (the batched-ingress A/B lever):
-                # decode + route one message per frame
-                on_batch = None
-                if ist is not None:
-                    from ..observability.stats import (COUNT_BOUNDS,
-                                                       INGEST_STATS)
-                    on_batch = ist.histogram_with(
-                        INGEST_STATS["frame_batch"], COUNT_BOUNDS).observe
-                async for headers, body in frame_stream(reader,
-                                                        on_batch=on_batch):
-                    try:
-                        msg = decode_message(headers, body, ist)
-                    except _BodyDecodeError as e:
-                        self._bounce_undecodable(e.message, str(e))
-                        continue
-                    except WireDecodeError as e:
-                        # headers undecodable: scoped to this message —
-                        # the frame was fully consumed, the link is fine
-                        log.warning("dropping message with undecodable "
-                                    "headers: %s", e)
-                        continue
-                    self._route_inbound(silo, msg)
+            await self._pump_batched(silo, reader, ist,
+                                     route=f"in:{peer_addr}")
         except (asyncio.IncompleteReadError, ConnectionResetError):
             pass  # clean EOF / peer died
         except FrameError as e:

@@ -50,7 +50,7 @@ _FRAME_BATCH = _INGEST["frame_batch"]
 
 __all__ = [
     "MAX_FRAME_SEGMENT", "FrameError", "WireDecodeError",
-    "encode_frame", "read_frame", "frame_stream",
+    "encode_frame", "read_frame",
     "encode_message", "decode_message",
     "encode_message_batch", "decode_frames", "finish_batch_entries",
     "writev_leftover",
@@ -90,50 +90,6 @@ async def read_frame(reader: asyncio.StreamReader) -> tuple[bytes, bytes]:
     headers = await reader.readexactly(hlen) if hlen else b""
     body = await reader.readexactly(blen) if blen else b""
     return headers, body
-
-
-async def frame_stream(reader: asyncio.StreamReader, chunk_size: int = 1 << 16,
-                       on_batch=None):
-    """Yield (headers, body) frames from a buffered chunk reader.
-
-    The per-frame path (`read_frame`) costs three readexactly awaits per
-    message; under load this reads a socket chunk once and parses every
-    complete frame out of it (the IncomingMessageBuffer batching,
-    IncomingMessageBuffer.cs:125). Ends cleanly at EOF on a frame
-    boundary; raises IncompleteReadError for a mid-frame EOF and
-    FrameError for an oversized announcement (connection must drop).
-
-    ``on_batch`` (metrics): called with the number of complete frames
-    parsed out of each socket read — the receive-side batching-degree
-    signal (frames-per-wakeup ≈ how hard the sender/backlog is driving
-    this link)."""
-    buf = bytearray()
-    pos = 0
-    while True:
-        end = len(buf)
-        n_frames = 0
-        while end - pos >= 8:
-            hlen, blen = _LEN.unpack_from(buf, pos)
-            if hlen > MAX_FRAME_SEGMENT or blen > MAX_FRAME_SEGMENT:
-                raise FrameError(f"oversized frame announced: {hlen}+{blen}")
-            total = 8 + hlen + blen
-            if end - pos < total:
-                break
-            h0 = pos + 8
-            yield bytes(buf[h0:h0 + hlen]), bytes(buf[h0 + hlen:pos + total])
-            pos += total
-            n_frames += 1
-        if on_batch is not None and n_frames:
-            on_batch(n_frames)
-        if pos:
-            del buf[:pos]
-            pos = 0
-        chunk = await reader.read(chunk_size)
-        if not chunk:
-            if buf:
-                raise asyncio.IncompleteReadError(bytes(buf), None)
-            return
-        buf += chunk
 
 
 # ---------------------------------------------------------------------------
@@ -439,8 +395,8 @@ def encode_message_batch(msgs: list, bounce, native: bool = True,
             try:
                 if _msg_mod._DEBUG_POOL:
                     # inside the try: a poisoned envelope bounces like any
-                    # other per-message failure (the per-frame path's
-                    # behavior) instead of killing the sender task
+                    # other per-message failure instead of killing the
+                    # sender task
                     _msg_mod.assert_live(m, "wire.encode_message_batch")
                 ttl = None
                 if m.expires_at is not None:
@@ -526,7 +482,7 @@ def decode_frames(buf, stats=None) -> tuple[int, list, list]:
     bytes were fully parsed (the caller keeps the partial tail for the
     next socket read); ``bounces`` are :class:`_BodyDecodeError`\\ s whose
     headers survived (route an error back); header-undecodable frames are
-    dropped with a log, exactly like the per-frame path.
+    dropped with a log.
 
     Native path: ONE ``unpack_batch`` C call decodes every hotwire frame
     straight into blank Message shells; pickle-peer frames in the same
@@ -561,8 +517,8 @@ def decode_frames(buf, stats=None) -> tuple[int, list, list]:
             if hlen > MAX_FRAME_SEGMENT or blen > MAX_FRAME_SEGMENT:
                 if pos > 0:
                     # deliver the frames parsed ahead of the hostile
-                    # announcement (per-frame parity); the next call sees
-                    # it at position 0 and raises then
+                    # announcement; the next call sees it at position
+                    # 0 and raises then
                     break
                 raise FrameError(f"oversized frame announced: {hlen}+{blen}")
             total = 8 + hlen + blen

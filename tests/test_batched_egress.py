@@ -293,6 +293,44 @@ async def test_system_and_ping_responses_bypass_accumulator():
     assert silo.message_center.egress.groups
 
 
+async def test_every_silo_groups_application_responses_of_one_batch():
+    """No option chooses the response path: a silo built with nothing
+    set has the accumulator, and the APPLICATION responses of one batch
+    leave as ONE group per destination at the batch boundary while the
+    PING response ahead of them has already left on its own."""
+    from orleans_tpu.core.message import Category
+    from orleans_tpu.runtime.cluster import InProcFabric
+
+    class Echo(Grain):
+        async def ping(self):
+            return 1
+
+    fabric = InProcFabric()
+    silo = (SiloBuilder().with_fabric(fabric).add_grains(Echo)).build()
+    eg = silo.message_center.egress
+    assert isinstance(eg, EgressBatcher) and silo.dispatcher._egress is eg
+    assert not hasattr(silo.config, "batched_egress")
+    fabric.is_dead = lambda a: False
+    sent = []
+    fabric.deliver_group = lambda dest, msgs: sent.append(
+        ("group", dest, [m.category for m in msgs]))
+    fabric.deliver = lambda msg: sent.append(("single", msg.category))
+
+    def pair(i, cat=Category.APPLICATION):
+        req = make_request(target_grain=GrainId.for_grain(GT, i),
+                           interface_name="Echo", method_name="ping",
+                           body=((), {}), sending_silo=S2, target_silo=S1,
+                           category=cat)
+        return req, make_response(req, i)
+
+    items = [pair(0, Category.PING), pair(1), pair(2), pair(3)]
+    silo.dispatcher.send_response_batch(items)
+    # flushed at the batch boundary, not at the armed end-of-burst flush
+    assert sent == [("single", Category.PING),
+                    ("group", S2, [Category.APPLICATION] * 3)]
+    assert not eg.groups
+
+
 async def test_send_message_drains_pending_group_for_fifo():
     """MessageCenter.send_message must flush a pending response group to
     its destination before the per-message send — per-sender FIFO per
@@ -419,15 +457,6 @@ async def test_deliver_batch_mixed_runs_preserve_order():
     client.deliver_batch([notify, make_response(req, 5)])
     assert client.delivered == [notify]
     assert fut.result() == 5
-    # the per-message lever: batched correlation off, deliver() sees all
-    client.batched_egress = False
-    req2 = make_request(target_grain=GrainId.for_grain(GT, 3),
-                        interface_name="eg.IEcho", method_name="m",
-                        body=((), {}), sending_silo=S2, target_silo=S1)
-    fut2 = loop.create_future()
-    client.callbacks[req2.id] = _fresh_callback(req2, fut2, None, None)
-    client.deliver_batch([make_response(req2, 6)])
-    assert fut2.result() == 6  # deliver() -> receive_response per message
 
 
 # ---------------------------------------------------------------------------
@@ -498,14 +527,10 @@ async def _socket_cluster(vec_cls=None, n_keys: int = 32, **cfg):
     return silo, client, EchoGrain
 
 
-@pytest.mark.parametrize("egress", [True, False])
-async def test_vector_call_batch_results_identical_either_lever(egress):
+async def test_vector_call_batch_results_over_sockets():
     CounterVec = _vector_counter()
-    silo, client, EchoGrain = await _socket_cluster(
-        CounterVec, batched_egress=egress)
-    client.batched_egress = egress
+    silo, client, EchoGrain = await _socket_cluster(CounterVec)
     try:
-        assert (silo.message_center.egress is not None) == egress
         # vector burst through call_batch: responses resolve from one
         # inbound batch — the exact shape the egress pipeline groups
         outs = await asyncio.gather(*client.call_batch(

@@ -427,23 +427,6 @@ async def test_call_group_all_failed_leaves_no_pending_entry():
     assert not rt.pending
 
 
-async def test_per_frame_fallback_config_still_works():
-    """batched_ingress=False restores the per-frame hand-off end to end
-    (the A/B lever the floor test leans on)."""
-    CounterVec = _vector_counter()
-    silo, client, EchoGrain = await _socket_cluster(
-        CounterVec, n_keys=8, batched_ingress=False)
-    try:
-        g = client.get_grain(EchoGrain, "pf")
-        assert await asyncio.gather(*(g.record(i) for i in range(10))) == \
-            list(range(10))
-        r = client.get_grain(CounterVec, 3)
-        assert int(await r.bump(x=np.int32(0))) == 0
-    finally:
-        await client.close_async()
-        await silo.stop()
-
-
 # ---------------------------------------------------------------------------
 # Queue-wait-trend load shedding
 # ---------------------------------------------------------------------------
@@ -664,28 +647,53 @@ async def test_vector_batch_bad_kwargs_scoped_to_one_message():
         await silo.stop()
 
 
-async def test_deliver_batch_honors_receiver_batched_ingress_off():
-    """A co-hosted batched-mode silo's fabric pump may hand a grouped
-    read to a batched_ingress=False silo: the RECEIVER's A/B lever must
-    still route per-message."""
+@pytest.mark.parametrize("observed", ["shedding", "backlog"])
+async def test_deliver_batch_goes_per_message_on_what_it_observes(observed):
+    """No option chooses the route of a grouped read. The silo routes it
+    as a unit unless shedding is on (queue depth is the shed signal, so
+    ingress must accumulate) or a category is backlogged (what is queued
+    goes first): then every message takes :meth:`deliver`, in order, and
+    the calls still land once each."""
+    cfg = {"load_shedding_enabled": True} if observed == "shedding" else {}
     CounterVec = _vector_counter()
     silo, client, EchoGrain = await _socket_cluster(
-        CounterVec, n_keys=4, batched_ingress=False)
+        CounterVec, n_keys=4, **cfg)
     try:
         mc = silo.message_center
-        mc._route_batch = lambda msgs: pytest.fail(
-            "batched route taken with batched_ingress=False")
-        vecg = GrainType.of("CounterVec")
-        msgs = [make_request(
-            target_grain=GrainId.for_grain(vecg, k),
-            interface_name="CounterVec", method_name="bump",
-            body=((), {"x": np.int32(0)}), direction=Direction.ONE_WAY)
-            for k in range(4)]
-        mc.deliver_batch(msgs)
+        assert not hasattr(silo.config, "batched_ingress")
+        routed, single = [], []
+        route_batch, deliver = mc._route_batch, mc.deliver
+        mc._route_batch = lambda msgs: (routed.append(len(msgs)),
+                                        route_batch(msgs))
+        mc.deliver = lambda m: (single.append(m.target_grain.key),
+                                deliver(m))
+
+        def burst():
+            vecg = GrainType.of("CounterVec")
+            return [make_request(
+                target_grain=GrainId.for_grain(vecg, k),
+                interface_name="CounterVec", method_name="bump",
+                body=((), {"x": np.int32(0)}), direction=Direction.ONE_WAY)
+                for k in range(4)]
+
+        if observed == "backlog":
+            mc.deliver_batch(burst())            # nothing queued: a unit
+            assert routed == [4] and not single
+            # something queued ahead in any category: no overtaking
+            mc.inbound[Category.APPLICATION].put_nowait(make_request(
+                target_grain=GrainId.for_grain(GrainType.of("EchoGrain"),
+                                               "q"),
+                interface_name="EchoGrain", method_name="record",
+                body=((7,), {}), direction=Direction.ONE_WAY))
+        mc.deliver_batch(burst())
+        assert single == list(range(4))
+        assert routed == ([4] if observed == "backlog" else [])
+        bursts = len(routed) + 1                 # the reads below route too
+        await asyncio.sleep(0.05)                # the category pump drains
         await silo.vector.flush()
         reads = await asyncio.gather(
             *(client.get_grain(CounterVec, k).read() for k in range(4)))
-        assert [int(v) for v in reads] == [1] * 4
+        assert [int(v) for v in reads] == [bursts] * 4
     finally:
         await client.close_async()
         await silo.stop()

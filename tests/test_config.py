@@ -101,6 +101,68 @@ class TestBuilderIntegration:
         assert rt.capacity_per_shard == 64
 
 
+@pytest.mark.parametrize("gone", ["batched_ingress", "batched_egress"])
+def test_deleted_path_options_are_rejected_by_name(gone):
+    """The per-frame ingress pump and the per-message response path are
+    gone with their switches: an old deployment file that still sets one
+    must fail loudly, naming it, on every way in — never be ignored."""
+    from orleans_tpu.runtime.silo import SiloConfig
+    with pytest.raises(TypeError, match=gone):
+        SiloConfig(**{gone: False})
+    with pytest.raises(AttributeError, match=gone):
+        SiloBuilder().with_config(**{gone: False})
+    with pytest.raises(TypeError, match=gone):
+        MessagingOptions(**{gone: False})
+    assert not hasattr(flatten(MessagingOptions()), gone)
+
+
+def test_the_tick_lever_has_one_field_and_one_default():
+    """``offloop_tick`` is the one old-path switch left (PERF.md section
+    6, PR 30). It is a silo option with one default, the served path;
+    ``DispatchOptions`` no longer carries a second copy with the
+    opposite default, so a bare runtime ticks on its worker."""
+    from orleans_tpu.config import DispatchOptions
+    from orleans_tpu.dispatch import VectorRuntime
+    from orleans_tpu.parallel import make_mesh
+    from orleans_tpu.runtime.silo import SiloConfig
+    with pytest.raises(TypeError, match="offloop_tick"):
+        DispatchOptions(offloop_tick=True)
+    assert SiloConfig().offloop_tick is True
+    assert flatten(MessagingOptions()).offloop_tick is True
+    assert flatten(MessagingOptions(offloop_tick=False)).offloop_tick is False
+    rt = VectorRuntime(mesh=make_mesh(1),
+                       options=DispatchOptions(capacity_per_shard=8))
+    assert rt.offloop_tick is True
+    assert not hasattr(VectorRuntime, "_run_batch")
+
+
+def test_dispatch_imports_nothing_from_the_loop_profiler():
+    """Layering: the device tier is observed, it does not reach up into
+    an observer. ``VectorRuntime.loop_prof`` is injected by the silo;
+    no module under ``orleans_tpu/dispatch`` imports
+    ``observability.profiling``."""
+    import ast
+    import pathlib
+
+    import orleans_tpu.dispatch as pkg
+    root = pathlib.Path(pkg.__file__).parent
+    files = sorted(root.rglob("*.py"))
+    assert len(files) > 5
+    bad = []
+    for f in files:
+        for node in ast.walk(ast.parse(f.read_text())):
+            if isinstance(node, ast.ImportFrom):
+                names = [node.module or ""] + [
+                    f"{node.module or ''}.{a.name}" for a in node.names]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            else:
+                continue
+            if any(n.endswith("observability.profiling") for n in names):
+                bad.append((f.name, node.lineno))
+    assert not bad, bad
+
+
 def test_log_options_dumps_every_field(caplog):
     with caplog.at_level(logging.INFO, logger="orleans.options"):
         log_options(MessagingOptions(), MembershipOptions())

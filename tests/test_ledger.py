@@ -270,48 +270,68 @@ async def test_host_turns_charged_with_key_and_tenant():
 # Device tier: engine charges + the on-device cost twin
 # ---------------------------------------------------------------------------
 
-def _vector_silo(name, *, offloop: bool, tenant_of=None, n_shards=1):
+def _vector_silo(name, *, tenant_of=None, n_shards=1):
     b = (SiloBuilder().with_name(name).add_grains(EchoGrain)
          .with_config(ledger_enabled=True, ledger_top_k=16,
-                      ledger_tenant_of=tenant_of, offloop_tick=offloop))
+                      ledger_tenant_of=tenant_of))
     add_vector_grains(b, CounterVec, mesh=make_mesh(n_shards),
                       capacity_per_shard=16)
     return b.build()
 
 
-async def test_device_ticks_charged_inline():
-    silo = _vector_silo("led-dev", offloop=False,
+async def test_device_ticks_charged_exactly_on_two_shards():
+    """Every message of every tick is charged once, whichever shard its
+    key lives on: ticks = payloads, rows = messages, row-seconds = the
+    sum over ticks of rows x that tick's wall, each key its ticks'
+    walls."""
+    silo = _vector_silo("led-dev", n_shards=2,
                         tenant_of=lambda label: "vec-tenant")
     await silo.start()
+    led = silo.ledger
+    payloads = []
+    charge_tick = led.charge_tick
+
+    def spy(payload):
+        payloads.append(payload)
+        charge_tick(payload)
+
+    led.charge_tick = spy
     client = await ClusterClient(silo.fabric).connect()
     try:
-        refs = [client.get_grain(CounterVec, k) for k in range(4)]
-        for rnd in range(3):
+        n_keys, rounds = 6, 3
+        refs = [client.get_grain(CounterVec, k) for k in range(n_keys)]
+        for rnd in range(rounds):
             await asyncio.gather(*(r.add(x=1.0) for r in refs))
-        led = silo.ledger
-        row = led.device[("CounterVec", "add")]
-        assert row[1] >= 12 and row[2] > 0.0          # rows, row-seconds
-        assert led.total_row_seconds() > 0.0
+        await silo.vector.flush()
+        tbl = silo.vector.table(CounterVec)
+        assert {tbl.key_to_slot[k][0] for k in range(n_keys)} == {0, 1}
+        # stamped on the worker, replayed on the loop: one per tick
+        assert len(payloads) == led.device[("CounterVec", "add")][0]
+        ticks, rows, row_seconds = led.device[("CounterVec", "add")]
+        assert rows == n_keys * rounds == sum(p[2] for p in payloads)
+        assert row_seconds == pytest.approx(
+            sum(p[2] * p[3] for p in payloads), rel=1e-9)
+        assert led.total_row_seconds() == pytest.approx(row_seconds)
+        assert all(len(p[4]) == p[2] and p[3] > 0.0 for p in payloads)
         # per-key device labels + hook tenancy (no baggage on batches)
         assert any(lbl.startswith("CounterVec#")
                    for lbl, _c, _e in led.keys.top())
         assert any(t[0] == "vec-tenant" for t in led.tenants.top())
         # the on-device twin was enabled by hosting and accumulated
-        tbl = silo.vector.table(CounterVec)
         assert silo.vector.track_cost and tbl.cost is not None
         assert tbl.cost_seconds() > 0.0
-        assert led.charges > 0
+        assert led.charges >= ticks
     finally:
         await client.close_async()
         await silo.stop()
 
 
-async def test_offloop_tick_charges_replay_loop_side(debug_pool):
+async def test_tick_charges_replay_loop_side(debug_pool):
     """The tick worker may not touch the loop-confined ledger: charges
     stamp into the job's deferred list and replay in _complete_job.
     Runs under ORLEANS_TPU_DEBUG_POOL=1 so the charged batched path also
     proves pool discipline (the ISSUE 17 satellite)."""
-    silo = _vector_silo("led-offloop", offloop=True)
+    silo = _vector_silo("led-offloop")
     await silo.start()
     client = await ClusterClient(silo.fabric).connect()
     try:

@@ -59,10 +59,9 @@ class FloatCell(VectorGrain):
         return state, state["v"]
 
 
-def _rt(n_shards=4, dense=None, capacity=64, offloop=False) -> VectorRuntime:
+def _rt(n_shards=4, dense=None, capacity=64) -> VectorRuntime:
     rt = VectorRuntime(mesh=make_mesh(n_shards),
                        capacity_per_shard=capacity)
-    rt.offloop_tick = offloop
     rt.register(Cell)
     if dense:
         rt.table(Cell).ensure_dense(dense)
@@ -126,8 +125,8 @@ async def test_map_actors_defers_conflicting_per_key_turns():
     assert int(s) == 8 * 11  # both the per-key add AND the bulk add ran
 
 
-async def test_map_actors_offloop_worker_parity():
-    rt = _rt(dense=16, offloop=True)
+async def test_map_actors_worker_parity():
+    rt = _rt(dense=16)
     try:
         futs = [rt.call(Cell, k, "add", c=np.int32(2)) for k in range(16)]
         n = await rt.map_actors(Cell, "add", {"c": np.int32(3)})
@@ -307,7 +306,6 @@ async def test_bulk_ops_survive_table_grow_racing(request):
     """Continuous bulk ticks (off-loop worker live) while hashed
     allocations force grow(): every write lands, none truncated."""
     rt = VectorRuntime(mesh=make_mesh(2), capacity_per_shard=8)
-    rt.offloop_tick = True
     rt.register(Cell)
     request.addfinalizer(rt.shutdown_worker)
     base = 10**13
@@ -362,7 +360,7 @@ async def test_bulk_ops_safe_across_migration_rounds():
 async def test_bulk_in_flight_keys_are_fenced(request):
     """While an off-loop per-key batch is in flight, a concurrent bulk
     apply defers those keys (pending_key_hashes covers the worker)."""
-    rt = _rt(dense=8, offloop=True)
+    rt = _rt(dense=8)
     request.addfinalizer(rt.shutdown_worker)
     futs = [rt.call(Cell, k, "add", c=np.int32(1)) for k in range(8)]
     # hand the batch to the worker, then immediately bulk-apply
@@ -378,7 +376,7 @@ async def test_bulk_in_flight_keys_are_fenced(request):
 async def test_bulk_snapshot_restore_roundtrip_under_traffic():
     """Checkpoint capture racing bulk ticks: the fence serializes the
     snapshot against in-flight kernels, and restore round-trips."""
-    rt = _rt(dense=16, offloop=False)
+    rt = _rt(dense=16)
     await rt.map_actors(Cell, "add", {"c": np.int32(3)},
                         keys=np.arange(16))
     tbl = rt.table(Cell)
@@ -551,7 +549,6 @@ async def test_bulk_storm_holds_qos_invariant():
         membership_refresh_period=0.3,
         membership_vote_expiration=5.0,
         response_timeout=5.0,
-        batched_egress=True,
     )
     fabric = InProcFabric()
     table = InMemoryMembershipTable()

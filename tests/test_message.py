@@ -156,7 +156,7 @@ def test_stack_overflow_guard():
 
 
 # ---------------------------------------------------------------------------
-# frame_stream: buffered chunked frame parsing (wire.py)
+# the receive pump's chunked framing (socket_fabric._read_frame_batches)
 # ---------------------------------------------------------------------------
 
 class _ChunkReader:
@@ -169,44 +169,73 @@ class _ChunkReader:
         return self.chunks.pop(0) if self.chunks else b""
 
 
-async def _collect_frames(chunks):
-    from orleans_tpu.runtime.wire import frame_stream
-    out = []
-    async for h, b in frame_stream(_ChunkReader(chunks)):
-        out.append((h, b))
-    return out
+def _pump_messages(n: int = 5) -> list:
+    from orleans_tpu.core.ids import GrainId, GrainType
+    from orleans_tpu.core.message import make_request
+    return [make_request(
+        target_grain=GrainId.for_grain(GrainType.of("PumpGrain"), i),
+        interface_name="PumpGrain", method_name=f"m{i}",
+        body=((b"body-" * i,), {})) for i in range(n)]
 
 
-def test_frame_stream_parses_frames_across_chunk_boundaries():
+def _collect_batches(chunks, strict_tail: bool = True) -> list:
     import asyncio
-    from orleans_tpu.runtime.wire import encode_frame
-    frames = [(f"h{i}".encode(), f"body-{i}".encode() * i) for i in range(5)]
-    blob = b"".join(encode_frame(h, b) for h, b in frames)
+    from orleans_tpu.runtime.socket_fabric import _read_frame_batches
+
+    async def collect():
+        out = []
+        async for msgs, bounces in _read_frame_batches(
+                _ChunkReader(chunks), strict_tail=strict_tail):
+            assert not bounces
+            out.append([(m.method_name, m.body) for m in msgs])
+        return out
+
+    return asyncio.run(collect())
+
+
+def test_pump_parses_frames_across_chunk_boundaries():
+    from orleans_tpu.runtime.wire import encode_message
+    msgs = _pump_messages()
+    want = [(m.method_name, m.body) for m in msgs]
+    blob = b"".join(encode_message(m) for m in msgs)
     # all at once, byte-by-byte, and ragged 7-byte chunks
     for chunking in ([blob],
                      [blob[i:i + 1] for i in range(len(blob))],
                      [blob[i:i + 7] for i in range(0, len(blob), 7)]):
-        got = asyncio.get_event_loop_policy().new_event_loop()\
-            .run_until_complete(_collect_frames(chunking))
-        assert got == frames, chunking
+        reads = _collect_batches(chunking)
+        assert [x for read in reads for x in read] == want
+        assert all(reads), "a read without a whole frame yields nothing"
+    assert len(_collect_batches([blob])) == 1   # one read, one hand-off
 
 
-def test_frame_stream_mid_frame_eof_raises():
+def test_pump_mid_frame_eof_raises_on_a_silo_link_only():
     import asyncio
     import pytest
-    from orleans_tpu.runtime.wire import encode_frame
-    blob = encode_frame(b"hh", b"bb")[:-1]
-    loop = asyncio.get_event_loop_policy().new_event_loop()
+    from orleans_tpu.runtime.wire import encode_message
+    first, torn = (encode_message(m) for m in _pump_messages(2))
     with pytest.raises(asyncio.IncompleteReadError):
-        loop.run_until_complete(_collect_frames([blob]))
+        _collect_batches([first + torn[:-1]])
+    # a gateway link: a torn tail is a clean close after what was whole
+    assert [len(r) for r in _collect_batches([first + torn[:-1]],
+                                             strict_tail=False)] == [1]
 
 
-def test_frame_stream_oversized_announcement_raises():
+def test_pump_oversized_announcement_raises_after_the_frames_ahead():
     import asyncio
     import struct
     import pytest
-    from orleans_tpu.runtime.wire import MAX_FRAME_SEGMENT, FrameError
+    from orleans_tpu.runtime.socket_fabric import _read_frame_batches
+    from orleans_tpu.runtime.wire import (MAX_FRAME_SEGMENT, FrameError,
+                                          encode_message)
+    good = encode_message(_pump_messages(1)[0])
     bad = struct.pack("<II", MAX_FRAME_SEGMENT + 1, 0) + b"x" * 16
-    loop = asyncio.get_event_loop_policy().new_event_loop()
+    got = []
+
+    async def pump():
+        async for msgs, _b in _read_frame_batches(
+                _ChunkReader([good + bad]), strict_tail=True):
+            got.extend(m.method_name for m in msgs)
+
     with pytest.raises(FrameError):
-        loop.run_until_complete(_collect_frames([bad]))
+        asyncio.run(pump())
+    assert got == ["m0"]    # delivered, then the link drops

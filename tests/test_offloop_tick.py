@@ -1,20 +1,21 @@
-"""Off-loop device-tick pipeline: the tick worker, the tick-serialization
+"""The device-tick pipeline: the tick worker, the tick-serialization
 fence for donated state/staging, and the deliberate client-side
 ``call_batch`` path.
 
 The hard invariants under test (ISSUE 9 tentpole):
 
-* worker-side ticks produce results identical to the inline path, with
-  turn semantics (one message per activation per tick) preserved under
-  concurrent enqueue-during-tick;
+* worker-side ticks produce the results a plain per-key total gives,
+  with turn semantics (one message per activation per tick) preserved
+  under concurrent enqueue-during-tick;
 * ``grow()`` (loop-side, triggered by hashed allocation) can never
   interleave with a worker-side batch whose donated state/staging upload
   is in flight — the table fence serializes them;
 * the migration fence sees worker-in-flight keys
   (``pending_key_hashes``), so a rebalance shard move can never race an
   executing batch;
-* ``flush()`` drains worker-side in-flight batches (and stays the
-  historical tick-and-yield spin on the inline path);
+* ``flush()`` drains worker-side in-flight batches;
+* a bare ``VectorRuntime`` starts its worker on the first claimed job
+  like a silo-hosted one, and an abandoned runtime's worker exits;
 * the batched client path honors ``ORLEANS_TPU_DEBUG_POOL=1`` pool
   discipline end to end;
 * the hand-off to the worker is bounded (ISSUE 29): a (class, method)
@@ -24,6 +25,7 @@ The hard invariants under test (ISSUE 9 tentpole):
 """
 
 import asyncio
+import gc
 
 import jax.numpy as jnp
 import numpy as np
@@ -59,40 +61,38 @@ class EchoGrain(Grain):
         return x
 
 
-def _build(offloop: bool, *, dense: int | None = 64,
-           capacity: int = 64, n_shards: int = 1):
-    b = (SiloBuilder().with_name(f"ot-{offloop}")
-         .add_grains(EchoGrain)
-         .with_config(offloop_tick=offloop))
+def _build(*, dense: int | None = 64,
+           capacity: int = 64, n_shards: int = 1, **cfg):
+    b = SiloBuilder().with_name("ot").add_grains(EchoGrain).with_config(**cfg)
     add_vector_grains(b, CounterVec, mesh=make_mesh(n_shards),
                       capacity_per_shard=capacity,
                       dense={CounterVec: dense} if dense else None)
     return b.build()
 
 
-async def test_offloop_results_match_inline():
-    """Same traffic through both levers → identical per-key state."""
-    totals = {}
-    for offloop in (False, True):
-        silo = _build(offloop)
-        await silo.start()
-        client = await ClusterClient(silo.fabric).connect()
-        try:
-            refs = [client.get_grain(CounterVec, k) for k in range(16)]
-            for rnd in range(5):
-                await asyncio.gather(*(r.add(x=float(rnd + k))
-                                       for k, r in enumerate(refs)))
-            out = await asyncio.gather(*(r.read() for r in refs))
-            totals[offloop] = [float(v) for v in out]
-            if offloop:
-                # the worker actually engaged (lazily started on traffic)
-                assert silo.vector._worker is not None
-            else:
-                assert silo.vector._worker is None
-        finally:
-            await client.close_async()
-            await silo.stop()
-    assert totals[True] == totals[False]
+async def test_worker_results_match_plain_totals():
+    """Served traffic through the worker → the per-key state a plain
+    Python running total gives."""
+    want = [0.0] * 16
+    silo = _build()
+    await silo.start()
+    client = await ClusterClient(silo.fabric).connect()
+    try:
+        assert silo.vector._worker is None  # started lazily, on traffic
+        refs = [client.get_grain(CounterVec, k) for k in range(16)]
+        for rnd in range(5):
+            got = await asyncio.gather(*(r.add(x=float(rnd + k))
+                                         for k, r in enumerate(refs)))
+            for k in range(16):
+                want[k] += float(rnd + k)
+            assert [float(v) for v in got] == want
+        out = await asyncio.gather(*(r.read() for r in refs))
+        assert [float(v) for v in out] == want
+        assert silo.vector._worker.is_alive()
+    finally:
+        await client.close_async()
+        await silo.stop()
+    assert silo.vector._worker is None  # silo stop ended the thread
 
 
 async def test_concurrent_enqueue_during_tick_preserves_turns():
@@ -100,7 +100,7 @@ async def test_concurrent_enqueue_during_tick_preserves_turns():
     in some tick, one-per-activation-per-tick, and per-key sums come out
     exact (the donation/rotation discipline never loses or doubles a
     write)."""
-    silo = _build(True)
+    silo = _build()
     await silo.start()
     client = await ClusterClient(silo.fabric).connect()
     try:
@@ -129,7 +129,7 @@ async def test_grow_racing_worker_upload():
     sink re-point) while worker batches are continuously in flight: the
     table fence serializes the swap against donated uploads, and no
     write is lost across the growth."""
-    silo = _build(True, dense=None, capacity=8)
+    silo = _build(dense=None, capacity=8)
     await silo.start()
     client = await ClusterClient(silo.fabric).connect()
     try:
@@ -161,7 +161,7 @@ async def test_migration_fence_sees_inflight_keys():
     fences shard moves on — until the loop-side completion runs. Made
     deterministic by holding the tick fence from the test: the worker
     blocks on it, so the batch is provably in flight."""
-    silo = _build(True)
+    silo = _build()
     await silo.start()
     client = await ClusterClient(silo.fabric).connect()
     try:
@@ -195,44 +195,159 @@ async def test_migration_fence_sees_inflight_keys():
 
 async def test_flush_drains_worker_inflight():
     """``flush()`` returns only after pending AND worker-in-flight work
-    retired, on both levers (one-way calls leave no futures to await, so
-    flush is the only drain)."""
-    for offloop in (False, True):
-        silo = _build(offloop)
+    retired (one-way calls leave no futures to await, so flush is the
+    only drain)."""
+    silo = _build()
+    await silo.start()
+    try:
+        rt = silo.vector
+        for k in range(12):
+            rt.call(CounterVec, k, "add", x=float(k))
+        await rt.flush()
+        assert not rt.pending and rt._inflight == 0
+        assert rt.messages_processed >= 12
+    finally:
+        await silo.stop()
+
+
+async def test_bare_runtime_ticks_on_its_worker():
+    """A bare VectorRuntime (no silo, no options) runs the served tick:
+    its worker starts on the first claimed job and ``flush()`` drains
+    it."""
+    rt = VectorRuntime(mesh=make_mesh(1), capacity_per_shard=16)
+    assert rt._worker is None
+    fut = rt.call(CounterVec, 5, "add", x=3.0)
+    assert rt._worker is None  # nothing claimed yet
+    await rt.flush()
+    assert fut.done() and float(fut.result()) == 3.0
+    assert rt._worker.is_alive()
+    assert rt._worker.name == "orleans-tick-worker" and rt._worker.daemon
+    assert not rt.pending and rt._inflight == 0 and rt._quiesced.is_set()
+    rt.shutdown_worker()
+
+
+async def test_shutdown_worker_twice_then_a_call_restarts_it():
+    from orleans_tpu.config import DispatchOptions
+    rt = VectorRuntime(mesh=make_mesh(1),
+                       options=DispatchOptions(capacity_per_shard=16))
+    rt.shutdown_worker()  # never started: nothing to stop
+    assert float(await rt.call(CounterVec, 5, "add", x=3.0)) == 3.0
+    first = rt._worker
+    rt.shutdown_worker()
+    rt.shutdown_worker()
+    assert rt._worker is None and not first.is_alive()
+    assert float(await rt.call(CounterVec, 5, "add", x=1.0)) == 4.0
+    assert rt._worker is not first and rt._worker.is_alive()
+    rt.shutdown_worker()
+    assert not rt._worker_stop.alive  # the restart's finaliser went too
+
+
+def test_abandoned_runtime_worker_exits():
+    """Hundreds of short-lived bare runtimes are built in one process:
+    the idle worker must not pin its runtime, and collecting the runtime
+    ends the thread."""
+    import weakref
+
+    async def use() -> tuple:
+        rt = VectorRuntime(mesh=make_mesh(1), capacity_per_shard=16)
+        assert float(await rt.call(CounterVec, 1, "add", x=2.0)) == 2.0
+        return rt._worker, weakref.ref(rt)
+
+    worker, ref = asyncio.run(use())
+    for _ in range(3):
+        gc.collect()
+    assert ref() is None, "the idle worker (or a cycle) pins the runtime"
+    worker.join(10.0)
+    assert not worker.is_alive()
+
+
+def test_bare_runtime_survives_a_second_event_loop():
+    """One runtime driven by two ``asyncio.run`` calls: completions post
+    to the loop that claimed, and ``flush()`` waits on that loop's
+    event."""
+    rt = VectorRuntime(mesh=make_mesh(1), capacity_per_shard=16)
+
+    async def round_(x: float) -> float:
+        fut = rt.call(CounterVec, 3, "add", x=x)
+        await rt.flush()
+        return float(fut.result())
+
+    try:
+        assert asyncio.run(round_(1.0)) == 1.0
+        first = rt._worker
+        assert asyncio.run(round_(2.0)) == 3.0
+        assert rt._worker is first and first.is_alive()
+    finally:
+        rt.shutdown_worker()
+
+
+@pytest.mark.parametrize("hosted", [True, False], ids=["silo", "bare"])
+async def test_lever_off_runs_the_same_job_on_the_loop(hosted):
+    """``offloop_tick=False`` (the one lever left, PERF.md section 6, PR
+    30): every claimed job runs on the event loop, in place, through the
+    same ``_run_job`` → ``_execute_batch`` → ``_complete_job``. No worker
+    starts, the answers are the plain per-key totals, a key's burst is
+    served one message a tick in send order, and ``flush()`` drains."""
+    import threading
+    silo = None
+    if hosted:
+        silo = _build(offloop_tick=False, metrics_enabled=True)
         await silo.start()
-        try:
-            rt = silo.vector
-            for k in range(12):
-                rt.call(CounterVec, k, "add", x=float(k))
-            await rt.flush()
-            assert not rt.pending and rt._inflight == 0
-            assert rt.messages_processed >= 12
-        finally:
+        rt = silo.vector
+    else:
+        rt = VectorRuntime(mesh=make_mesh(1), capacity_per_shard=64)
+        rt.offloop_tick = False
+    threads = set()
+    execute = rt._execute_batch
+
+    def spy(*a, **k):
+        threads.add(threading.current_thread())
+        return execute(*a, **k)
+
+    rt._execute_batch = spy
+    try:
+        assert rt.offloop_tick is False
+        futs = [rt.call(CounterVec, k, "add", x=float(k + 1))
+                for k in range(8)]
+        burst = [rt.call(CounterVec, 3, "add", x=1.0) for _ in range(4)]
+        await rt.flush()
+        assert [float(f.result()) for f in futs] \
+            == [float(k + 1) for k in range(8)]
+        assert [float(f.result()) for f in burst] == [5.0, 6.0, 7.0, 8.0]
+        assert rt.conflicts_deferred == 4 + 3 + 2 + 1
+        assert threads == {threading.main_thread()}
+        assert rt._worker is None and rt._inflight == 0
+        assert not rt._inflight_groups and rt._quiesced.is_set()
+        if hosted:
+            # the observations took the one route: stamped in the job's
+            # sink, replayed by _complete_job
+            h = silo.stats.histograms
+            ticks = h["ingest.tick.seconds"].total
+            assert ticks == 5 == h["engine.claim.seconds"].total \
+                == h["engine.resolve.seconds"].total
+            assert silo.stats.get("ingest.messages") == 12
+    finally:
+        del rt._execute_batch
+        if silo is not None:
             await silo.stop()
 
 
-async def test_standalone_runtime_stays_inline():
-    """A bare VectorRuntime (no silo, no DispatchOptions opt-in) keeps
-    today's synchronous loop-inline tick: no worker thread appears."""
+async def test_lever_off_a_failed_batch_fails_its_callers_only():
     rt = VectorRuntime(mesh=make_mesh(1), capacity_per_shard=16)
-    assert rt.offloop_tick is False
-    fut = rt.call(CounterVec, 5, "add", x=3.0)
+    rt.offloop_tick = False
+
+    def boom(*_a, **_k):
+        raise RuntimeError("no kernel")
+
+    rt._kernel = boom
+    fut = rt.call(CounterVec, 2, "add", x=1.0)
     await rt.flush()
-    assert float(await fut) == 3.0
+    with pytest.raises(RuntimeError, match="no kernel"):
+        fut.result()
+    assert rt._inflight == 0 and not rt._inflight_groups
+    del rt._kernel
+    assert float(await rt.call(CounterVec, 2, "add", x=2.0)) == 2.0
     assert rt._worker is None
-
-
-async def test_dispatch_options_offloop_lever():
-    from orleans_tpu.config import DispatchOptions
-    rt = VectorRuntime(mesh=make_mesh(1),
-                       options=DispatchOptions(capacity_per_shard=16,
-                                               offloop_tick=True))
-    assert rt.offloop_tick is True
-    fut = rt.call(CounterVec, 5, "add", x=3.0)
-    await rt.flush()
-    assert float(await fut) == 3.0
-    assert rt._worker is not None
-    rt.shutdown_worker()
 
 
 async def test_call_batch_debug_pool_discipline():
@@ -241,7 +356,7 @@ async def test_call_batch_debug_pool_discipline():
     call_group → off-loop tick → response correlation."""
     prev = set_debug_pool(True)
     try:
-        silo = _build(True)
+        silo = _build()
         await silo.start()
         client = await ClusterClient(silo.fabric).connect()
         try:
@@ -263,7 +378,7 @@ async def test_call_batch_debug_pool_discipline():
 async def test_call_batch_per_item_error_isolation():
     """A schema-violating item resolves ITS awaitable with the error;
     the rest of the batch proceeds."""
-    silo = _build(True)
+    silo = _build()
     await silo.start()
     client = await ClusterClient(silo.fabric).connect()
     try:
@@ -279,40 +394,11 @@ async def test_call_batch_per_item_error_isolation():
         await silo.stop()
 
 
-async def test_offloop_removes_tick_slices():
-    """With profiling on, the off-loop path leaves only ``tick_schedule``
-    on the loop: staging/transfer/sync run on the worker and never
-    appear as loop occupancy (the counterpart of
-    test_occupancy_under_concurrent_turns_and_ticks)."""
-    from orleans_tpu.config import ProfilingOptions
-
-    b = (SiloBuilder().with_name("ot-prof").add_grains(EchoGrain)
-         .with_config(offloop_tick=True)
-         .with_options(ProfilingOptions(enabled=True, window=0.05)))
-    add_vector_grains(b, CounterVec, mesh=make_mesh(1),
-                      dense={CounterVec: 32})
-    silo = b.build()
-    await silo.start()
-    client = await ClusterClient(silo.fabric).connect()
-    try:
-        refs = [client.get_grain(CounterVec, k) for k in range(16)]
-        for rnd in range(10):
-            await asyncio.gather(*(r.add(x=1.0) for r in refs))
-        prof = silo.loop_prof.profile()
-        sec = prof["seconds"]
-        assert sec.get("tick_schedule", 0.0) > 0.0
-        for cat in ("tick_staging", "tick_transfer", "tick_sync"):
-            assert sec.get(cat, 0.0) == 0.0, (cat, sec)
-    finally:
-        await client.close_async()
-        await silo.stop()
-
-
 async def test_checkpoint_capture_fenced_under_traffic():
     """Donation-safe capture while worker ticks are continuously in
     flight: the fence means the D2H copy never materializes a donated
     array (a race here raises 'Array has been deleted')."""
-    silo = _build(True)
+    silo = _build()
     await silo.start()
     client = await ClusterClient(silo.fabric).connect()
     try:
@@ -387,11 +473,8 @@ async def test_call_batch_partial_gateway_failure_isolated():
 # the bounded, completion-driven hand-off (ISSUE 29)
 # ---------------------------------------------------------------------------
 
-def _offloop_rt(offloop: bool = True) -> VectorRuntime:
-    from orleans_tpu.config import DispatchOptions
-    return VectorRuntime(mesh=make_mesh(1),
-                         options=DispatchOptions(capacity_per_shard=256,
-                                                 offloop_tick=offloop))
+def _bare_rt() -> VectorRuntime:
+    return VectorRuntime(mesh=make_mesh(1), capacity_per_shard=256)
 
 
 def _spy(rt: VectorRuntime) -> tuple[list, list]:
@@ -432,13 +515,11 @@ async def test_held_calls_coalesce(entry, n):
     """N single calls over N loop iterations while the worker is held:
     DEPTH jobs of one call reach the worker, the other N - DEPTH wait in
     ``pending`` and ride ONE job when the first completes — DEPTH + 1
-    jobs, not N — and every answer is the inline path's."""
-    inline = _offloop_rt(False)
-    want = [_enqueue(inline, entry, k, float(k + 1)) for k in range(n)]
-    await inline.flush()
-    want = [float(f.result()) for f in want]
+    jobs, not N — and every answer is the key's plain total (one
+    ``add`` of k + 1 on a fresh key k)."""
+    want = [float(k + 1) for k in range(n)]
 
-    rt = _offloop_rt()
+    rt = _bare_rt()
     try:
         await rt.call(CounterVec, 999, "add", x=0.0)  # worker up, compiled
         jobs, _ticks = _spy(rt)
@@ -467,7 +548,7 @@ async def test_completion_rearms_held_group(raises):
     does not reschedule itself and ``rt.ticks`` stands still — and the
     completion of one of its jobs claims it, also when that batch
     raised (its callers get the error, the held ones their answers)."""
-    rt = _offloop_rt()
+    rt = _bare_rt()
     try:
         await rt.call(CounterVec, 999, "add", x=0.0)
         jobs, ticks = _spy(rt)
@@ -516,7 +597,7 @@ async def test_per_key_order_across_deferral_and_hold(burst):
     conflict-deferred) while the group is held and after: replies come in
     send order with totals 1..n, and a read enqueued after an update's
     future resolved sees that update."""
-    rt = _offloop_rt()
+    rt = _bare_rt()
     try:
         await rt.call(CounterVec, 999, "add", x=0.0)
         jobs, _ticks = _spy(rt)
@@ -564,7 +645,7 @@ async def test_held_groups_drain(drain):
     only after they ran; ``shutdown_worker()`` ends the thread after the
     jobs it holds and the held calls start a fresh one; the migration
     fence (``pending_key_hashes``) covers held keys all along."""
-    rt = _offloop_rt()
+    rt = _bare_rt()
     try:
         await rt.call(CounterVec, 999, "add", x=0.0)
         first = rt._worker
@@ -603,7 +684,7 @@ async def test_busy_group_does_not_hold_another(other):
     class OtherVec(CounterVec):
         pass
 
-    rt = _offloop_rt()
+    rt = _bare_rt()
     try:
         await rt.call(CounterVec, 999, "add", x=0.0)
         jobs, _ticks = _spy(rt)
@@ -639,7 +720,7 @@ async def test_engine_held_counter(hold):
     many passes find it held, and reads 0 — not absent — where nothing
     was held."""
     b = (SiloBuilder().with_name(f"ot-held-{hold}").add_grains(EchoGrain)
-         .with_config(offloop_tick=True, metrics_enabled=True))
+         .with_config(metrics_enabled=True))
     add_vector_grains(b, CounterVec, mesh=make_mesh(1),
                       dense={CounterVec: 64})
     silo = b.build()

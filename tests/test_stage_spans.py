@@ -4,8 +4,18 @@ layer boundary of the served device path — host-clock histogram,
 
 Counts and containment only; no timing thresholds. One served silo
 (SocketFabric + GatewayClient + write-behind storage) is driven once per
-tick path (off-loop worker on and off) and the per-stage cases read its
-registry.
+traffic shape of a benchmark cell and the per-stage cases read its
+registry:
+
+* ``heartbeat`` — ``presence_heartbeat``'s shape: dense int keys, one
+  writing method, ``call_batch`` frames;
+* ``ycsb`` — ``ycsb_a_zipf``'s shape: hashed string keys, single calls,
+  a ``read_only`` method beside a writing one on the same keys, two
+  updates of a key in flight at once, so that ``engine.claim`` defers
+  and ``_tick`` holds;
+* ``heartbeat-lever-off`` — the first shape with ``offloop_tick=False``
+  (the one lever PR 30 kept): the job runs on the loop through the same
+  functions, so every stage keeps its name, unit and count.
 """
 
 import asyncio
@@ -31,12 +41,9 @@ from orleans_tpu.storage.checkpoint import _gather_rows
 
 N_KEYS = 16
 ROUNDS = 4
-# stages that exist only with the off-loop tick worker
-WORKER_ONLY = ("engine.worker_queue", "engine.fence_wait",
-               "engine.complete_hop")
-PER_TICK = ("engine.claim", "ingest.staging", "ingest.transfer",
-            "ingest.tick.dispatch", "ingest.tick.sync",
-            "engine.resolve") + WORKER_ONLY
+PER_TICK = ("engine.claim", "engine.worker_queue", "engine.fence_wait",
+            "ingest.staging", "ingest.transfer", "ingest.tick.dispatch",
+            "ingest.tick.sync", "engine.complete_hop", "engine.resolve")
 PER_FLUSH = ("flush", "flush.locate", "flush.gather", "flush.write")
 
 
@@ -52,13 +59,18 @@ class CounterVec(VectorGrain):
         total = state["total"] + args["x"]
         return {"total": total}, total
 
+    @actor_method(read_only=True)
+    def peek(state, args):
+        return state, state["total"]
 
-def _build(metrics: bool, offloop: bool, storage=None, period: float = 0.05):
-    b = (SiloBuilder().with_name(f"ss-{metrics}-{offloop}")
+
+def _build(metrics: bool, storage=None, period: float = 0.05,
+           offloop: bool = True):
+    b = (SiloBuilder().with_name(f"ss-{metrics}")
          .with_fabric(SocketFabric())
          .with_config(metrics_enabled=metrics, offloop_tick=offloop))
     add_vector_grains(b, CounterVec, mesh=make_mesh(1),
-                      dense={CounterVec: 64}, capacity_per_shard=64,
+                      dense={CounterVec: 64}, capacity_per_shard=128,
                       **({"storage": storage, "flush_period": period}
                          if storage is not None else {}))
     return b.build()
@@ -80,9 +92,45 @@ async def _settle(silo, rows: int) -> None:
     raise AssertionError("the flusher never drained")
 
 
-async def _serve(metrics: bool, offloop: bool) -> dict:
+async def _frames(client) -> None:
+    """``presence_heartbeat``'s shape: one ``call_batch`` frame a round
+    over dense int keys, one writing method."""
+    calls = [(k, {"x": np.int32(1)}) for k in range(N_KEYS)]
+    for r in range(ROUNDS):
+        out = await asyncio.gather(*client.call_batch(CounterVec, "add",
+                                                      calls))
+        assert [int(v) for v in out] == [r + 1] * N_KEYS
+
+
+async def _single_calls(client) -> None:
+    """``ycsb_a_zipf``'s shape: single calls on hashed string keys; after
+    every key's first touch, each round has two updates and a read of
+    every key in flight at once (the second update of a key cannot share
+    the first one's tick)."""
+    refs = [client.get_grain(CounterVec, f"user-{k}") for k in range(N_KEYS)]
+    one = np.int32(1)
+    out = await asyncio.gather(*(g.add(x=one) for g in refs))
+    assert [int(v) for v in out] == [1] * N_KEYS
+    for r in range(ROUNDS):
+        base = 1 + 2 * r
+        first = [g.add(x=one) for g in refs]
+        reads = [g.peek() for g in refs]
+        second = [g.add(x=one) for g in refs]
+        # replies of a key in send order; a read sees a state between
+        assert [int(v) for v in await asyncio.gather(*first)] \
+            == [base + 1] * N_KEYS
+        assert [int(v) for v in await asyncio.gather(*second)] \
+            == [base + 2] * N_KEYS
+        assert all(base <= int(v) <= base + 2
+                   for v in await asyncio.gather(*reads))
+
+
+SHAPES = {"heartbeat": _frames, "ycsb": _single_calls}
+
+
+async def _serve(metrics: bool, shape: str, offloop: bool = True) -> dict:
     """Drive one served silo; returns what the cases compare."""
-    silo = _build(metrics, offloop, MemoryStorage())
+    silo = _build(metrics, MemoryStorage(), offloop=offloop)
     await silo.start()
     loop_thread = threading.get_ident()
     observers: set = set()
@@ -102,20 +150,24 @@ async def _serve(metrics: bool, offloop: bool) -> dict:
     silo.vector._complete_job = spy_complete
     client = await GatewayClient([silo.gateway_endpoint]).connect()
     try:
-        await _rounds(client, range(N_KEYS))
+        await SHAPES[shape](client)
         await _settle(silo, N_KEYS)
     finally:
         await client.close_async()
         await silo.stop()
     return {"stats": silo.stats, "observers": observers, "sunk": sunk,
             "loop_thread": loop_thread,
-            "stage_left": stats_mod._thread.stage}
+            "stage_left": stats_mod._thread.stage,
+            "deferred": silo.vector.conflicts_deferred}
 
 
-@pytest.fixture(scope="module", params=[True, False],
-                ids=["offloop", "inline"])
+@pytest.fixture(scope="module",
+                params=[("heartbeat", True), ("ycsb", True),
+                        ("heartbeat", False)],
+                ids=["heartbeat", "ycsb", "heartbeat-lever-off"])
 def served(request):
-    return request.param, asyncio.run(_serve(True, request.param))
+    shape, offloop = request.param
+    return shape, asyncio.run(_serve(True, shape, offloop))
 
 
 def _count(stats, name: str) -> int:
@@ -125,12 +177,10 @@ def _count(stats, name: str) -> int:
 
 @pytest.mark.parametrize("stage", STAGES)
 def test_stage_observed_once_per_unit_of_work(served, stage):
-    offloop, run = served
+    _shape, run = served
     st = run["stats"]
     got = _count(st, stage + ".seconds")
-    if stage in WORKER_ONLY and not offloop:
-        assert got == 0  # no worker, no hand-off to time
-    elif stage in PER_TICK:
+    if stage in PER_TICK:
         # one claimed batch = one job = one of each tick stage
         assert got == _count(st, "ingest.tick.seconds") >= ROUNDS
     elif stage in PER_FLUSH:
@@ -147,7 +197,7 @@ def test_stage_observed_once_per_unit_of_work(served, stage):
 
 
 def test_tick_is_tiled_by_dispatch_and_sync(served):
-    _offloop, run = served
+    _shape, run = served
     h = run["stats"].histograms
     tick, disp, sync = (h["ingest.tick.seconds"],
                         h["ingest.tick.dispatch.seconds"],
@@ -157,21 +207,30 @@ def test_tick_is_tiled_by_dispatch_and_sync(served):
 
 
 def test_worker_stages_reach_the_registry_through_the_sink(served):
-    offloop, run = served
+    shape, run = served
     # nothing but the loop thread ever wrote the registry
     assert run["observers"] == {run["loop_thread"]}
     worker_side = {f"{s}.seconds" for s in (
         "engine.fence_wait", "engine.worker_queue", "ingest.staging",
         "ingest.transfer", "ingest.tick.dispatch", "ingest.tick.sync")}
-    if offloop:
-        assert worker_side <= run["sunk"]
-    else:
-        assert not run["sunk"]  # inline: no job, no sink
+    assert worker_side <= run["sunk"]
     assert run["stage_left"] is None
+    st = run["stats"]
+    # both counters exist at 0 too; the second update of a key in flight
+    # with the first either met it in one claim (deferred) or found its
+    # group still with the worker (held)
+    assert st.counters["engine.deferred"] >= 0 <= st.counters["engine.held"]
+    if shape == "ycsb":
+        assert run["deferred"] + st.get("engine.held") >= 1
+        assert st.get("ingest.messages.CounterVec.peek") == N_KEYS * ROUNDS
+        assert st.get("ingest.messages.CounterVec.add") \
+            == N_KEYS * (1 + 2 * ROUNDS)
+    else:
+        assert st.get("ingest.messages") == N_KEYS * ROUNDS
 
 
 def test_flush_rows_sum_to_flushed(served):
-    _offloop, run = served
+    _shape, run = served
     st = run["stats"]
     rows = st.histograms[FLUSH_STATS["rows"]]
     assert rows.sum == st.get(FLUSH_STATS["flushed"]) >= N_KEYS
@@ -180,7 +239,7 @@ def test_flush_rows_sum_to_flushed(served):
 
 
 async def test_metrics_off_registers_none_of_the_new_names():
-    silo = _build(False, True, MemoryStorage())
+    silo = _build(False, MemoryStorage())
     await silo.start()
     client = await GatewayClient([silo.gateway_endpoint]).connect()
     try:
@@ -200,7 +259,7 @@ async def test_metrics_off_registers_none_of_the_new_names():
 async def test_compiles_are_booked_to_the_stage_that_compiled():
     """A gather in a size bucket the process has not seen compiles under
     flush.gather; a tick at a warm bucket books nothing."""
-    silo = _build(True, True, MemoryStorage(), period=3600.0)
+    silo = _build(True, MemoryStorage(), period=3600.0)
     await silo.start()
     client = await GatewayClient([silo.gateway_endpoint]).connect()
     bridge = silo.vector_bridges[CounterVec]
@@ -234,7 +293,7 @@ async def test_compiles_are_booked_to_the_stage_that_compiled():
 
 async def test_profiler_capture_carries_dispatch_events_with_their_tick(
         tmp_path):
-    silo = _build(True, True)
+    silo = _build(True)
     await silo.start()
     client = await GatewayClient([silo.gateway_endpoint]).connect()
     try:
@@ -263,24 +322,34 @@ async def test_profiler_capture_carries_dispatch_events_with_their_tick(
 
 async def test_failed_batch_closes_its_stages():
     """A batch that raises mid-stage records the failed step and leaves
-    the thread with no current stage (inline: this thread)."""
-    silo = _build(True, False)
+    the worker thread with no current stage."""
+    silo = _build(True)
     await silo.start()
     rt = silo.vector
+    left = []
+    run_job = rt._run_job
     try:
         def boom(*_a, **_k):
             raise RuntimeError("no kernel")
 
+        def spy_run_job(job):
+            run_job(job)
+            left.append((threading.current_thread().name,
+                         stats_mod._thread.stage))
+
         rt._kernel = boom
+        rt._run_job = spy_run_job
         fut = rt.call(CounterVec, 3, "add", x=np.int32(1))
         await rt.flush()
         with pytest.raises(RuntimeError, match="no kernel"):
             await fut
+        rt.shutdown_worker()  # joins: the spy has run to its end
+        assert left == [("orleans-tick-worker", None)]
         assert stats_mod._thread.stage is None
         assert silo.stats.histograms["ingest.transfer.seconds"].total == 1
         assert "ingest.tick.dispatch.seconds" not in silo.stats.histograms
     finally:
-        del rt._kernel
+        del rt._kernel, rt._run_job
         await silo.stop()
 
 
@@ -290,7 +359,7 @@ async def test_compile_listener_is_installed_once(monkeypatch):
                         "register_event_duration_secs_listener",
                         calls.append)
     monkeypatch.setattr(stats_mod, "_listening", False)
-    silos = [_build(True, True), _build(True, False), _build(False, True)]
+    silos = [_build(True), _build(True), _build(False)]
     for s in silos:
         await s.start()
     try:

@@ -451,37 +451,45 @@ async def test_an_unclean_warm_up_ends_the_run():
 # through VectorRuntime
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("offloop", [False, True], ids=["inline", "offloop"])
-async def test_hot_key_burst_in_one_tick(offloop):
+def _key_hash(rt, key) -> int:
+    a = rt.actor(Record, key)
+    return a.key_hash if hasattr(a, "key_hash") else rt.key_hash_for(
+        key, GrainId.for_grain(GrainType.of("RecordVectorGrain"),
+                               key).uniform_hash)
+
+
+@pytest.mark.parametrize("key", [11, "user-hot"], ids=["dense", "hashed"])
+async def test_hot_key_burst_in_one_tick(key):
     """k updates and k reads of one key enqueued together: one of each a
     tick, the rest deferred; ver 1..k; every read is the state at the ver
     it reports."""
     k = 6
     rt = _runtime()
-    rt.offloop_tick = offloop
+    kh = _key_hash(rt, key)
+    rk = kh & 0x7FFFFFFF                      # what initial_state is given
     rng = np.random.default_rng(3)
     ref = ref_mod.Reference(SEED)
     vals = [(int(rng.integers(10)), _value(rng)) for _ in range(k)]
     ups, reads = [], []
     for field, value in vals:
-        ref.sending_update(11)
-        ups.append(rt.call(Record, 11, "update", field=field, value=value))
-        reads.append(rt.call(Record, 11, "read"))
+        ref.sending_update(rk)
+        ups.append(rt.call(Record, kh, "update", field=field, value=value))
+        reads.append(rt.call(Record, kh, "read"))
     await rt.flush()
     vers = [int(await f) for f in ups]
     assert vers == list(range(1, k + 1))      # FIFO within the method
     for (field, value), ver in zip(vals, vers):
-        assert ref.update(11, field, value, ver) == 0
+        assert ref.update(rk, field, value, ver) == 0
     seen = []
     for f in reads:
         ver, data = _reply_bytes(await f)
-        assert ref.read(11, ver, data) == 0
+        assert ref.read(rk, ver, data) == 0
         seen.append(ver)
     assert seen == sorted(seen)
     # each method defers k-1, then k-2, ... of its own
     assert rt.conflicts_deferred == k * (k - 1)
     _keys, states = ref.states()
-    row = rt.table(Record).read_row(11)
+    row = rt.table(Record).read_row(kh)
     assert np.array_equal(row["fields"], states["fields"][0])
     assert int(row["ver"]) == k
     rt.shutdown_worker()
@@ -522,10 +530,7 @@ async def test_a_read_only_first_touch_does_not_activate(order, key):
     """A read-only method writes nothing back, so the row is initialised
     by the first WRITE that is claimed, whatever was read before it."""
     rt = _runtime()
-    a = rt.actor(Record, key)
-    kh = a.key_hash if hasattr(a, "key_hash") else rt.key_hash_for(
-        key, GrainId.for_grain(GrainType.of("RecordVectorGrain"),
-                               key).uniform_hash)
+    kh = _key_hash(rt, key)
     init = jax.vmap(Record.initial_state)(
         jnp.asarray([kh & 0x7FFFFFFF], jnp.int32))
     s0 = np.asarray(init["fields"])[0, :1000].tobytes()
