@@ -32,7 +32,7 @@ import time
 import warnings
 import weakref
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +50,12 @@ from .vector_grain import ActorMethod, VectorGrain
 _QUEUE_WAIT = _INGEST["queue_wait"]
 _TICK = _INGEST["tick"]
 _MESSAGES = _INGEST["messages"]
+_TRANSFER_JOBS = _INGEST["transfer_jobs"]
+_TRANSFER_PUTS = _INGEST["transfer_puts"]
+_TRANSFER_BYTES = _INGEST["transfer_bytes"]
+# the sink's keys that replay as counter increments, not observations
+_COUNTERS = frozenset(
+    (_MESSAGES, _TRANSFER_JOBS, _TRANSFER_PUTS, _TRANSFER_BYTES))
 _WORKER_QUEUE = "engine.worker_queue.seconds"
 _DEFERRED = "engine.deferred"               # counter: msgs deferred >= once
 _DEFER_WAIT = "engine.defer_wait.seconds"   # first deferral -> claimed
@@ -184,25 +190,85 @@ class _DensePlan:
         return jax.tree_util.tree_map(one, results)
 
 
-class _StagingSet:
-    """One preallocated ``[n_shards, B, ...]`` host staging buffer set for
-    a (class, method) batch bucket: the batch operands (slots/key-hashes/
-    fresh/valid) plus one array per schema field. Two sets per bucket
-    alternate between "filling from ingress" and "donated to the tick
-    kernel" (see ``VectorRuntime._staging_acquire``), so steady-state
-    ingest never allocates — and never touches a buffer whose device
-    upload could still be in flight."""
+class _PackedLayout(NamedTuple):
+    """Where each operand of a tick lies in one shard's row of the
+    packed staging buffer. The row is ``words`` int32 words, one block
+    of ``B`` lanes per operand, blocks back to back: slots, key hashes,
+    fresh, valid, then every argument field of the method's schema by
+    name (``names``). ``fields`` holds one ``(dtype, shape, offset,
+    count)`` a block, offset and count in words: ``B`` is a multiple of
+    8, so every block is whole words and starts on an 8-byte boundary.
+    A dtype is the one the device sees: with x64 off an 8-byte schema
+    dtype is staged as its 4-byte twin, so the fill loop's assignment
+    does the narrowing ``jnp.asarray`` used to do. Hashable: it is part
+    of the packed kernel's cache key."""
 
-    __slots__ = ("slots", "khash", "fresh", "valid", "args", "used", "sink",
-                 "scalars", "arrays")
+    B: int
+    words: int
+    names: tuple
+    fields: tuple
+
+
+def _packed_layout(B: int, schema: dict) -> _PackedLayout:
+    assert B % 8 == 0, B  # _bucket's floor: blocks are whole words
+    args = sorted(schema.items())
+    cols = [(np.int32, ()), (np.int32, ()), (np.bool_, ()), (np.bool_, ())]
+    fields, off = [], 0
+    for dtype, shape in cols + [v for _f, v in args]:
+        dt = np.dtype(jax.dtypes.canonicalize_dtype(dtype))
+        shape = tuple(int(d) for d in shape)
+        count = B * dt.itemsize * int(np.prod(shape, dtype=np.int64)) // 4
+        fields.append((dt, shape, off, count))
+        off += count
+    return _PackedLayout(B, off, tuple(f for f, _v in args), tuple(fields))
+
+
+def _unpack_words(words, dt: np.dtype, shape: tuple):
+    """``[..., k]`` int32 words → ``[..., *shape]`` of ``dt``, bits
+    reinterpreted and never converted (a bool is its byte, 0 or 1)."""
+    if dt == np.bool_:
+        return _unpack_words(words, np.dtype(np.uint8), shape) != 0
+    lead = words.shape[:-1]
+    if dt.kind == "c":
+        parts = _unpack_words(words, np.dtype(f"f{dt.itemsize // 2}"),
+                              (*shape, 2))
+        return jax.lax.complex(parts[..., 0], parts[..., 1])
+    if dt.itemsize > 4:
+        words = words.reshape(*lead, -1, dt.itemsize // 4)
+    return jax.lax.bitcast_convert_type(words, dt).reshape(*lead, *shape)
+
+
+class _StagingSet:
+    """One preallocated host staging buffer for a (class, method) batch
+    bucket: ``packed``, ``[n_shards, words]`` int32, which crosses to the
+    device in ONE transfer a job (``_PackedLayout`` has the row's plan;
+    the packed kernel unpacks it). The batch operands ``slots`` /
+    ``khash`` / ``fresh`` / ``valid`` and one ``args[f]`` per schema
+    field are numpy VIEWS into it, ``[n_shards, B, ...]`` each, so
+    filling, ``reset`` and the hit / cost folds read and write the
+    operands by name. Two sets per bucket alternate between "filling
+    from ingress" and "donated to the tick kernel" (see
+    ``VectorRuntime._staging_acquire``), so steady-state ingest never
+    allocates — and never touches a buffer whose device upload could
+    still be in flight."""
+
+    __slots__ = ("packed", "layout", "slots", "khash", "fresh", "valid",
+                 "args", "used", "sink", "scalars", "arrays")
 
     def __init__(self, n: int, B: int, sink: int, schema: dict):
-        self.slots = np.full((n, B), sink, dtype=np.int32)
-        self.khash = np.zeros((n, B), dtype=np.int32)
-        self.fresh = np.zeros((n, B), dtype=bool)
-        self.valid = np.zeros((n, B), dtype=bool)
-        self.args = {f: np.zeros((n, B, *shape), dtype=dtype)
-                     for f, (dtype, shape) in schema.items()}
+        lay = self.layout = _packed_layout(B, schema)
+        self.packed = np.zeros((n, lay.words), dtype=np.int32)
+        views = []
+        for dt, shape, off, _count in lay.fields:
+            strides = [dt.itemsize]
+            for d in reversed(shape):
+                strides.append(strides[-1] * d)
+            views.append(np.ndarray(
+                (n, B, *shape), dt, self.packed, off * 4,
+                (lay.words * 4, *reversed(strides))))
+        self.slots, self.khash, self.fresh, self.valid = views[:4]
+        self.slots[:] = sink
+        self.args = dict(zip(lay.names, views[4:]))
         # the fill loop's two kinds of field: scalars are assigned as
         # they come; an array field may arrive as ``bytes`` (the wire's
         # native tag for a byte string), which numpy cannot assign to a
@@ -385,7 +451,7 @@ class VectorRuntime:
         # ingest stage metrics (observability.stats.INGEST_STATS), set by
         # dispatch.hosting when the owning silo has metrics enabled: each
         # message batch splits into staging (pending -> host arrays),
-        # transfer (host -> device operands), and tick (kernel dispatch +
+        # transfer (host -> device, one packed buffer), and tick (dispatch +
         # device execution + host materialize) histograms — the device
         # half of the socket->tick ingest attribution — each a StageSpan,
         # so the same intervals lie on a jax.profiler capture
@@ -997,7 +1063,7 @@ class VectorRuntime:
                             self.ledger.charge_tick(val)
                     elif st is None:
                         continue
-                    elif key is _MESSAGES:
+                    elif key in _COUNTERS:
                         st.increment(key, val)
                     else:
                         st.observe(key, val)
@@ -1194,9 +1260,10 @@ class VectorRuntime:
 
     def _execute_batch(self, cls: type, method: str, ready: list[_Pending],
                        sink: list, trace_roll: bool = False, tick: int = 0):
-        """Staging fill → operand upload → kernel dispatch → host
-        materialize sync for one claimed, conflict-free batch, on the
-        tick worker. ``sink`` is the job's deferred-stats list: every
+        """Staging fill → operand upload (one buffer, one transfer) →
+        kernel dispatch → host materialize sync for one claimed,
+        conflict-free batch, on the tick worker (or in place on the
+        loop). ``sink`` is the job's deferred-stats list: every
         observation is STAMPED here and recorded loop-side in
         _complete_job, because StatsRegistry/Histogram/QueueWaitTrend/
         the ledger are loop-confined.
@@ -1245,7 +1312,6 @@ class VectorRuntime:
         stg = self._staging_acquire(cls, method, tbl, B, schema)
         slots, khash = stg.slots, stg.khash
         fresh, valid = stg.fresh, stg.valid
-        args_stacked = stg.args
         scalars, arrays = stg.scalars, stg.arrays
         for s, ps in enumerate(per_shard):
             stg.used[s] = len(ps)
@@ -1293,17 +1359,18 @@ class VectorRuntime:
                 sink.append((None, max(0.0, sum(stamped) / len(stamped))))
         span_name = span_start = t_span0 = None
         try:
-            # operand buffers are donated: these device arrays are fresh
-            # per tick (never the cached _DensePlan operands), so XLA may
-            # reuse them as the kernel's output/scratch — the device_put
-            # below becomes a donation hand-off, not a second copy
-            kernel = self._kernel(cls, method, B, donate_operands=True)
-            kernel_args = (
-                tbl.state, jnp.asarray(slots), jnp.asarray(khash),
-                jnp.asarray(fresh), jnp.asarray(valid),
-                {k: jnp.asarray(v) for k, v in args_stacked.items()})
+            # ONE host→device transfer a job: the staging set's packed
+            # buffer, which the kernel unpacks by the set's layout. The
+            # device array is fresh per tick (never a cached _DensePlan
+            # operand) and is donated with the state, so XLA may reuse
+            # it as the kernel's scratch
+            kernel = self._kernel(cls, method, B, layout=stg.layout)
+            kernel_args = (tbl.state, jnp.asarray(stg.packed))
             if st is not None:
                 stage.close()
+                sink.append((_TRANSFER_JOBS, 1))
+                sink.append((_TRANSFER_PUTS, 1))
+                sink.append((_TRANSFER_BYTES, stg.packed.nbytes))
                 # the stage span bridges host tracing to the XLA
                 # timeline: on a jax.profiler capture this tick's kernel
                 # launch nests under otpu:ingest.tick.dispatch
@@ -1359,9 +1426,9 @@ class VectorRuntime:
             # batches FIFO, so by the time a staging set rotates back its
             # tick has provably synced here. (A read-only kernel returns
             # no state: its operands are not donated, so they are what
-            # there is to wait for.)
+            # there is to wait for: the packed buffer.)
             jax.block_until_ready(
-                kernel_args[1:] if m.read_only else new_state)
+                kernel_args[1] if m.read_only else new_state)
         if st is not None:
             # tick closes AFTER the host transfer for the same reason the
             # span timing does: jax dispatch is async, and the np.asarray
@@ -2362,25 +2429,25 @@ class VectorRuntime:
     # Kernel construction
     # ------------------------------------------------------------------
     def _kernel(self, cls: type, method: str, B: int,
-                contiguous: bool = False, donate_operands: bool = False):
+                contiguous: bool = False,
+                layout: _PackedLayout | None = None):
         tbl = self.tables[cls]
         key = (cls, method, B, tbl.capacity, tbl.n_shards, contiguous,
-               donate_operands)
+               layout)
         k = self._kernel_cache.get(key)
         if k is None:
             k = self._build_kernel(cls, method, contiguous=contiguous,
-                                   donate_operands=donate_operands)
+                                   layout=layout)
             self._kernel_cache[key] = k
-            if donate_operands:
-                # first invocation compiles, and compiling an operand-
-                # donating kernel emits a known-benign UserWarning for
-                # buffers XLA cannot alias (the bool masks always;
-                # slots/khash when no same-shape output remains —
-                # donation stays correct, they just aren't aliased).
-                # Suppress it for THAT call only: the cache holds the
-                # raw kernel, so steady-state ticks never touch the
-                # process warnings filter, and application JAX code
-                # keeps the diagnostic for its own kernels.
+            if layout is not None:
+                # first invocation compiles, and compiling a kernel that
+                # donates its packed operand emits a known-benign
+                # UserWarning: no output has the buffer's shape, so XLA
+                # cannot alias it (donation stays correct). Suppress it
+                # for THAT call only: the cache holds the raw kernel, so
+                # steady-state ticks never touch the process warnings
+                # filter, and application JAX code keeps the diagnostic
+                # for its own kernels.
                 raw = k
 
                 def k(*a, _raw=raw):
@@ -2394,7 +2461,11 @@ class VectorRuntime:
     def _build_kernel(self, cls: type, method: str, scan_rounds: int = 0,
                       contiguous: bool = False,
                       scan_all_valid: bool = False,
-                      donate_operands: bool = False):
+                      layout: _PackedLayout | None = None):
+        """The jitted tick of one (class, method): ``(state, slots,
+        khash, fresh, valid, args)``, or with ``layout`` the served
+        tick's ``(state, packed)`` — the same step behind a prologue
+        that slices each operand out of the packed buffer's words."""
         tbl = self.tables[cls]
         m = tbl.methods[method]
         handler = m.fn
@@ -2511,6 +2582,15 @@ class VectorRuntime:
                 return (() if read_only else out_state), results
 
             body = scanned
+        elif layout is not None:
+            def body(state, packed):
+                # block shape [1, words]: every operand is a static
+                # slice of the row, reinterpreted to its dtype
+                cols = [_unpack_words(packed[:, off:off + count], dt,
+                                      (layout.B, *shape))
+                        for dt, shape, off, count in layout.fields]
+                return local_step(state, *cols[:4],
+                                  dict(zip(layout.names, cols[4:])))
         else:
             body = local_step
 
@@ -2519,21 +2599,20 @@ class VectorRuntime:
             pspec = P(None, SILO_AXIS) if scan_rounds else spec
             body = jax.shard_map(
                 body, mesh=mesh,
-                in_specs=(spec, spec, spec, spec, spec, pspec),
+                in_specs=(spec, spec) if layout is not None else
+                (spec, spec, spec, spec, spec, pspec),
                 out_specs=(spec, P(None, SILO_AXIS) if scan_rounds else spec),
                 check_vma=False)
         # else: single-shard — shard_map would be a no-op; plain jit over
         # the table's committed arrays runs on the mesh's one device
         if read_only:
             donate: tuple = ()
-        elif donate_operands:
-            # per-tick operand buffers (slots/khash/fresh/valid/args) are
-            # fresh arrays the caller never reuses — donate them alongside
-            # the state so the staging hand-off is zero-copy where XLA can
-            # alias and scratch-reuse elsewhere. NEVER set for kernels fed
-            # by cached _DensePlan.device_operands (those persist across
-            # ticks by design).
-            donate = (0, 1, 2, 3, 4, 5)
+        elif layout is not None:
+            # the packed operand is a fresh array a tick that the caller
+            # never reuses: donated alongside the state. (Kernels fed by
+            # cached _DensePlan.device_operands never donate theirs:
+            # those persist across ticks by design.)
+            donate = (0, 1)
         else:
             donate = (0,)
         return jax.jit(body, donate_argnums=donate)

@@ -95,6 +95,9 @@ INGEST_STATS = {
     "turns": "ingest.turns",                     # counter: host turns timed
     "staging": "ingest.staging.seconds",
     "transfer": "ingest.transfer.seconds",
+    "transfer_jobs": "ingest.transfer.jobs",     # counter: jobs that uploaded
+    "transfer_puts": "ingest.transfer.puts",     # counter: their H2D transfers
+    "transfer_bytes": "ingest.transfer.bytes",   # counter: bytes they uploaded
     "tick": "ingest.tick.seconds",
     "messages": "ingest.messages",               # counter: device msgs ticked
 }

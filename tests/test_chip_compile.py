@@ -138,15 +138,47 @@ def presence_1m():
     return _presence_runtime(make_mesh(1), N_PLAYERS)
 
 
+def _served_tick(rt, cls, method, B, state, sharding):
+    """The per-tick kernel as the served path launches it, compiled for
+    the described chip: ``(state, packed)``, one operand buffer that the
+    kernel unpacks by the staging set's layout, both donated."""
+    from orleans_tpu.dispatch.engine import _packed_layout
+
+    layout = _packed_layout(B, rt.method_of(cls, method).args_schema)
+    kern = rt._build_kernel(cls, method, layout=layout)
+    compiled = kern.lower(
+        state, _struct((1, layout.words), jnp.int32, sharding)).compile()
+    # exactly two parameters: the table and the one staged buffer (to
+    # the chip: one array a state leaf, and one more)
+    (args, kwargs) = compiled.in_avals
+    assert not kwargs and len(args) == 2
+    assert len(jax.tree_util.tree_leaves(args[1])) == 1
+    entry = compiled.as_text().split("ENTRY", 1)[1]
+    assert entry.count(" parameter(") == len(state) + 1
+    return compiled
+
+
+def _table_sized_copies(compiled, table_shape) -> list[str]:
+    """The compiled program's ``copy`` instructions that produce an array
+    of the table leaf's shape."""
+    dims = ",".join(str(d) for d in table_shape)
+    return [line.strip() for line in compiled.as_text().splitlines()
+            if " copy(" in line and f"[{dims}]" in line.split(" copy(")[0]]
+
+
 def test_served_tick_kernel_compiles_for_v5e(one_chip, presence_1m):
-    """The per-tick kernel as the served path launches it: operands
-    donated, a 1024-lane batch gathered from the 1M-row table."""
+    """A 1024-lane batch gathered from the 1M-row table and scattered
+    back in place."""
     rt, tbl, Player = presence_1m
-    kern = rt._build_kernel(Player, "heartbeat", donate_operands=True)
-    compiled = kern.lower(*_kernel_operands(tbl, 1024, one_chip)).compile()
+    state = {k: _struct(v.shape, v.dtype, one_chip)
+             for k, v in tbl.state.items()}
+    compiled = _served_tick(rt, Player, "heartbeat", 1024, state, one_chip)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 0          # the state really aliases
     assert mem.argument_size_in_bytes < 1 << 30
+    assert mem.temp_size_in_bytes < 1 << 20     # no second table
+    for leaf in tbl.state.values():
+        assert not _table_sized_copies(compiled, leaf.shape)
 
 
 def test_bulk_tick_kernel_compiles_for_v5e(one_chip, presence_1m):
@@ -192,19 +224,11 @@ def test_ycsb_tick_kernels_touch_rows_not_the_table(one_chip, method, B):
     rows = (1 << 20) + 1
     state = {"fields": _struct((1, rows, 1024), jnp.uint8, one_chip),
              "ver": _struct((1, rows), jnp.int32, one_chip)}
-    lane = (1, B)
-    args = {"field": _struct(lane, jnp.int32, one_chip),
-            "value": _struct((*lane, 100), jnp.uint8, one_chip)} \
-        if method == "update" else {}
-    kern = rt._build_kernel(Record, method, donate_operands=True)
-    compiled = kern.lower(
-        state, _struct(lane, jnp.int32, one_chip),
-        _struct(lane, jnp.int32, one_chip),
-        _struct(lane, jnp.bool_, one_chip),
-        _struct(lane, jnp.bool_, one_chip), args).compile()
+    compiled = _served_tick(rt, Record, method, B, state, one_chip)
     mem = compiled.memory_analysis()
     table = rows * 1028
     assert mem.temp_size_in_bytes < 64 << 20
+    assert not _table_sized_copies(compiled, (1, rows, 1024))
     if method == "update":
         assert mem.alias_size_in_bytes >= table     # in place
     else:

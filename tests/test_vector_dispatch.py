@@ -4,6 +4,7 @@ batched no-op invokes) plus turn-semantics guarantees under batching."""
 
 import asyncio
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -303,3 +304,174 @@ async def test_bad_first_call_does_not_poison_inferred_schema():
     assert m.args_schema is None, f"schema poisoned: {m.args_schema}"
     assert int(await rt.call(InferVec, 1, "bump", x=np.int32(5))) == 5
     assert m.args_schema["x"][0] == np.dtype(np.int32)
+
+
+# ----------------------------------------------------------------------
+# The packed tick: one staged buffer a job, unpacked by the kernel
+# ----------------------------------------------------------------------
+_YCSB_UPDATE = {"field": (jnp.int32, ()), "value": (jnp.uint8, (100,))}
+# case → (schema, how a value of an array field is handed in)
+_PACKED_CASES = {
+    "presence_f16x2_i32": ({"pos": (jnp.float16, (2,)),
+                            "delta": (jnp.int32, ())}, "ndarray"),
+    "ycsb_update_bytes": (_YCSB_UPDATE, "bytes"),
+    "ycsb_update_ndarray": (_YCSB_UPDATE, "ndarray"),
+    "no_arguments_read": ({}, "ndarray"),
+    "lone_bool": ({"b": (jnp.bool_, ())}, "ndarray"),
+    "lone_f32": ({"x": (jnp.float32, ())}, "ndarray"),
+    "odd_u8x3": ({"v": (jnp.uint8, (3,))}, "ndarray"),
+    # an 8-byte host dtype is staged as the 4-byte twin the device sees
+    "i64_narrows_to_i32": ({"q": (np.int64, ())}, "ndarray"),
+    "c64_f32_pairs": ({"z": (jnp.complex64, (2,))}, "ndarray"),
+}
+
+
+def _packed_grain(schema: dict) -> type:
+    """A grain whose ``put`` stores every argument in its row and answers
+    with it, so the arguments' bits come back twice: in the reply and in
+    the table. ``read`` is the read-only, argument-less tick."""
+    held = {f"s_{f}": (jax.dtypes.canonicalize_dtype(dt), shape)
+            for f, (dt, shape) in schema.items()}
+
+    class PackedCase(VectorGrain):
+        STATE = {"n": (jnp.int32, ()), "h": (jnp.int32, ()), **held}
+
+        @staticmethod
+        def initial_state(key_hash):
+            row = {k: jnp.zeros(shape, dt) for k, (dt, shape) in held.items()}
+            return {"n": jnp.int32(0), "h": key_hash * 3 + 1, **row}
+
+        @actor_method(args=schema)
+        def put(state, args):
+            new = {"n": state["n"] + 1, "h": state["h"],
+                   **{f"s_{f}": args[f] for f in schema}}
+            return new, (new["n"], state["h"], dict(args))
+
+        @actor_method(args={}, read_only=True)
+        def read(state, args):
+            return state, (state["n"], state["h"])
+
+    return PackedCase
+
+
+def _random_args(rng, schema: dict, form: str) -> dict:
+    """One call's arguments with every bit random (NaNs, denormals and
+    negative zeros among the floats): nothing may be rounded on its way."""
+    out = {}
+    for f, (dtype, shape) in schema.items():
+        dt = np.dtype(dtype)
+        if dt == np.bool_:
+            out[f] = np.bool_(rng.integers(2))
+            continue
+        if dt == np.int64:
+            # with x64 off the device holds an int32: the value has to
+            # fit it, as numpy's assignment into the staged view insists
+            out[f] = np.int64(rng.integers(-2**31, 2**31))
+            continue
+        raw = rng.integers(0, 256, int(np.prod(shape, dtype=int)) *
+                           dt.itemsize, dtype=np.uint8).tobytes()
+        if shape and form == "bytes":
+            out[f] = raw
+        else:
+            v = np.frombuffer(raw, dt).reshape(shape)
+            out[f] = v.copy() if shape else v[()]
+    return out
+
+
+def _bits(tree) -> list:
+    return [(np.asarray(a).dtype, np.asarray(a).shape,
+             np.asarray(a).tobytes())
+            for a in jax.tree_util.tree_leaves(tree)]
+
+
+@pytest.mark.parametrize("shards", [1, 8])
+@pytest.mark.parametrize("B", [8, 1024])
+@pytest.mark.parametrize("case", list(_PACKED_CASES))
+async def test_packed_job_is_bit_identical_to_six_operands(case, B, shards):
+    """A served job (one packed buffer, one transfer, the packed kernel)
+    answers and leaves the table exactly as the six-operand kernel does
+    when it is fed the same staging views."""
+    schema, form = _PACKED_CASES[case]
+    G = _packed_grain(schema)
+    method = "put" if schema else "read"
+    rng = np.random.default_rng([list(_PACKED_CASES).index(case), B, shards])
+    per = 2048
+    rt = VectorRuntime(mesh=make_mesh(shards), capacity_per_shard=per)
+    tbl = rt.table(G)
+    tbl.ensure_dense(per * shards)
+    acquired = []
+    acquire = rt._staging_acquire
+    rt._staging_acquire = lambda *a: acquired.append(acquire(*a)) \
+        or acquired[-1]
+    # the last shard carries the bucket, the others three lanes each
+    wide = 5 if B == 8 else B // 2 + 1
+    keys = [s * per + 3 * i for s in range(shards)
+            for i in range(wide if s == shards - 1 else 3)]
+    rng.shuffle(keys)
+    # a first job writes every third key, so the compared job carries
+    # fresh and initialised lanes side by side
+    await asyncio.gather(*rt.call_group(
+        G, "put", [(k, _random_args(rng, schema, form), True)
+                   for k in keys[::3]]))
+    with rt.tick_fence():
+        before = jax.tree_util.tree_map(jnp.copy, tbl.state)
+    calls = [(k, _random_args(rng, schema, form), True) for k in keys]
+    replies = await asyncio.gather(*rt.call_group(G, method, calls))
+    stg = acquired[-1]
+    assert stg.layout.B == B and sum(stg.used) == len(keys)
+    assert all(np.shares_memory(v, stg.packed) for v in
+               (stg.slots, stg.khash, stg.fresh, stg.valid,
+                *stg.args.values()))
+    assert stg.fresh.any() and not stg.fresh[stg.valid].all()
+    ref_state, ref = rt._build_kernel(G, method)(
+        before, jnp.asarray(stg.slots), jnp.asarray(stg.khash),
+        jnp.asarray(stg.fresh), jnp.asarray(stg.valid),
+        {f: jnp.asarray(v) for f, v in stg.args.items()})
+    with rt.tick_fence():
+        assert _bits(tbl.state) == _bits(ref_state or before)
+    ref = jax.tree_util.tree_map(np.asarray, ref)
+    lane = [0] * shards
+    for (k, _a, _w), reply in zip(calls, replies):
+        s = k // per
+        assert _bits(reply) == _bits(jax.tree_util.tree_map(
+            lambda a: a[s, lane[s]], ref)), (k, s, lane[s])
+        lane[s] += 1
+    rt.shutdown_worker()
+
+
+async def test_packed_job_counts_one_transfer():
+    """Metrics on: a job books itself, its one host→device transfer and
+    the staged buffer's bytes. Metrics off: its sink stays empty."""
+    from orleans_tpu.observability.stats import StatsRegistry
+
+    jobs = []
+
+    def served(rt):
+        done = rt._complete_job
+        rt._complete_job = lambda job, *a: (jobs.append(job), done(job, *a))
+        return rt
+
+    rt = served(VectorRuntime(mesh=make_mesh(1)))
+    rt.stats = reg = StatsRegistry()
+    for wave in (range(5), range(40), range(3)):
+        await asyncio.gather(*(rt.call(CounterActor, k, "add", n=np.int32(1))
+                               for k in wave))
+    assert await rt.call(CounterActor, 1, "get") == 3
+    assert len(jobs) == 4
+    assert reg.get("ingest.transfer.jobs") == 4
+    assert reg.get("ingest.transfer.puts") == 4
+    sets = [st for pool in rt._staging.values()
+            for sets, _i in pool.values() for st in sets]
+    by_bucket = {(bool(st.args), st.layout.B): st.packed.nbytes
+                 for st in sets}
+    # add at buckets 8, 64, 8 (10 bytes of header + 4 a lane), get at 8
+    assert by_bucket == {(True, 8): 112, (True, 64): 896, (False, 8): 80}
+    assert reg.get("ingest.transfer.bytes") == 112 + 896 + 112 + 80
+    rt.shutdown_worker()
+
+    jobs.clear()
+    quiet = served(VectorRuntime(mesh=make_mesh(1)))
+    await asyncio.gather(*(quiet.call(CounterActor, k, "add", n=np.int32(1))
+                           for k in range(5)))
+    assert len(jobs) == 1 and jobs[0].stats == []
+    quiet.shutdown_worker()
