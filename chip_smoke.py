@@ -34,7 +34,11 @@ tiny-size CPU rehearsal; the last line then names the CPU).
 ``shard_map``, every player→game message through ``VectorRuntime.route``
 into a sharded ``GameGrain`` fan-in, sparse hashed keys over the exchange
 with the dedup/defer loop, and ``reshard_dense`` 4→3→4 — compared row for
-row with the same traffic on a one-device mesh in the same process.
+row with the same traffic on a one-device mesh in the same process. Then
+the **served** phase again, its silo's table sharded over the four
+devices (``make_mesh()`` takes every local device): the same callers,
+client ``call_batch``, ``whereis``, host-grain call, storage read-back
+and clean stop, against the same reference.
 
 ``--worker-procs N`` runs ONLY the served phase with
 ``SiloConfig(worker_procs=N)`` under a hard time limit (the question of
@@ -566,6 +570,8 @@ def phase_engine(N: int, rng) -> dict:
 # ---------------------------------------------------------------------------
 
 async def _served(N: int, rng, worker_procs: int) -> dict:
+    import jax
+
     from orleans_tpu.core.ids import GrainId, GrainType
     from orleans_tpu.dispatch import add_vector_grains
     from orleans_tpu.membership import FileMembershipTable, join_cluster
@@ -580,14 +586,24 @@ async def _served(N: int, rng, worker_procs: int) -> dict:
          .add_grains(GameGrain))
     if worker_procs > 1:
         b = b.with_config(worker_procs=worker_procs)
-    add_vector_grains(b, Player, dense={Player: N}, capacity_per_shard=N,
+    # no mesh is passed: the runtime's own make_mesh() takes every local
+    # device, one shard a chip, as a deployment's silo does
+    n_dev = jax.device_count()
+    add_vector_grains(b, Player, dense={Player: N},
+                      capacity_per_shard=-(-N // n_dev),
                       storage=storage, flush_period=0.25)
     silo = b.build()
     join_cluster(silo, FileMembershipTable(os.path.join(tmp, "mbr.json")))
     await silo.start()
     rt = silo.vector
+    tbl = rt.table(Player)
+    on = {d for leaf in tbl.state.values() for d in leaf.devices()}
+    check(tbl.n_shards == len(on) == n_dev,
+          f"the served table has {tbl.n_shards} shards on {len(on)} of "
+          f"{n_dev} devices")
     compared: dict = {"worker_procs": silo.config.worker_procs,
-                      "offloop_tick": silo.config.offloop_tick}
+                      "offloop_tick": silo.config.offloop_tick,
+                      "shards": tbl.n_shards}
     client = None
     written: set = set()  # every key whose write was acknowledged
 
@@ -887,6 +903,9 @@ def _mesh_traffic(n_dev: int, N: int, seed: int) -> dict:
             for h in hashes))
         await rt.flush()
     asyncio.run(activate())
+    # the only per-key calls of this phase: stop the tick worker they
+    # started, so the served phase that follows can tell its own apart
+    rt.shutdown_worker()
     check(ctbl.device_dir.count == hashes.size, "sparse activation count")
     B2 = 4096 // n            # the same 4096 messages on any mesh
     dest = rng.choice(hashes, size=n * B2)
@@ -1082,7 +1101,8 @@ def main() -> int:
     rng = np.random.default_rng(ARGS.seed)
     N = ARGS.players
     if ARGS.chips > 1:
-        phases = [("mesh", phase_mesh, N, ARGS.seed, ARGS.chips)]
+        phases = [("mesh", phase_mesh, N, ARGS.seed, ARGS.chips),
+                  ("served", phase_served, N, rng)]
     elif ARGS.worker_procs > 1:
         phases = [("served", phase_served, N, rng, ARGS.worker_procs)]
     else:

@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec as P
 from ..compile_cache import ensure_compile_cache
 from ..core.ids import GrainId
 from ..observability.stats import INGEST_STATS as _INGEST
+from ..observability.stats import MESH_STATS as _MESH
 from ..observability.stats import NO_SPAN, StageSpan
 from ..parallel.mesh import SILO_AXIS, make_mesh
 from .table import ShardedActorTable
@@ -53,9 +54,13 @@ _MESSAGES = _INGEST["messages"]
 _TRANSFER_JOBS = _INGEST["transfer_jobs"]
 _TRANSFER_PUTS = _INGEST["transfer_puts"]
 _TRANSFER_BYTES = _INGEST["transfer_bytes"]
+_JOB_LANES = _MESH["lanes"]
+_JOB_MAX_SHARD_LANES = _MESH["max_shard_lanes"]
+_JOB_SLOTS = _MESH["slots"]
 # the sink's keys that replay as counter increments, not observations
 _COUNTERS = frozenset(
-    (_MESSAGES, _TRANSFER_JOBS, _TRANSFER_PUTS, _TRANSFER_BYTES))
+    (_MESSAGES, _TRANSFER_JOBS, _TRANSFER_PUTS, _TRANSFER_BYTES,
+     _JOB_LANES, _JOB_MAX_SHARD_LANES, _JOB_SLOTS))
 _WORKER_QUEUE = "engine.worker_queue.seconds"
 _DEFERRED = "engine.deferred"               # counter: msgs deferred >= once
 _DEFER_WAIT = "engine.defer_wait.seconds"   # first deferral -> claimed
@@ -1371,6 +1376,11 @@ class VectorRuntime:
                 sink.append((_TRANSFER_JOBS, 1))
                 sink.append((_TRANSFER_PUTS, 1))
                 sink.append((_TRANSFER_BYTES, stg.packed.nbytes))
+                # the job's shape on the mesh: every shard computes the
+                # fullest shard's bucket
+                sink.append((_JOB_LANES, len(ready)))
+                sink.append((_JOB_MAX_SHARD_LANES, max(stg.used)))
+                sink.append((_JOB_SLOTS, n * B))
                 # the stage span bridges host tracing to the XLA
                 # timeline: on a jax.profiler capture this tick's kernel
                 # launch nests under otpu:ingest.tick.dispatch

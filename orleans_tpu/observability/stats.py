@@ -22,8 +22,9 @@ __all__ = ["StatsRegistry", "Histogram", "QueueWaitTrend", "CallSiteStats",
            "StageSpan", "NO_SPAN", "observe_or_defer", "open_stage_registry",
            "close_stage_registry", "STAGES", "FLUSH_STATS",
            "DISPATCH_STATS", "REBALANCE_STATS", "INGEST_STATS",
-           "INGEST_STAGES", "EGRESS_STATS", "EGRESS_STAGES", "RING_STATS",
-           "RING_STAGES", "SLO_STATS", "SIZE_BOUNDS", "COUNT_BOUNDS"]
+           "INGEST_STAGES", "MESH_STATS", "EGRESS_STATS", "EGRESS_STAGES",
+           "RING_STATS", "RING_STAGES", "SLO_STATS", "SIZE_BOUNDS",
+           "COUNT_BOUNDS"]
 
 # Hot-lane dispatch counter pair (runtime.hotlane): hits = calls that ran
 # as frame-collapsed inline turns (including the always-interleave direct
@@ -100,6 +101,18 @@ INGEST_STATS = {
     "transfer_bytes": "ingest.transfer.bytes",   # counter: bytes they uploaded
     "tick": "ingest.tick.seconds",
     "messages": "ingest.messages",               # counter: device msgs ticked
+}
+
+# What a served job costs on a mesh: a job is one ``[n_shards, B]`` launch
+# with B the bucket of its FULLEST shard, so every shard computes B lanes
+# whatever it was handed. Counters, summed over jobs, stamped beside
+# ingest.transfer's close and replayed on the loop like it (nothing is
+# stamped with metrics off). On one shard max_shard_lanes == lanes; on
+# any mesh lanes <= n_shards * max_shard_lanes <= slots.
+MESH_STATS = {
+    "lanes": "mesh.job.lanes",                      # messages the jobs carried
+    "max_shard_lanes": "mesh.job.max_shard_lanes",  # their fullest shard's
+    "slots": "mesh.job.slots",                      # n_shards * B computed
 }
 
 

@@ -146,8 +146,10 @@ def _served_tick(rt, cls, method, B, state, sharding):
 
     layout = _packed_layout(B, rt.method_of(cls, method).args_schema)
     kern = rt._build_kernel(cls, method, layout=layout)
+    n_shards = next(iter(state.values())).shape[0]
     compiled = kern.lower(
-        state, _struct((1, layout.words), jnp.int32, sharding)).compile()
+        state, _struct((n_shards, layout.words), jnp.int32,
+                       sharding)).compile()
     # exactly two parameters: the table and the one staged buffer (to
     # the chip: one array a state leaf, and one more)
     (args, kwargs) = compiled.in_avals
@@ -239,6 +241,44 @@ def test_ycsb_tick_kernels_touch_rows_not_the_table(one_chip, method, B):
 # ---------------------------------------------------------------------------
 # four chips: the shard_map kernel and the exchange
 # ---------------------------------------------------------------------------
+
+_COLLECTIVES = ("all-to-all", "all-reduce", "all-gather",
+                "collective-permute", "reduce-scatter")
+
+
+@pytest.fixture(scope="module")
+def presence_4m():
+    from orleans_tpu.parallel import make_mesh
+    return _presence_runtime(make_mesh(4), 4 * N_PLAYERS)
+
+
+@pytest.mark.parametrize("B", [1024, 4096])
+def test_sharded_served_tick_compiles_for_v5e_2x2(four_chips, presence_4m,
+                                                  B):
+    """presence-4m's tick as the served path launches it on the 2x2: 4M
+    rows, a quarter on each chip, ``(state, packed)`` sharded on the
+    leading axis, ticked in place — and no collective: a client's call
+    is routed to its shard on the host, so the shards do not talk."""
+    from orleans_tpu.parallel import SILO_AXIS
+
+    # built on four CPU devices; the kernel builder gets the described mesh
+    rt, tbl, Player = presence_4m
+    tbl.mesh = four_chips
+    shard = NamedSharding(four_chips, P(SILO_AXIS))
+    state = {k: _struct(v.shape, v.dtype, shard)
+             for k, v in tbl.state.items()}
+    compiled = _served_tick(rt, Player, "heartbeat", B, state, shard)
+    mem = compiled.memory_analysis()
+    state_bytes = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                      for v in tbl.state.values())
+    assert 0 < mem.alias_size_in_bytes <= state_bytes // 4 + 4096
+    assert mem.temp_size_in_bytes < 1 << 20     # no second table
+    for leaf in tbl.state.values():
+        per_chip = (1, *leaf.shape[1:])
+        assert not _table_sized_copies(compiled, per_chip)
+    text = compiled.as_text()
+    assert not [c for c in _COLLECTIVES if c in text]
+
 
 def test_sharded_scan_and_exchange_compile_for_v5e_2x2(
         four_chips, monkeypatch):
