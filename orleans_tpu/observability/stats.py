@@ -20,7 +20,7 @@ from jax.profiler import TraceAnnotation
 
 __all__ = ["StatsRegistry", "Histogram", "QueueWaitTrend", "CallSiteStats",
            "StageSpan", "NO_SPAN", "observe_or_defer", "open_stage_registry",
-           "close_stage_registry", "STAGES", "FLUSH_STATS",
+           "close_stage_registry", "STAGES", "FLUSH_STATS", "RECOVER_STATS",
            "DISPATCH_STATS", "REBALANCE_STATS", "INGEST_STATS",
            "INGEST_STAGES", "MESH_STATS", "EGRESS_STATS", "EGRESS_STAGES",
            "RING_STATS", "RING_STAGES", "SLO_STATS", "SIZE_BOUNDS",
@@ -255,7 +255,10 @@ SLO_STATS = {
 #   flush                 one write-behind pass of hosting.flush_all
 #   flush.locate/.gather  VectorStorageBridge.flush under the tick fence
 #   flush.write           the gather of per-key storage writes
-#   recover               first touch -> bridge.load done
+#   recover               one first-touch recovery pass: a decoded
+#                         read's fresh keys -> their one bridge.load done
+#                         (beside it RECOVER_STATS: the messages that met
+#                         a fresh key, and the keys a pass read)
 #
 # Spans that stay open across an ``await`` (flush, flush.write, recover)
 # interleave with other work on the loop: their seconds are wall time of
@@ -276,6 +279,14 @@ FLUSH_STATS = {
     # counter: of those, rows that went through a provider's own
     # write_many (not GrainStorage's per-key default)
     "batched": "vector.storage.flush.batched",
+}
+
+RECOVER_STATS = {
+    # counter: messages that met a fresh key at the pump (0 too, in a
+    # read without one: it exists)
+    "first_touch": "vector.storage.first_touch",
+    # COUNT_BOUNDS: distinct keys read, observed once a recovery pass
+    "keys": "vector.storage.recover.keys",
 }
 
 _BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"

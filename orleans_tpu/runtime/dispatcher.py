@@ -57,13 +57,34 @@ _HDR_UNPARSED = object()
 
 from ..observability.stats import INGEST_STATS as _INGEST  # noqa: E402
 from ..observability.stats import SLO_STATS as _SLO  # noqa: E402
-from ..observability.stats import NO_SPAN, StageSpan  # noqa: E402
+from ..observability.stats import (COUNT_BOUNDS,  # noqa: E402
+                                   RECOVER_STATS, StageSpan)
 
 _QUEUE_WAIT = _INGEST["queue_wait"]
 _TURNS = _INGEST["turns"]
 _TURN_ERRORS = _SLO["turn_errors"]
+_FIRST_TOUCH = RECOVER_STATS["first_touch"]
+_RECOVER_KEYS = RECOVER_STATS["keys"]
 
 MAX_FORWARD_COUNT = 2  # SiloMessagingOptions.MaxForwardCount default
+
+
+class _RecoveryPass:
+    """One first-touch recovery pass (``Dispatcher._recover_keys``):
+    its ``keys`` and its ``recover`` span (None with metrics off),
+    ``landed`` once its load is done, ``errors`` the keys whose read
+    failed (key hash → exception), ``parked`` the calls that wait behind
+    it while it is in flight (method → items in arrival order)."""
+
+    __slots__ = ("keys", "span", "landed", "errors", "parked")
+
+    def __init__(self, keys: list, span) -> None:
+        self.keys = keys
+        self.span = span
+        self.landed = False
+        self.errors: dict = {}
+        self.parked: dict = {}
+
 
 # Bulk-population collective methods (MapReduce over actors): reserved
 # method names carried by ordinary APPLICATION requests to a vector
@@ -115,8 +136,9 @@ class Dispatcher:
         self._loop_prof = None
         # the message center's response accumulator (runtime.egress)
         self._egress = silo.message_center.egress
-        # in-flight device-tier state recoveries: (class, key_hash) →
-        # future; concurrent calls for one recovering key share the load
+        # in-flight device-tier state recoveries: (class, key_hash) → the
+        # _RecoveryPass reading it; later calls for a recovering key wait
+        # behind its pass and share the load
         self._vector_recoveries: dict = {}
         self._turn_count = 0
         # strong refs to every in-flight turn/addressing task: the event
@@ -351,38 +373,9 @@ class Dispatcher:
                 msg.target_silo = owner
                 self.transmit(msg)
                 return
-        try:
-            args, kwargs = msg.body if msg.body is not None else ((), {})
-            if args:
-                raise TypeError(
-                    f"vector grain methods take keyword arguments only "
-                    f"(schema-bound); got {len(args)} positional")
-            key_hash = rt.key_hash_for(msg.target_grain.key,
-                                       msg.target_grain.uniform_hash)
-            # record the routing hash so ownership sweeps can re-derive
-            # who owns this resident row after a membership change
-            rt.table(vcls).note_route(key_hash,
-                                      msg.target_grain.uniform_hash)
-            bridge = getattr(self.silo, "vector_bridges", {}).get(vcls)
-            if bridge is not None and \
-                    self._vector_key_is_fresh(rt, vcls, key_hash):
-                # virtual-actor recovery (Catalog.cs:443 +
-                # StateStorageBridge.cs:49 on the device tier): this silo
-                # became the key's ring owner without its state — e.g.
-                # after the previous owner died — so rehydrate the row
-                # from write-behind storage before the first kernel tick
-                # touches it. Keys with no stored state proceed fresh
-                # (the lazy-recreate contract).
-                fut = self._track(asyncio.ensure_future(
-                    self._recover_then_call(
-                        rt, vcls, bridge, key_hash, msg.method_name, kwargs)))
-            else:
-                fut = rt.call(vcls, key_hash, msg.method_name, **kwargs)
-        except Exception as e:  # noqa: BLE001 — schema/arg errors → caller
-            if msg.direction != Direction.ONE_WAY:
-                self.send_response(msg, make_error_response(msg, e))
-            return
-        self._finish_vector_call(msg, fut)
+        # the call itself joins the engine as the batched ingress does:
+        # a read of one message (first-touch recovery included)
+        self._enqueue_vector_calls(rt, vcls, (msg,))
 
     def _finish_vector_call(self, msg: Message, fut: "asyncio.Future",
                             hdr=_HDR_UNPARSED) -> None:
@@ -445,20 +438,18 @@ class Dispatcher:
         table resolution and ONE tick schedule for N messages instead of
         N ``rt.call`` hops. This is the queue-wait killer on the vector
         path: the whole socket read's calls land in the same tick batch.
-        Messages needing the slow path (ownership forward, storage
-        recovery, malformed bodies) peel off to the per-message handler,
-        which preserves their exact semantics."""
+        Bulk collectives and ownership forwards peel off here, message by
+        message; everything addressed to this silo — a key's first touch
+        with its storage recovery, a malformed body — goes on through
+        :meth:`_enqueue_vector_calls` as one read."""
         rt = self.silo.vector
         my_addr = self.silo.silo_address
         ring = self.silo.locator.ring
         # worker process (runtime.multiproc): no ownership forwards —
         # the staging ring funnels everything into the owner engine
         proxy = getattr(rt, "is_shm_proxy", False)
-        bridge = getattr(self.silo, "vector_bridges", {}).get(vcls)
-        tbl = rt.table(vcls)
-        tracer = self.silo.tracer
         now = time.monotonic()
-        groups: dict[str, list] = {}
+        local: list = []
         for msg in msgs:
             if msg.expires_at is not None and now > msg.expires_at:
                 log.warning("dropping expired vector request %s",
@@ -497,6 +488,28 @@ class Dispatcher:
                     # message forever)
                     self._handle_vector_request(vcls, msg)
                 continue
+            local.append(msg)
+        if local:
+            self._enqueue_vector_calls(rt, vcls, local)
+
+    def _enqueue_vector_calls(self, rt, vcls: type, msgs) -> None:
+        """One read's calls for keys this silo owns, into the engine:
+        the bodies are checked, the read's fresh keys are recovered from
+        write-behind storage in ONE pass (:meth:`_recover_keys`) and
+        every message joins its method's ``call_group`` in arrival
+        order. Where the pass completed on the spot the fresh keys'
+        messages are grouped like the rest; where it suspended they wait
+        behind it (and so does any later message for a key whose pass is
+        still in flight) while the other keys' messages go on at once."""
+        bridge = getattr(self.silo, "vector_bridges", {}).get(vcls)
+        tbl = rt.table(vcls)
+        tracer = self.silo.tracer
+        recovering = self._vector_recoveries
+        is_fresh = self._vector_key_is_fresh
+        groups: dict[str, list] = {}
+        fresh: dict = {}  # this read's fresh keys, each once, in order
+        touched = 0
+        for msg in msgs:
             try:
                 args, kwargs = msg.body if msg.body is not None else ((), {})
                 if args:
@@ -516,15 +529,9 @@ class Dispatcher:
                 if msg.direction != Direction.ONE_WAY:
                     self.send_response(msg, make_error_response(msg, e))
                 continue
-            if bridge is not None and \
-                    self._vector_key_is_fresh(rt, vcls, key_hash):
-                # first touch with write-behind storage: recovery path
-                self._handle_vector_request(vcls, msg)
-                continue
+            # record the routing hash so ownership sweeps can re-derive
+            # who owns this resident row after a membership change
             tbl.note_route(key_hash, msg.target_grain.uniform_hash)
-            g = groups.get(msg.method_name)
-            if g is None:
-                g = groups[msg.method_name] = []
             # one-way calls need no result plumbing — the engine skips
             # their futures entirely. Exception: a SAMPLED one-way (trace
             # header present) still needs its device span closed at tick
@@ -534,8 +541,71 @@ class Dispatcher:
             hdr = (context_from_headers(msg.request_context)
                    if tracer is not None else None)
             want = msg.direction != Direction.ONE_WAY or hdr is not None
-            g.append((msg, key_hash, kwargs, want, hdr))
+            g = groups
+            if bridge is not None:
+                # virtual-actor recovery (Catalog.cs:443 +
+                # StateStorageBridge.cs:49 on the device tier): this silo
+                # became the key's ring owner without its state — e.g.
+                # after the previous owner died — so the row is
+                # rehydrated from write-behind storage before the first
+                # kernel tick touches it. Keys with no stored state
+                # proceed fresh (the lazy-recreate contract).
+                held = recovering.get((vcls, key_hash)) \
+                    if recovering else None
+                if held is not None:
+                    # its key's pass is in flight: behind it, never a
+                    # second read and never past an earlier message
+                    g = held.parked
+                    touched += 1
+                elif key_hash in fresh:
+                    touched += 1
+                elif is_fresh(tbl, key_hash):
+                    fresh[key_hash] = None
+                    touched += 1
+            items = g.get(msg.method_name)
+            if items is None:
+                items = g[msg.method_name] = []
+            items.append((msg, key_hash, kwargs, want, hdr))
+        if bridge is not None and self._istats is not None:
+            self.silo.stats.increment(_FIRST_TOUCH, touched)  # 0: it exists
+        if fresh:
+            rec = self._recover_keys(rt, vcls, bridge, list(fresh))
+            if not rec.landed:
+                # the load suspended: this read's messages of the keys
+                # under recovery wait behind the pass
+                self._take_vector_items(groups, fresh, rec.parked)
+            elif rec.errors:
+                self._fail_vector_items(groups, rec.errors)
+        self._call_vector_groups(rt, vcls, groups)
+
+    @staticmethod
+    def _take_vector_items(groups: dict, keys, into: dict) -> dict:
+        """Move the items whose key is in ``keys`` out of the per-method
+        ``groups`` into ``into`` (per method too, arrival order kept)."""
         for method, items in groups.items():
+            taken = [it for it in items if it[1] in keys]
+            if taken:
+                into.setdefault(method, []).extend(taken)
+                items[:] = [it for it in items if it[1] not in keys]
+        return into
+
+    def _fail_vector_items(self, groups: dict, errors: dict) -> None:
+        """Take each call of a key whose storage read failed out of
+        ``groups`` and answer it with that key's failure (``errors``:
+        key hash → exception)."""
+        failed = self._take_vector_items(groups, errors, {})
+        self.send_response_batch(
+            (m, make_error_response(m, errors[kh]))
+            for items in failed.values() for m, kh, _, _, _ in items
+            if m.direction != Direction.ONE_WAY)
+
+    def _call_vector_groups(self, rt, vcls: type, groups: dict) -> None:
+        """One ``call_group`` a method, and the response plumbing of
+        every call that wants one."""
+        tracer = self.silo.tracer
+        for method, items in groups.items():
+            if not items:
+                continue
             try:
                 # per-item trace contexts ride beside the group: the
                 # engine (or the shm proxy, in a worker process) parents
@@ -860,49 +930,81 @@ class Dispatcher:
         return {"value": total, "count": count}
 
     @staticmethod
-    def _vector_key_is_fresh(rt, vcls: type, key_hash: int) -> bool:
+    def _vector_key_is_fresh(tbl, key_hash: int) -> bool:
         """True iff the key has no live row in the local table (first
         touch on this silo — the recovery trigger)."""
-        tbl = rt.table(vcls)
         if 0 <= key_hash < tbl.dense_n:
-            return not bool(tbl.dense_active[key_hash])
+            return not tbl.dense_active[key_hash]
         return tbl.lookup(key_hash) is None
 
-    async def _recover_then_call(self, rt, vcls: type, bridge,
-                                 key_hash: int, method: str, kwargs: dict):
-        """Rehydrate one key from write-behind storage, then run the call.
-        Concurrent first-touch calls share a single storage read; the
-        call itself joins the next tick as usual."""
-        rec_key = (vcls, key_hash)
-        rec = self._vector_recoveries.get(rec_key)
-        if rec is None:
-            if not self._vector_key_is_fresh(rt, vcls, key_hash):
-                # a recovery completed between the fresh-check in
-                # _handle_vector_request and this task running: loading
-                # again would re-scatter stale stored state over ticks
-                # that already ran
-                return await rt.call(vcls, key_hash, method, **kwargs)
-            st = self.silo.ingest_stats
-            # first touch -> the stored row is in the table (or there was
-            # none); opened before the load task exists (an eager task
-            # factory runs it to its first suspension right here) and
-            # held across the storage read
-            span = StageSpan(st, "recover", nest=False) \
-                if st is not None else NO_SPAN
-            rec = asyncio.ensure_future(bridge.load([key_hash]))
-            self._vector_recoveries[rec_key] = rec
-            try:
-                with span:
-                    restored = await rec
-                # counted from the first touch on, so a deployment that
-                # recovers nothing reads 0 and not "no such counter"
-                self.silo.stats.increment("vector.storage.recovered",
-                                          len(restored))
-            finally:
-                self._vector_recoveries.pop(rec_key, None)
-        else:
-            await rec
-        return await rt.call(vcls, key_hash, method, **kwargs)
+    def _recover_keys(self, rt, vcls: type, bridge,
+                      keys: list) -> _RecoveryPass:
+        """One first-touch recovery pass: rehydrate ``keys`` (fresh, none
+        of them under another pass) from write-behind storage with one
+        ``bridge.load`` — one bulk read, the rows found scattered under
+        the tick fence — under one ``recover`` span. Whether the load
+        completed is observed, not configured: an eager task over a
+        provider that never suspends is done when it is returned and the
+        pass has ``landed``; otherwise the pass is booked under each of
+        its keys until the load lands, and :meth:`_recovery_landed`
+        enqueues what was parked behind it. The caller decides freshness
+        and starts the pass in one synchronous stretch, so no recovery
+        can activate a key in between (loading it again would scatter
+        stale stored state over ticks that already ran)."""
+        st = self._istats
+        # first touch -> the stored rows are in the table (or there were
+        # none); opened before the load task exists (an eager task
+        # factory runs it to its first suspension right here) and held
+        # across the storage read
+        rec = _RecoveryPass(keys, StageSpan(
+            st, "recover", nest=False, keys=len(keys))
+            if st is not None else None)
+        load = asyncio.ensure_future(bridge.load(keys, rec.errors))
+        if load.done():
+            self._recovery_read(rec, load)
+            return rec
+        recovering = self._vector_recoveries
+        for k in keys:
+            recovering[(vcls, k)] = rec
+        self._track(load).add_done_callback(
+            lambda f: self._recovery_landed(rt, vcls, rec, f))
+        return rec
+
+    def _recovery_read(self, rec: _RecoveryPass,
+                       load: "asyncio.Future") -> None:
+        """The pass's load is done: close its span, count it, and book a
+        load that failed as a whole under every key of the pass."""
+        rec.landed = True
+        span = rec.span
+        if span is not None:
+            span.close()
+            span.stats.histogram_with(_RECOVER_KEYS,
+                                      COUNT_BOUNDS).observe(len(rec.keys))
+        if load.cancelled():
+            return
+        exc = load.exception()
+        if exc is not None:
+            rec.errors.update((k, exc) for k in rec.keys)
+            return
+        # counted from the first touch on, so a deployment that recovers
+        # nothing reads 0 and not "no such counter"
+        self.silo.stats.increment("vector.storage.recovered",
+                                  len(load.result()))
+
+    def _recovery_landed(self, rt, vcls: type, rec: _RecoveryPass,
+                         load: "asyncio.Future") -> None:
+        """Done-callback of a pass whose load suspended: release its
+        keys, then enqueue the messages parked behind it — grouped, in
+        arrival order — or fail those of a key whose read failed."""
+        recovering = self._vector_recoveries
+        for k in rec.keys:
+            recovering.pop((vcls, k), None)
+        self._recovery_read(rec, load)
+        if load.cancelled():
+            return  # silo stop: the callers' futures break via close()
+        if rec.errors:
+            self._fail_vector_items(rec.parked, rec.errors)
+        self._call_vector_groups(rt, vcls, rec.parked)
 
     def receive_request(self, activation: ActivationData, msg: Message) -> None:
         """ReceiveRequest:262 — gate, then run or enqueue."""

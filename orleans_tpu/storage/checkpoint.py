@@ -23,7 +23,6 @@ Two recovery paths:
 
 from __future__ import annotations
 
-import asyncio
 import logging
 import math
 from typing import TYPE_CHECKING, Iterable
@@ -397,22 +396,37 @@ class VectorStorageBridge:
                 raise first
         return len(kept) - len(failed) - conflicts
 
-    async def load(self, keys: Iterable[int]) -> list[int]:
+    async def load(self, keys: Iterable[int],
+                   errors: dict | None = None) -> list[int]:
         """Resume: read stored rows and scatter them into the table.
         Returns the keys that had persisted state (missing keys keep
-        their fresh-init state — the lazy-recreate contract)."""
+        their fresh-init state — the lazy-recreate contract).
+
+        The keys are read in bulk: one ``storage.read_many`` for the
+        whole list (a single synchronous pass for a provider that
+        overrides it, one concurrent ``read`` per key otherwise), and the
+        rows found are scattered under one hold of the tick fence.
+
+        Per-key failure: a key whose read raised is left as it was
+        (fresh, no etag remembered) and its exception is put into
+        ``errors[key]``; the other keys' rows are restored all the same.
+        Without an ``errors`` dict to hold them the first such exception
+        is raised before anything is scattered — a caller that cannot
+        see a failure must never be told the pass succeeded."""
         keys = [int(k) for k in keys]
         if not keys:
             return []
         tbl = self.runtime.table(self.grain_class)
-
-        async def read_one(key: int):
-            state, etag = await self.storage.read(
-                self.grain_type, self._grain_id(key))
-            return key, state, etag
-
-        rows = await asyncio.gather(*(read_one(k) for k in keys))
-        found = [(k, s, e) for k, s, e in rows if s is not None]
+        rows = await self.storage.read_many(
+            self.grain_type, [self._grain_id(k) for k in keys])
+        found = []
+        for k, r in zip(keys, rows):
+            if isinstance(r, BaseException):
+                if errors is None:
+                    raise r
+                errors[k] = r
+            elif r[0] is not None:
+                found.append((k, r[0], r[1]))
         if not found:
             return []
         for k, _, e in found:

@@ -20,6 +20,13 @@ per key, so a provider that knows only per-key operations (``FileStorage``,
 the fault- and latency-injecting wrappers, a user's own) keeps its per-key
 latency, faults and interleaving; ``MemoryStorage`` overrides it with a single
 synchronous pass over its dict.
+
+Batched read: ``GrainStorage.read_many(grain_type, grain_ids)`` is the same
+contract turned round — one item per id, in order, ``(state, etag)`` or the
+exception that id raised. First-touch recovery of the device tier
+(``VectorStorageBridge.load``) reads through it; the default is one
+concurrent ``read`` per id, and ``MemoryStorage`` answers from its dict in
+one pass.
 """
 
 from __future__ import annotations
@@ -61,7 +68,9 @@ ADOPT_ETAG = _AdoptEtag()
 
 class GrainStorage:
     """Provider interface (``IGrainStorage``): etag-checked read/write/clear
-    keyed by (grain type name, grain id)."""
+    keyed by (grain type name, grain id), and their bulk forms
+    ``read_many`` / ``write_many`` over the records of one grain type
+    (per-key by default; a provider that can do better overrides them)."""
 
     async def read(self, grain_type: str, grain_id: GrainId
                    ) -> tuple[Any, str | None]:
@@ -99,6 +108,20 @@ class GrainStorage:
         return await asyncio.gather(*(one(*e) for e in entries),
                                     return_exceptions=True)
 
+    async def read_many(self, grain_type: str, grain_ids) -> list:
+        """Read many records of one grain type. Returns a list with one
+        item per id, in order: ``(state, etag)`` as ``read`` returns it
+        (``(None, None)`` when absent), or the exception that id raised —
+        one id's failure never fails another's.
+
+        This default is per-key: every id runs its own ``read``
+        concurrently, so latency, injected faults and interleaving stay
+        those of the provider's ``read``. A provider that can do better
+        in bulk overrides it."""
+        return await asyncio.gather(
+            *(self.read(grain_type, g) for g in grain_ids),
+            return_exceptions=True)
+
 
 def _key(grain_type: str, grain_id: GrainId) -> tuple:
     return (grain_type, grain_id.uniform_hash, str(grain_id.key), grain_id.key_ext)
@@ -135,14 +158,16 @@ class MemoryStorage(GrainStorage):
         return new_etag
 
     def __init_subclass__(cls, **kwargs) -> None:
-        # write_many below goes to the dict directly: a subclass that
-        # brings its own read or write (to count, fail or delay) and no
-        # write_many of its own gets the per-key default back, so its
-        # methods see every record
+        # write_many and read_many below go to the dict directly: a
+        # subclass that brings its own read or write (to count, fail or
+        # delay) and no bulk method of its own gets the per-key default
+        # back, so its methods see every record
         super().__init_subclass__(**kwargs)
         if "write_many" not in vars(cls) and vars(cls).keys() & {
                 "read", "write"}:
             cls.write_many = GrainStorage.write_many
+        if "read_many" not in vars(cls) and "read" in vars(cls):
+            cls.read_many = GrainStorage.read_many
 
     async def write_many(self, grain_type, entries):
         """The whole batch in one synchronous pass over the dict: no
@@ -167,6 +192,22 @@ class MemoryStorage(GrainStorage):
             new_etag = f"e{next(seq)}"
             data[k] = (blob, new_etag)
             out.append(new_etag)
+        return out
+
+    async def read_many(self, grain_type, grain_ids):
+        """Every id in one synchronous pass over the dict: no coroutine
+        per key, and a miss costs one probe."""
+        get = self._data.get
+        out = []
+        for grain_id in grain_ids:
+            rec = get(_key(grain_type, grain_id))
+            if rec is None:
+                out.append((None, None))
+                continue
+            try:
+                out.append((deserialize(rec[0]), rec[1]))
+            except Exception as e:  # noqa: BLE001 — this id's result
+                out.append(e)
         return out
 
     async def clear(self, grain_type, grain_id, etag):

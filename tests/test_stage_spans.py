@@ -30,7 +30,8 @@ import pytest
 
 from orleans_tpu.dispatch import VectorGrain, actor_method, add_vector_grains
 from orleans_tpu.observability import stats as stats_mod
-from orleans_tpu.observability.stats import (FLUSH_STATS, STAGES, StageSpan,
+from orleans_tpu.observability.stats import (FLUSH_STATS, RECOVER_STATS,
+                                             STAGES, StageSpan,
                                              StatsRegistry,
                                              close_stage_registry,
                                              open_stage_registry)
@@ -191,7 +192,11 @@ def test_stage_observed_once_per_unit_of_work(served, stage):
     elif stage == "egress.flush":
         assert got == _count(st, "egress.build.seconds") >= 1
     elif stage == "recover":
-        assert got == N_KEYS  # every key's first touch, and only that
+        # once a recovery pass (a decoded read's fresh keys), not once a
+        # message: the passes read every key's first touch, and only that
+        keys = st.histograms[RECOVER_STATS["keys"]]
+        assert 1 <= got == keys.total <= N_KEYS
+        assert keys.sum == N_KEYS == st.get(RECOVER_STATS["first_touch"])
     else:
         raise AssertionError(f"stage {stage} has no case")
 
@@ -250,10 +255,85 @@ async def test_metrics_off_registers_none_of_the_new_names():
         await silo.stop()
     names = set(silo.stats.histograms) | set(silo.stats.counters)
     new = {s + ".seconds" for s in STAGES} | {
-        FLUSH_STATS["rows"], FLUSH_STATS["flushes"]}
+        FLUSH_STATS["rows"], FLUSH_STATS["flushes"]} | set(
+        RECOVER_STATS.values())
     assert not names & new
     assert not [n for n in names if n.startswith("compile.")]
     assert silo.stats.get(FLUSH_STATS["flushed"]) >= 4  # it did flush
+
+
+class _GatedStorage(MemoryStorage):
+    """A provider that really waits: every read parks on ``gate``."""
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.gate = asyncio.Event()
+
+    async def read(self, grain_type, grain_id):
+        await self.gate.wait()
+        return await super().read(grain_type, grain_id)
+
+
+async def test_recover_span_is_held_across_a_suspending_load():
+    """One ``recover`` span a pass, open while the provider waits and
+    closed when the load lands; a window without a first touch leaves the
+    two recovery stats in place, unmoved."""
+    storage = _GatedStorage()
+    silo = _build(True, storage, period=3600.0)
+    await silo.start()
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    st = silo.stats
+    try:
+        calls = [(k, {"x": np.int32(1)}) for k in range(N_KEYS)]
+        futs = client.call_batch(CounterVec, "add", calls)
+        for _ in range(400):
+            if silo.dispatcher._vector_recoveries:
+                break
+            await asyncio.sleep(0.005)
+        # the pass is in flight: its span is open, nothing observed yet
+        assert len(silo.dispatcher._vector_recoveries) == N_KEYS
+        assert _count(st, "recover.seconds") == 0
+        assert st.get(RECOVER_STATS["first_touch"]) == N_KEYS
+        await asyncio.sleep(0.05)
+        storage.gate.set()
+        assert [int(v) for v in await asyncio.gather(*futs)] == [1] * N_KEYS
+        assert _count(st, "recover.seconds") == 1
+        assert st.histograms["recover.seconds"].sum >= 0.05
+        keys = st.histograms[RECOVER_STATS["keys"]]
+        assert (keys.total, keys.sum) == (1, N_KEYS)
+        assert not silo.dispatcher._vector_recoveries
+        # a second round touches nothing fresh: both stats stay, unmoved
+        before = dict(st.counters)
+        out = await asyncio.gather(*client.call_batch(CounterVec, "add",
+                                                      calls))
+        assert [int(v) for v in out] == [2] * N_KEYS
+        assert st.counters[RECOVER_STATS["first_touch"]] \
+            == before[RECOVER_STATS["first_touch"]] == N_KEYS
+        assert (keys.total, keys.sum) == (1, N_KEYS)
+        assert _count(st, "recover.seconds") == 1
+    finally:
+        await client.close_async()
+        await silo.stop()
+
+
+async def test_first_touch_counter_exists_before_any_first_touch():
+    """0 and not absent: a read that meets no fresh key still stamps the
+    counter, so a window without a first touch reads 0."""
+    silo = _build(True, MemoryStorage(), period=3600.0)
+    await silo.start()
+    rt = silo.vector
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    try:
+        # activated in-process, past the dispatcher: no first touch there
+        assert int(await rt.call(CounterVec, 5, "add", x=np.int32(1))) == 1
+        assert RECOVER_STATS["first_touch"] not in silo.stats.counters
+        g = client.get_grain(CounterVec, 5)
+        assert int(await g.add(x=np.int32(1))) == 2
+        assert silo.stats.counters[RECOVER_STATS["first_touch"]] == 0
+        assert _count(silo.stats, "recover.seconds") == 0
+    finally:
+        await client.close_async()
+        await silo.stop()
 
 
 async def test_compiles_are_booked_to_the_stage_that_compiled():
