@@ -7,8 +7,9 @@ variable makes sure this process cannot take the chip). It builds its
 share of the cell's traffic from the seed, opens its own ``GatewayClient``
 over loopback TCP, warms up, reports ready, takes one start instant from
 the parent, and records per request (due, send, reply, calls ok / failed /
-wrong) in numpy arrays, which it writes with its reference's expected
-states to the file the parent named.
+wrong, whether the traffic kind aimed it at a hot record) in numpy arrays,
+which it writes with its reference's expected states to the file the parent
+named.
 
 Protocol: one JSON object per line, parent -> stdin, child -> stdout:
 ``{"state": "built"}`` <- ``{"endpoint": ...}`` -> ``{"state": "ready"}``
@@ -54,7 +55,7 @@ def say(obj: dict) -> None:
 class Records:
     """Per-request records in preallocated numpy arrays."""
 
-    COLS = ("due", "send", "done", "ok", "failed", "wrong")
+    COLS = ("due", "send", "done", "ok", "failed", "wrong", "hot")
 
     def __init__(self, n: int = 1 << 14) -> None:
         self.a = np.zeros((n, len(self.COLS)), np.float64)
@@ -80,6 +81,8 @@ async def drive(traffic, client, loop_kind: str, t0: float, seconds: float,
     cpu: dict = {}
     extra: dict = {}
 
+    hot_of = getattr(traffic, "hot_of", None)  # a kind may mark hot records
+
     async def stamp_cpu() -> None:
         await asyncio.sleep(max(0.0, t0 - time.monotonic()))
         cpu["t0"] = time.process_time()
@@ -90,7 +93,8 @@ async def drive(traffic, client, loop_kind: str, t0: float, seconds: float,
         send = time.monotonic()
         due = send if due is None else due  # closed loop: due when sent
         ok, failed, wrong = await traffic.request(client, slot)
-        rec.add(due, send, time.monotonic(), ok, failed, wrong)
+        rec.add(due, send, time.monotonic(), ok, failed, wrong,
+                hot_of(slot) if hot_of else 0)
 
     async def closed_caller(slot: int) -> None:
         await asyncio.sleep(max(0.0, t0 - time.monotonic()))
@@ -172,6 +176,7 @@ async def main(spec: dict) -> int:
     }
     traffic = load_by_name("traffic", wl["generator"]).Traffic(ctx)
     say({"state": "built", "codec": native.wire_codec(),
+         "traffic": getattr(traffic, "about", dict)(),
          "jax_backend_initialised": _backend_initialised()})
     loop = asyncio.get_running_loop()
     msg = json.loads(await loop.run_in_executor(None, sys.stdin.readline))

@@ -46,11 +46,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-from loadgen import load_by_name, say as emit  # noqa: E402
+from loadgen import Records, load_by_name, say as emit  # noqa: E402
 
 TRACE_SLICE_S = 3.0   # the profiler traces this much, mid-window
 MIN_BUCKET = 8        # the engine's smallest tick bucket (dispatch/engine.py)
+MAX_FLUSH_ROWS = 16384  # the widest write-behind pass set-up compiles for
 CHILD_LIMIT_S = 120.0  # a child silent for this long has hung
+QUANTILES = ("50", "90", "95", "99")  # of a latency population, printed
 
 
 def fail(msg: str, code: int = 2) -> NoReturn:
@@ -269,6 +271,26 @@ async def warm_buckets(rt, cls, warm: dict) -> list[int]:
     return buckets
 
 
+def warm_flush_gather(silo, rt) -> list[int]:
+    """Compile the write-behind flush's device→host gather (one program a
+    table and power-of-two row bucket) for every bucket up to
+    ``MAX_FLUSH_ROWS``, over twice the widest pass a cell makes today (6.1k
+    rows): a pass wider than any the warm-up's traffic happened to make
+    compiles inside the window otherwise. The bridge's own gather, of row
+    (0, 0) as its padding reads it; nothing is written or marked."""
+    if not getattr(silo, "vector_bridges", None):
+        return []
+    buckets = [1 << i for i in range(MIN_BUCKET.bit_length() - 1,
+                                     MAX_FLUSH_ROWS.bit_length())]
+    for cls, bridge in silo.vector_bridges.items():
+        tbl = rt.table(cls)
+        for b in buckets:
+            at = np.zeros(b, np.int32)
+            with rt.tick_fence():
+                bridge._gather(tbl, at, at)
+    return buckets
+
+
 async def settle_flusher(silo, period: float, limit: float = 20.0) -> None:
     """Wait until the write-behind flusher has drained the warm-up's writes:
     its counter unchanged over two flush periods."""
@@ -395,8 +417,64 @@ async def compare_storage(storage, cls, kept: list, period: float) -> dict:
             "seconds": time.monotonic() - t0}
 
 
-def percentile(vals: np.ndarray, q: float) -> float:
-    return float(np.percentile(vals, q)) if len(vals) else float("nan")
+def verdict(cols: dict, wrong_in_warm_up: int, ok_calls: int, rows: dict,
+            stored: dict | None, rows_grown: int) -> tuple[bool, dict]:
+    """``correct``, and each number it was decided from beside its limit
+    (every comparison is exact: the limit is 0). Every reply the children
+    judged counts, whenever it came and whatever record it was for."""
+    compared = {
+        "wrong_replies": int(cols["wrong"].sum()) + wrong_in_warm_up,
+        "bad_rows": rows["bad_rows"],
+        "stored_not_readable": stored["not_readable"] if stored else 0,
+        "table_rows_grown": rows_grown,
+        "windows_without_a_right_answer": int(ok_calls <= 0),
+    }
+    return (not any(compared.values()),
+            {k: {"value": v, "limit": 0} for k, v in compared.items()})
+
+
+def percentile(vals: np.ndarray, q: float) -> float | None:
+    return float(np.percentile(vals, q)) if len(vals) else None
+
+
+def client_numbers(cols: dict, t0: float, seconds: float) -> dict:
+    """What the clients saw, from the children's per-request records
+    (``loadgen.Records``' columns, merged). A request counts where it
+    completed inside the window; a latency sample is one that neither
+    failed nor was wrong. ``latency_ms`` is over all of them;
+    ``latency_ms_cold`` and ``latency_ms_hot`` are the same samples apart,
+    by whether the traffic kind aimed the request at a hot record (a kind
+    that marks none has every sample cold and the first two equal)."""
+    inside = (cols["done"] >= t0) & (cols["done"] <= t0 + seconds)
+    sample = inside & (cols["failed"] == 0) & (cols["wrong"] == 0)
+    hot = cols["hot"] > 0
+    ms = (cols["done"] - cols["due"]) * 1e3
+    late = (cols["send"] - cols["due"])[inside] * 1e3
+    n, n_hot = int(inside.sum()), int((inside & hot).sum())
+
+    def percentiles_of(mask: np.ndarray, qs: tuple) -> dict:
+        return {q: percentile(ms[mask], float(q)) for q in qs}
+
+    return {
+        "requests_in_window": n,
+        "requests_in_flight_at_end": int((~inside).sum()),
+        "ok_calls": int(cols["ok"][inside].sum()),
+        "failed_calls": int(cols["failed"][inside].sum()),
+        "wrong_calls": int(cols["wrong"][inside].sum()),
+        "hot_requests": n_hot,
+        "hot_share_pct": 100.0 * n_hot / n if n else None,
+        "latency_samples": int(sample.sum()),
+        "latency_ms": percentiles_of(sample, QUANTILES + ("100",)),
+        "latency_ms_cold": percentiles_of(sample & ~hot, QUANTILES),
+        "latency_ms_hot": percentiles_of(sample & hot, QUANTILES),
+        "generator_lateness_ms": {
+            "mean": float(late.mean()) if len(late) else None,
+            "p95": percentile(late, 95.0),
+            "max": float(late.max()) if len(late) else None},
+        "ok_calls_by_second": np.histogram(
+            cols["done"][inside] - t0, bins=max(1, int(seconds)),
+            range=(0.0, seconds),
+            weights=cols["ok"][inside])[0].astype(int).tolist()}
 
 
 # ---------------------------------------------------------------------------
@@ -443,16 +521,23 @@ async def serve(args, wl: dict, cfg: dict, device: dict, meter: CompileMeter,
     try:
         buckets = await warm_buckets(rt, classes[wl["warm"]["grain"]],
                                      wl["warm"])
+        flush_buckets = warm_flush_gather(silo, rt)
+        about = []
         for c in children:
             built = await c.expect("built")
             if not built["codec"].startswith("native"):
                 raise RuntimeError(f"load generator {c.idx} runs the wire "
                                    f"codec {built['codec']!r}")
+            about.append(built["traffic"])
             c.tell({"endpoint": silo.gateway_endpoint})
+        if any(a != about[0] for a in about):
+            raise RuntimeError(f"the load generators disagree about the "
+                               f"traffic: {about}")
         warm = [(await c.expect("ready"))["warm"] for c in children]
         if storage is not None:
             await settle_flusher(silo, period)
         emit({"phase": "set-up", "tick_buckets_warmed": buckets,
+              "flush_buckets_warmed": flush_buckets, "traffic": about[0],
               "warm_up_calls": [int(sum(w[0] for w in warm)),
                                 int(sum(w[1] for w in warm)),
                                 int(sum(w[2] for w in warm))],
@@ -502,52 +587,37 @@ async def serve(args, wl: dict, cfg: dict, device: dict, meter: CompileMeter,
         await silo.stop()
 
     # ---- the clients' numbers ---------------------------------------------
-    t1 = t0 + args.seconds
     cols = {c: np.concatenate([r[f"rec.{c}"] for r in results])
-            for c in ("due", "send", "done", "ok", "failed", "wrong")}
-    inside = (cols["done"] >= t0) & (cols["done"] <= t1)
-    ok = int(cols["ok"][inside].sum())
-    wrong_in = int(cols["wrong"][inside].sum())
-    failed_in = int(cols["failed"][inside].sum())
+            for c in Records.COLS}
+    cl = client_numbers(cols, t0, args.seconds)
+    ok = cl["ok_calls"]
     unsent = sum(r["extra"].get("unsent", 0) for r in results)
-    wrong_all = int(cols["wrong"].sum()) + sum(r["extra"]["warm"][2]
-                                               for r in results)
     failed_warm = sum(r["extra"]["warm"][1] for r in results)
-    lat = (cols["done"] - cols["due"])[inside & (cols["failed"] == 0)
-                                       & (cols["wrong"] == 0)] * 1e3
-    late = (cols["send"] - cols["due"])[inside] * 1e3
-    attempted = ok + wrong_in + failed_in + unsent
-    failed = wrong_in + failed_in + unsent
-    correct = (wrong_all == 0 and rows["bad_rows"] == 0 and ok > 0
-               and (stored is None or stored["not_readable"] == 0)
-               and tbl.capacity == capacity0)
+    attempted = ok + cl["wrong_calls"] + cl["failed_calls"] + unsent
+    failed = cl["wrong_calls"] + cl["failed_calls"] + unsent
+    correct, compared = verdict(
+        cols, sum(r["extra"]["warm"][2] for r in results), ok, rows, stored,
+        tbl.capacity - capacity0)
 
-    emit({"phase": "clients", "loop": wl["loop"],
-          "requests_in_window": int(inside.sum()),
-          "requests_in_flight_at_end": int((~inside).sum()),
-          "latency_samples": int(len(lat)),
-          "latency_ms": {q: percentile(lat, float(q))
-                         for q in ("50", "90", "95", "99", "100")},
-          "generator_lateness_ms": {
-              "mean": float(late.mean()) if len(late) else None,
-              "p95": percentile(late, 95.0),
-              "max": float(late.max()) if len(late) else None},
-          "ok_calls_by_second": np.histogram(
-              cols["done"][inside] - t0, bins=max(1, int(args.seconds)),
-              range=(0.0, args.seconds),
-              weights=cols["ok"][inside])[0].astype(int).tolist(),
+    emit({"phase": "clients", "loop": wl["loop"], **cl,
           "unsent_at_end": unsent, "failed_in_warm_up": failed_warm,
           "per_child": [{"requests": int(len(r["rec.done"])),
                          "cpu_s": r["extra"]["cpu_s"]} for r in results]})
-    emit({"phase": "correct", "wrong_replies": wrong_all, "rows": rows,
+    emit({"phase": "correct",
+          "wrong_replies": compared["wrong_replies"]["value"], "rows": rows,
           "storage": stored, "table_grew": tbl.capacity != capacity0})
 
+    # every request of the window counts in each of these, the ones aimed
+    # at a hot record included: the populations apart are the clients'
+    # line's and the per-layer metrics'
     e2e = {
         "calls_per_s": (ok / args.seconds / cfg["chips"], "calls/s"),
-        "latency_p50_ms": (percentile(lat, 50.0), "ms"),
-        "latency_p95_ms": (percentile(lat, 95.0), "ms"),
+        "latency_p50_ms": (cl["latency_ms"]["50"], "ms"),
+        "latency_p95_ms": (cl["latency_ms"]["95"], "ms"),
         "setup_s": (setup_s, "s"),
     }
+    for name in wl.get("end_to_end_left_out", ()):  # the cell's file says
+        del e2e[name]
     dev = dict(device, memory_peak_bytes=int(
         mem.get("peak_bytes_in_use", mem.get("bytes_in_use", 0))))
     out = {"correct": bool(correct), "attempted": attempted,
@@ -556,6 +626,7 @@ async def serve(args, wl: dict, cfg: dict, device: dict, meter: CompileMeter,
         out["metrics"] = {k: {"value": v, "unit": u}
                           for k, (v, u) in e2e.items()}
         out["device"] = dev
+        out["compared"] = compared
         return out, correct
 
     # ---- the layers' numbers (traced run) -----------------------------------
@@ -576,7 +647,7 @@ async def serve(args, wl: dict, cfg: dict, device: dict, meter: CompileMeter,
     ctx = {
         "seconds": args.seconds,
         "counters": window["counters"], "histograms": window["histograms"],
-        "slice": sl, "trace": reduced,
+        "slice": sl, "trace": reduced, "clients": cl,
         "cpu": {"silo": [{"cpu_s": window["cpu_s"],
                           "wall_s": window["seconds"]}],
                 "clients": [{"cpu_s": r["extra"]["cpu_s"],
@@ -603,6 +674,7 @@ async def serve(args, wl: dict, cfg: dict, device: dict, meter: CompileMeter,
                          window_s=reduced["window_s"])
     out["breakdown"] = {"device_ops": reduced["device_ops"],
                         "idle_gaps": reduced["idle_gaps"]}
+    out["compared"] = compared
     return out, correct
 
 
@@ -655,6 +727,9 @@ def measure(args, wl: dict, cfg: dict, codec: str, tmp: str,
     out, correct = asyncio.run(
         serve(args, wl, cfg, device, meter, tmp, children))
     print(json.dumps(out), flush=True)
+    for name, c in out["compared"].items():
+        print(f"chipbench: compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     return 0 if correct else 1
 
 

@@ -27,7 +27,8 @@ import loadgen  # noqa: E402
 import peaks  # noqa: E402
 import trace_reduce  # noqa: E402
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
 def run_cell(workload: str, *extra: str, root: str = ROOT, trace: int = 0,
@@ -51,14 +52,29 @@ def manifest() -> dict:
 
 @pytest.mark.parametrize("cell", [w["name"] for w in manifest()["workloads"]])
 def test_rehearsal_prints_a_correct_last_line(cell):
-    rc, last, _ = run_cell(cell)
+    rc, last, lines = run_cell(cell)
     assert rc == 0 and set(last) == RESULT_KEYS
+    assert list(last)[-1] == "compared"   # each number beside its limit
+    assert all(c["value"] <= c["limit"] for c in last["compared"].values())
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] > 0
     assert last["device"]["platform"] == "cpu"  # never passes for a chip
-    want = {m["name"] for m in manifest()["end_to_end"]}
-    assert set(last["metrics"]) == want
+    # an end-to-end metric that lists its cells exists in those alone
+    want = {m["name"] for m in manifest()["end_to_end"]
+            if cell in m.get("workloads", [cell])}
+    assert set(last["metrics"]) == want and "setup_s" in want
     assert all(v["value"] > 0 for v in last["metrics"].values())
+    # the two populations: every request is in one of them, and a kind
+    # that marks no record hot reads the same numbers both ways
+    c = next(ln for ln in lines if ln.get("phase") == "clients")
+    assert 0 <= c["hot_requests"] <= c["requests_in_window"]
+    with open(os.path.join(BENCH, "workloads", cell + ".json")) as f:
+        marks = "hot_in_flight" in json.load(f)["params"]
+    assert (c["hot_requests"] > 0) == marks
+    if not marks:
+        assert c["latency_ms_cold"] == {
+            q: c["latency_ms"][q] for q in c["latency_ms_cold"]}
+        assert set(c["latency_ms_hot"].values()) == {None}
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in manifest()["workloads"]])
@@ -127,6 +143,29 @@ def test_open_loop_times_from_due_time_and_reports_lateness():
     assert extra["unsent"] > 0 and extra["due"] > len(c["due"])
 
 
+class HotSlotTraffic(SlowTraffic):
+    """Two callers; the kind says caller 1's requests are aimed at a hot
+    record."""
+    n_callers = 2
+
+    def hot_of(self, slot: int) -> int:
+        return slot
+
+
+def test_the_load_generator_asks_the_kind_which_requests_were_hot():
+    import time
+
+    async def go(traffic):
+        t0 = time.monotonic() + 0.05
+        rec, _ = await loadgen.drive(traffic, None, "closed", t0, 0.3,
+                                     rate=None, seed=7)
+        return rec.columns()
+    c = asyncio.run(go(HotSlotTraffic(0.02)))
+    assert 0.4 < c["hot"].mean() < 0.6 and set(c["hot"]) == {0.0, 1.0}
+    # a kind without ``hot_of`` marks nothing
+    assert not asyncio.run(go(SlowTraffic(0.02)))["hot"].any()
+
+
 def test_closed_loop_sends_the_next_request_on_the_reply():
     import time
 
@@ -184,6 +223,11 @@ def test_a_new_cell_config_and_metric_are_files_found_by_name(tmp_path):
                                seconds=2.0)
     assert rc == 0 and last["correct"] is True
     assert "engine.deferred_per_s" in last["metrics"]
+    # no manifest, no list to be on: a new cell reports every end-to-end metric
+    e2e = next(ln for ln in lines if ln.get("phase", "").startswith(
+        "end-to-end"))
+    assert {"calls_per_s", "latency_p50_ms", "latency_p95_ms",
+            "setup_s"} <= set(e2e)
     env = next(ln for ln in lines if ln.get("phase") == "environment")
     assert env["config"]["population"]["dense"] == 2000
     clients = next(ln for ln in lines if ln.get("phase") == "clients")

@@ -25,11 +25,11 @@ ADDED = sorted(
     "engine.complete_hop_ms engine.resolve_ms writebehind.flush_ms "
     "writebehind.gather_ms writebehind.write_ms writebehind.rows_per_flush "
     "writebehind.loop_share_pct writebehind.compiles_in_window "
-    "tick.compiles_in_window recovery.first_touch_ms recovery.share_pct "
+    "tick.compiles_in_window recovery.share_pct "
     "trace.unattributed_idle_pct silo.compiles_outside_stages "
     "gateway.msgs_per_read gateway.pump_loop_share_pct "
     "engine.resolve_loop_share_pct egress.flush_ms "
-    "egress.flush_loop_share_pct recovery.first_touch_share_pct".split())
+    "egress.flush_loop_share_pct".split())
 
 
 def ctx(**over) -> dict:

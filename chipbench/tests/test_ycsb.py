@@ -1,7 +1,7 @@
 """The ``ycsb_a_zipf`` cell, by hand (``pytest chipbench/tests``; tier-1
 collects ``tests/`` only): rehearsed end to end on the CPU, fault
-injection turns ``correct`` false, and the cell came in as files alone —
-no file the benchmark already had differs from the parent commit's.
+injection turns ``correct`` false, and the rule the cell came in under —
+a PR that is not of kind ``benchmark`` edits no file the benchmark had.
 """
 
 import json
@@ -14,19 +14,6 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
-
-NEW_FILES = {
-    "apps/ycsb.py", "references/ycsb.py", "traffic/ycsb_ops.py",
-    "configs/ycsb-1kb.json", "workloads/ycsb_a_zipf.json",
-    "tests/test_ycsb.py", "readers/histogram_mean_or_zero.py",
-    "layer_metrics/engine.deferred_share_pct.json",
-    "layer_metrics/engine.defer_wait_ms.json",
-    "layer_metrics/engine.claim_ms.json",
-    "layer_metrics/engine.read_share_pct.json",
-    "layer_metrics/wire.pickled_values_per_msg.json",
-    "layer_metrics/wire.payload_bytes_per_msg.json",
-}
-
 
 def run(*argv: str, timeout: float = 600.0):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -47,8 +34,9 @@ def test_rehearsal_end_to_end():
     assert rc == 0, err[-2000:]
     out = lines[-1]
     assert out["correct"] and out["failed"] == 0 and out["attempted"] > 100
+    # no latency_p95_ms here: the cell's own file leaves it out
     assert set(out["metrics"]) == {"calls_per_s", "latency_p50_ms",
-                                   "latency_p95_ms", "setup_s"}
+                                   "setup_s"}
     assert out["device"]["platform"] == "cpu"      # a rehearsal says so
     env = phase(lines, "environment")
     assert env["workload"]["client_procs"] == 2
@@ -108,36 +96,43 @@ def test_injected_fault_turns_correct_false(fault):
         else (c["rows"]["bad_rows"] > 0)
 
 
-def test_the_cell_came_in_as_files_alone():
-    """Against the parent commit: nothing the benchmark had is edited or
-    gone, and what is new under chipbench/ is this cell's."""
+def test_a_files_only_pr_edits_no_file_the_benchmark_had():
+    """Against the parent commit: a PR of any kind but ``benchmark`` may
+    add files under chipbench/ and entries to BENCHMARK.json, and edits or
+    deletes nothing the benchmark had (empty once the PR is the HEAD
+    commit). The kind is in the heading of ISSUE.md."""
     def git(*a: str) -> str:
         return subprocess.run(["git", *a], capture_output=True, text=True,
                               cwd=ROOT, check=True).stdout
     try:
-        parent = git("rev-parse", "HEAD").strip()
-        changed = git("diff", "--name-status", parent, "--", "chipbench")
-        untracked = git("ls-files", "--others", "--exclude-standard",
-                        "--", "chipbench")
+        changed = git("diff", "--name-status", "HEAD", "--", "chipbench")
+        before = json.loads(git("show", "HEAD:BENCHMARK.json"))
     except (subprocess.CalledProcessError, FileNotFoundError) as e:
         pytest.skip(f"not a git checkout: {e}")
-    new = {x.split("\t")[1] for x in changed.splitlines()
-           if x.startswith("A")} | set(untracked.split())
+    try:
+        with open(os.path.join(ROOT, "ISSUE.md")) as f:
+            heading = f.readline()
+    except FileNotFoundError:
+        heading = ""
+    if "[benchmark]" in heading:
+        pytest.skip("a PR of kind benchmark may edit the benchmark")
     edited = [x for x in changed.splitlines() if not x.startswith("A")]
     assert not edited, edited
-    if new:   # (empty once the PR is the HEAD commit)
-        assert {os.path.relpath(x, "chipbench") for x in new} == NEW_FILES
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    assert [w["name"] for w in bench["workloads"]][-1] == "ycsb_a_zipf"
-    assert [c["name"] for c in bench["configs"]][-1] == "ycsb-1kb"
-    assert bench["workloads"][0]["name"] == "presence_heartbeat"
-    for m in sorted(NEW_FILES):
-        if m.startswith("layer_metrics/"):
-            with open(os.path.join(BENCH, m)) as f:
-                d = json.load(f)
-            entry = next(e for e in bench["per_layer"]
-                         if e["name"] == d["name"])
-            assert entry.get("workloads", "all") == d["cells"]
-            for k in ("unit", "better", "source", "layer", "moves"):
-                assert entry[k] == d[k]
+        now = json.load(f)
+
+    def grown(was: list, is_now: list) -> bool:
+        """Entries only added; a metric's ``workloads`` list may gain the
+        new cells' names at its end; nothing else of an entry moves."""
+        def same(a: dict, b: dict) -> bool:
+            la, lb = a.get("workloads"), b.get("workloads")
+            return {**a, "workloads": None} == {**b, "workloads": None} \
+                and (la is None) == (lb is None) \
+                and (la is None or lb[:len(la)] == la)
+        return len(is_now) >= len(was) and all(map(same, was, is_now))
+
+    for k in ("command", "paths", "run_seconds"):
+        assert now[k] == before[k], k
+    assert len(now["end_to_end"]) == len(before["end_to_end"])
+    for k in ("end_to_end", "configs", "workloads", "per_layer"):
+        assert grown(before[k], now[k]), k

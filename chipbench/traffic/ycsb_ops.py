@@ -21,6 +21,22 @@ on its own records. With equal rates per process the union is the
 source's distribution. Every seed gives the same owners and the same hot
 records; operations, fields and values change.
 
+**Hot records.** A record is *hot* where the expected number of the cell's
+in-flight operations on it is at least ``hot_in_flight``:
+``key_mass()[k] * callers >= hot_in_flight``, ``callers`` the cell's callers
+over all processes. The set follows from the source's distribution and the
+cell's own traffic alone, never from the program, and is the same for every
+seed and every client process (``about()`` reports its size and mass, the
+harness prints them in its ``set-up`` record and checks that the children
+agree). ``hot_of(slot)`` says whether the operation a caller's ``request``
+just returned was aimed at a hot record (the load generator asks right
+after the reply; a kind without the method marks nothing). Without
+``hot_in_flight`` no record is hot. In a closed loop a hot operation queues behind the
+record's other callers (one message an actor a tick and method), so its
+latency is the record's queue length over its service rate; the harness
+counts it in every end-to-end metric and reports the two populations'
+latencies apart beside them.
+
 **Judging.** Replies are judged by ``references/ycsb.py`` through the
 ``ver`` each carries (see there); a read may not report a ``ver`` below
 the highest acknowledged when it was sent. A failed or timed-out update
@@ -59,7 +75,7 @@ failed or was wrong since ``ready`` booked on it. In flight is ``callers``
 throughout.
 
 Parameters (the workload file's ``params``): ``grain``,
-``read_proportion``, ``zipfian_constant``, ``warm_ops``.
+``read_proportion``, ``zipfian_constant``, ``warm_ops``, ``hot_in_flight``.
 """
 
 import asyncio
@@ -175,6 +191,9 @@ class Traffic:
                                      p["zipfian_constant"])
         mass = self.zipf.key_mass()
         self.owner = deal(mass, ctx["n_children"])
+        self.hot = mass * self.total_callers >= p.get("hot_in_flight",
+                                                      float("inf"))
+        self.hot_mass = float(mass[self.hot].sum())
         self.draws = 2 * BLOCK * ctx["n_children"]  # to keep ~2 BLOCKs
         # this process's records, hottest first (the warm-up's bursts)
         mine = np.flatnonzero(self.owner == self.child)
@@ -182,6 +201,7 @@ class Traffic:
         self.rngs = [np.random.default_rng([ctx["seed"], g])
                      for g in ctx["callers"]]
         self.blocks: list = [[] for _ in self.rngs]
+        self.slot_hot = [0] * len(self.rngs)  # a caller's operation in flight
         self.grains: dict = {}
         # the pre-roll: a caller's loop until the window takes it over
         self.preroll: dict = {}    # slot -> task
@@ -194,6 +214,18 @@ class Traffic:
     @property
     def n_callers(self) -> int:
         return len(self.rngs)
+
+    def about(self) -> dict:
+        """What of the traffic follows from the data files alone (the same
+        in every process and for every seed)."""
+        return {"hot_records": int(self.hot.sum()),
+                "hot_mass_pct": 100.0 * self.hot_mass}
+
+    def hot_of(self, slot: int) -> int:
+        """1 where the caller's last operation was aimed at a hot record (a
+        caller has one operation in flight, so after ``request`` returns
+        this is the operation it returned)."""
+        return self.slot_hot[slot]
 
     def _draw(self, rng) -> list:
         """BLOCK operations: (key, is_read, field, value); the keys are
@@ -288,6 +320,7 @@ class Traffic:
         if not block:
             block.extend(self._draw(self.rngs[slot]))
         key, is_read, field, value = block.pop()
+        self.slot_hot[slot] = int(self.hot[key])
         grain = self.grains.get(key)
         if grain is None:
             grain = self.grains[key] = client.get_grain(self.cls, key)
