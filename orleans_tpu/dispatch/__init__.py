@@ -7,4 +7,4 @@ from .hosting import add_vector_grains  # noqa: F401
 from .replicated import ReplicatedWorkerHost, replicated_worker  # noqa: F401
 from .reshard import reshard_dense  # noqa: F401
 from .table import ShardedActorTable  # noqa: F401
-from .vector_grain import VectorGrain, actor_method  # noqa: F401
+from .vector_grain import VectorGrain, actor_method, sends  # noqa: F401

@@ -41,12 +41,13 @@ from jax.sharding import PartitionSpec as P
 
 from ..compile_cache import ensure_compile_cache
 from ..core.ids import GrainId
+from ..observability.stats import EXCHANGE_STATS as _EXCH
 from ..observability.stats import INGEST_STATS as _INGEST
 from ..observability.stats import MESH_STATS as _MESH
 from ..observability.stats import NO_SPAN, StageSpan
 from ..parallel.mesh import SILO_AXIS, make_mesh
 from .table import ShardedActorTable
-from .vector_grain import ActorMethod, VectorGrain
+from .vector_grain import ActorMethod, SendingMethod, VectorGrain
 
 _QUEUE_WAIT = _INGEST["queue_wait"]
 _TICK = _INGEST["tick"]
@@ -60,7 +61,7 @@ _JOB_SLOTS = _MESH["slots"]
 # the sink's keys that replay as counter increments, not observations
 _COUNTERS = frozenset(
     (_MESSAGES, _TRANSFER_JOBS, _TRANSFER_PUTS, _TRANSFER_BYTES,
-     _JOB_LANES, _JOB_MAX_SHARD_LANES, _JOB_SLOTS))
+     _JOB_LANES, _JOB_MAX_SHARD_LANES, _JOB_SLOTS, *_EXCH.values()))
 _WORKER_QUEUE = "engine.worker_queue.seconds"
 _DEFERRED = "engine.deferred"               # counter: msgs deferred >= once
 _DEFER_WAIT = "engine.defer_wait.seconds"   # first deferral -> claimed
@@ -91,6 +92,15 @@ MIN_BUCKET = 8
 # shares the interpreter lock with it, uses. A constant, not an option:
 # nothing a deployment knows changes it.
 _HANDOFF_DEPTH = 1
+
+# The most messages one source shard sends one destination shard in one
+# pass of a sending job's exchange (``_exchange``): a pass's receive tick
+# is ``n_shards * capacity`` lanes, and with rows of tens of KB its
+# gather and scatter temporaries are that many rows — 4,096 lanes of a
+# 32 KB row are 134 MB a shard. What does not fit goes in the next pass.
+_EXCHANGE_CAP = 1024
+# the exchange's own payload field: "initialise this receiver's row first"
+_FRESH = "__fresh__"
 
 
 def _bucket(n: int) -> int:
@@ -354,10 +364,13 @@ class _TickJob:
     loop-confined). ``tick`` is ``rt.ticks`` at the claim (the unit of
     work the stage spans name); ``t_hand`` is the perf_counter stamp of
     the last hand-off between threads (submit, then completion), 0.0
-    with metrics off."""
+    with metrics off. ``outbox`` is a sending method's messages between
+    its tick and their delivery (``_Outbox``; None for every other job,
+    and again once delivered); ``host`` keeps the tick's results across
+    the exchange's stages."""
 
     __slots__ = ("cls", "method", "ready", "trace", "per_shard", "span",
-                 "stats", "tick", "t_hand")
+                 "stats", "tick", "t_hand", "outbox", "host")
 
     def __init__(self, cls, method, ready, trace=False, tick=0):
         self.cls = cls
@@ -369,6 +382,42 @@ class _TickJob:
         self.stats: list = []
         self.tick = tick
         self.t_hand = 0.0
+        self.outbox: _Outbox | None = None
+        self.host = None
+
+
+class _Outbox:
+    """What one sending job's tick emitted, until it is delivered:
+    ``keys`` / ``valid`` ``[n_shards, B * K]`` on the host (lane
+    ``i * K + j`` is message j of the shard's call i), ``keys_dev`` and
+    ``payload`` the same lanes still on the device. ``failed`` maps
+    ``(shard, call)`` to the error of a sender one of whose messages
+    cannot be delivered; ``fresh`` is the loop's verdict
+    (``_activate_receivers``): the receivers whose rows the delivery
+    has to initialise first, None until it is given."""
+
+    __slots__ = ("cls", "method", "fanout", "keys", "valid", "keys_dev",
+                 "payload", "failed", "fresh", "delivered")
+
+    def __init__(self, cls, method, fanout, keys, valid, keys_dev, payload):
+        self.cls = cls
+        self.method = method
+        self.fanout = fanout
+        self.keys = keys
+        self.valid = valid
+        self.keys_dev = keys_dev
+        self.payload = payload
+        self.failed: dict = {}
+        self.fresh: np.ndarray | None = None
+        self.delivered = 0
+
+    def fail(self, bad: np.ndarray, error_of) -> None:
+        """Take the lanes ``bad`` out of the outbox; the sender of each
+        fails with ``error_of(key)`` (its first such message's)."""
+        self.valid = self.valid & ~bad
+        for s, lane in np.argwhere(bad).tolist():
+            self.failed.setdefault((s, lane // self.fanout),
+                                   error_of(int(self.keys[s, lane])))
 
 
 def _worker_main(ref: "weakref.ref[VectorRuntime]",
@@ -476,6 +525,14 @@ class VectorRuntime:
         # stateless-worker (mesh-replicated) hosts per class — see
         # dispatch.replicated (StatelessWorkerPlacement.cs:6 on device)
         self._replicated_hosts: dict[type, Any] = {}
+        # first-touch recovery for the receivers of device-made messages
+        # (``@sends``), set by dispatch.hosting where the silo has
+        # write-behind storage: ``fn(cls, keys, then)`` rehydrates the
+        # keys that storage holds and calls ``then(errors)`` on the loop
+        # when every read has landed (Dispatcher.recover_receivers);
+        # None = a receiver nothing has touched starts from
+        # initial_state
+        self.receiver_recovery = None
         # the tick worker: claimed batches run on a dedicated per-engine
         # thread, started lazily by the first claimed job — staging fill,
         # operand upload, kernel dispatch and the host materialize sync
@@ -596,6 +653,28 @@ class VectorRuntime:
                     self.tables[cls].enable_hit_tracking()
                 if self.track_cost:
                     self.tables[cls].enable_cost_tracking()
+                for m in self.tables[cls].methods.values():
+                    if isinstance(m, SendingMethod):
+                        self._bind_sender(cls, m)
+
+    def _bind_sender(self, cls: type, m: SendingMethod) -> None:
+        """Resolve a sending method's destination at registration: the
+        class gets its table now (never from the tick worker), and the
+        destination method's declared arguments are the payload."""
+        if m.dest_class is None:
+            m.dest_class = cls
+        self.register(m.dest_class)
+        dest = self.tables[m.dest_class].methods.get(m.dest_method)
+        if dest is None or dest.args_schema is None or dest.read_only \
+                or isinstance(dest, SendingMethod):
+            raise TypeError(
+                f"{cls.__name__}.{m.name} sends to "
+                f"{m.dest_class.__name__}.{m.dest_method}, which has to be "
+                f"a writing @actor_method with a declared args schema")
+        if {_FRESH, "__key__"} & set(dest.args_schema):
+            raise TypeError(f"{m.dest_class.__name__}.{m.dest_method}: "
+                            f"argument names {_FRESH!r} and '__key__' are "
+                            f"the exchange's own")
 
     def table(self, cls: type) -> ShardedActorTable:
         if cls not in self.tables:
@@ -923,8 +1002,10 @@ class VectorRuntime:
     def _run_job(self, job: _TickJob, offloop: bool = True) -> None:
         """One claimed batch under the tick fence, then its completion:
         on the worker, posted back to the loop; with the lever off, on
-        the loop and called in place."""
-        host = err = None
+        the loop and called in place. A sending job comes here twice:
+        for its tick, and — once the loop has said which receivers are
+        fresh (``_activate_receivers``) — for its exchange."""
+        host, err = job.host, None
         st = self.stats
         wait = None
         try:
@@ -942,9 +1023,13 @@ class VectorRuntime:
             with self._fence:
                 if wait is not None:
                     wait.close()
-                job.per_shard, host, job.span = self._execute_batch(
-                    job.cls, job.method, job.ready, job.stats,
-                    trace_roll=job.trace, tick=job.tick)
+                if job.outbox is None:
+                    job.per_shard, host, job.span = self._execute_batch(
+                        job.cls, job.method, job.ready, job.stats,
+                        trace_roll=job.trace, tick=job.tick, job=job)
+                    job.host = host
+                else:
+                    self._exchange(job)
         except BaseException as e:  # noqa: BLE001 — futures fail loop-side
             err = e
         if st is not None:
@@ -1046,6 +1131,19 @@ class VectorRuntime:
             # resolving futures and replaying the worker's observations
             # is tick scheduling work on the loop, like the claim
             lp.set_category("tick_schedule")
+        ob = job.outbox
+        if err is None and ob is not None and ob.fresh is None:
+            # a sending job back from its tick: its messages wait for
+            # the loop's word on their receivers, then it returns to the
+            # worker for the exchange; it stays in flight meanwhile
+            try:
+                if st is not None and job.t_hand:
+                    st.observe(_COMPLETE_HOP,
+                               time.perf_counter() - job.t_hand)
+                self._activate_receivers(job)
+                return
+            except BaseException as e:  # noqa: BLE001 — fails the job
+                err = e
         try:
             if st is not None and job.t_hand:
                 # the hop: this callback waited behind whatever the loop
@@ -1082,6 +1180,14 @@ class VectorRuntime:
                         p.future.set_exception(err)
             else:
                 self._record_tick_span(job.span, job.ready)
+                if ob is not None:
+                    # a sender one of whose messages could not be
+                    # delivered fails alone; the others' replies follow
+                    for (s, i), e in ob.failed.items():
+                        fut = job.per_shard[s][i].future
+                        if fut is not None and not fut.done():
+                            fut.set_exception(e)
+                    self.messages_processed += ob.delivered
                 self._resolve_batch(job.ready, job.per_shard, host,
                                     job.tick, job.cls, job.method)
         except BaseException as e2:  # noqa: BLE001 — fail futures, not loop
@@ -1264,7 +1370,8 @@ class VectorRuntime:
         self.messages_processed += len(ready)
 
     def _execute_batch(self, cls: type, method: str, ready: list[_Pending],
-                       sink: list, trace_roll: bool = False, tick: int = 0):
+                       sink: list, trace_roll: bool = False, tick: int = 0,
+                       job: _TickJob | None = None):
         """Staging fill → operand upload (one buffer, one transfer) →
         kernel dispatch → host materialize sync for one claimed,
         conflict-free batch, on the tick worker (or in place on the
@@ -1278,7 +1385,10 @@ class VectorRuntime:
         batch leaves its open span to the caller's ``StageSpan.unwind``.
         Returns ``(per_shard, host_results, span_timing)`` where
         ``span_timing`` is ``(name, wall_start, duration)`` for a sampled
-        tick (recorded by the caller on the loop) or None."""
+        tick (recorded by the caller on the loop) or None. A sending
+        method's outbox is read back inside the sync stage (the
+        destination keys and the valid mask, not the payload) and left
+        on ``job.outbox`` where it holds a message."""
         st = self.stats
         led = self.ledger
         now_mono = batch_wall = 0.0
@@ -1393,7 +1503,7 @@ class VectorRuntime:
                 span_name = f"tick {cls.__name__}.{method}"
                 span_start = time.time()
                 t_span0 = time.perf_counter()
-            new_state, results = kernel(*kernel_args)
+            new_state, results, *sent = kernel(*kernel_args)
             if st is not None:
                 stage.close()
                 stage = StageSpan(st, "ingest.tick.sync", sink, tick=tick)
@@ -1439,6 +1549,8 @@ class VectorRuntime:
             # there is to wait for: the packed buffer.)
             jax.block_until_ready(
                 kernel_args[1] if m.read_only else new_state)
+        if sent:
+            job.outbox = self._read_outbox(m, sent[0])
         if st is not None:
             # tick closes AFTER the host transfer for the same reason the
             # span timing does: jax dispatch is async, and the np.asarray
@@ -1709,6 +1821,25 @@ class VectorRuntime:
         """Zero-copy tick for callers that already hold device-layout
         [n_shards, B] batches (the transport layer / benchmarks). Returns
         the raw [n_shards, B, ...] result pytree without host transfer."""
+        results = self._device_tick(grain_class, method, slots_b, khash_b,
+                                    fresh_b, valid_b, args_b)
+        self.ticks += 1
+        if isinstance(valid_b, np.ndarray):
+            self.messages_processed += int(valid_b.sum())
+        else:
+            # valid mask lives on device (exchange flows): counting it
+            # would force a sync — track lanes separately so
+            # messages_processed stays an honest delivered count
+            self.exchange_lanes += int(valid_b.shape[0] * slots_b.shape[1])
+        return results
+
+    def _device_tick(self, grain_class: type, method: str,
+                     slots_b, khash_b, fresh_b, valid_b, args_b,
+                     sink: list | None = None):
+        """The tick of :meth:`call_batch_device` without its counting:
+        fence, kernel, commit, telemetry. ``sink`` is a job's deferred-
+        stats list when the caller is the tick worker (the ledger is
+        loop-confined: its charge replays there)."""
         tbl = self.table(grain_class)
         m = self.method_of(grain_class, method)
         B = slots_b.shape[1]
@@ -1728,19 +1859,14 @@ class VectorRuntime:
             # host-synced just to count); per-slot precision comes from
             # record_cost, whose masked scatter stays all-device too
             wall = max(0.0, time.perf_counter() - t_led)
-            led.charge_tick(
-                (grain_class.__name__, method, int(slots_b.shape[0] * B),
-                 wall, ()))
+            payload = (grain_class.__name__, method,
+                       int(slots_b.shape[0] * B), wall, ())
+            if sink is not None:
+                sink.append((_LEDGER, payload))
+            else:
+                led.charge_tick(payload)
             if self.track_cost:
                 tbl.record_cost(slots_b, valid_b, int(wall * 1e6))
-        self.ticks += 1
-        if isinstance(valid_b, np.ndarray):
-            self.messages_processed += int(valid_b.sum())
-        else:
-            # valid mask lives on device (exchange flows): counting it
-            # would force a sync — track lanes separately so
-            # messages_processed stays an honest delivered count
-            self.exchange_lanes += int(valid_b.shape[0] * B)
         return results
 
     # ------------------------------------------------------------------
@@ -1823,11 +1949,14 @@ class VectorRuntime:
         Returns (results, applied): results [n_shards, L, ...] per-lane
         method results (junk on unapplied lanes), applied [n_shards, L].
 
-        Write-behind dirty tracking does NOT see exchange-applied writes
-        (the applied keys live on device; syncing them to host every tick
-        would defeat the all-device pipeline) — device-resident message
-        flows should persist via scheduled table checkpoints
-        (``add_vector_grains(checkpoint_dir=...)``) instead.
+        Write-behind dirty tracking is the CALLER's: this API never
+        brings the applied keys to the host. The callers that hold the
+        keys on the host mark them (``_broadcast_chunk``, and the served
+        exchange of a sending method, ``_exchange``, which marks every
+        delivered receiver dirty before its sender is acknowledged); a
+        flow that keeps its keys on the device persists through
+        scheduled table checkpoints
+        (``add_vector_grains(checkpoint_dir=...)``).
         """
         tbl = self.table(dest_class)
         self.method_of(dest_class, method)  # validate the method exists
@@ -1843,8 +1972,8 @@ class VectorRuntime:
                                              khash, fresh, applied, args)
             return results, applied
 
-        slots, applied, khash = self._apply_resolver(dest_class, False)(
-            recv_keys, recv_valid)
+        slots, applied, khash, _rest, _counts = self._apply_resolver(
+            dest_class, False)(recv_keys, recv_valid)
         fresh = jnp.zeros_like(applied)
         results = self.call_batch_device(dest_class, method, slots, khash,
                                          fresh, applied, args)
@@ -1853,7 +1982,10 @@ class VectorRuntime:
     def _apply_resolver(self, dest_class: type, sparse: bool):
         """The cached jitted slot-resolution half of
         :meth:`apply_received` (key → local slot + first-delivery dedup
-        mask). Cached per (class, regime, capacity, shard layout): a
+        mask; the dense regime also returns the lanes still to apply and,
+        per shard, how many it applied and left, so that a round costs
+        its caller one small read). Cached per (class, regime, capacity,
+        shard layout): a
         fresh ``jax.jit(local)`` per call would RETRACE on every
         delivery round — the repeated-fan-out hot path
         (broadcast_actors' dedup rounds) pays a full compile per round
@@ -1908,18 +2040,188 @@ class VectorRuntime:
                 first = rank_dense_keys(jnp.where(v, slot,
                                                   capacity + 1)) == 0
                 applied = v & first
+                rest = v & ~applied
                 slot = jnp.where(applied, slot, capacity)
+                counts = jnp.stack([jnp.sum(applied), jnp.sum(rest)])
                 return slot[None], applied[None], \
-                    (k & 0x7FFFFFFF).astype(jnp.int32)[None]
+                    (k & 0x7FFFFFFF).astype(jnp.int32)[None], \
+                    rest[None], counts.astype(jnp.int32)[None]
 
             if n_shards > 1:
                 spec = P(SILO_AXIS)
                 local = jax.shard_map(
                     local, mesh=self.mesh, in_specs=(spec, spec),
-                    out_specs=(spec, spec, spec), check_vma=False)
+                    out_specs=(spec,) * 5, check_vma=False)
         cached = jax.jit(local)
         self._kernel_cache[key] = cached
         return cached
+
+    # ------------------------------------------------------------------
+    # Served grain-to-grain calls: a sending method's job (``@sends``)
+    # is its tick, the loop's word on its receivers, and its exchange —
+    # route / apply_received's own machinery, under the tick fence,
+    # before the senders' replies resolve.
+    # ------------------------------------------------------------------
+    def _read_outbox(self, m: SendingMethod, sent) -> _Outbox | None:
+        """Tick worker, inside the tick's sync: the outbox's destination
+        keys and valid mask come to the host (the planner of the passes,
+        the dirty marks and the counters need them; the payload stays on
+        the device). A message for a key its class never provisioned
+        cannot be delivered: it leaves the outbox and fails its sender.
+        None where the job sent nothing."""
+        keys_dev, valid_dev, payload = sent
+        keys, valid = np.asarray(keys_dev), np.asarray(valid_dev)
+        if not valid.any():
+            return None
+        ob = _Outbox(m.dest_class, m.dest_method, m.fanout, keys, valid,
+                     keys_dev, payload)
+        bad = valid & ((keys < 0) | (keys >= self.tables[m.dest_class].dense_n))
+        if bad.any():
+            ob.fail(bad, lambda key: KeyError(
+                f"{m.name}: no {m.dest_class.__name__} with key {key} is "
+                f"provisioned; the message was not delivered"))
+        return ob
+
+    def _activate_receivers(self, job: _TickJob) -> None:
+        """Loop-side stage of a sending job, between its tick and its
+        exchange: which receivers does the delivery have to initialise?
+        Activation is the loop's bookkeeping (``dense_active``,
+        ``uninit``, the recovery passes in flight), and the worker runs
+        jobs in the order the loop hands them over — so the verdict is
+        given here and the exchange is queued behind every job claimed
+        before it. A receiver nothing has touched first gets its
+        first-touch recovery pass (``receiver_recovery``, where the silo
+        has storage); one the pass did not find, or whose first write
+        waits unclaimed, starts from ``initial_state`` on delivery. A
+        receiver whose read failed fails the senders that message it."""
+        ob = job.outbox
+        tbl = self.tables[ob.cls]
+        st = self.stats
+        span = StageSpan(st, "exchange.activate", nest=False,
+                         tick=job.tick) if st is not None else None
+        uniq = np.unique(ob.keys[ob.valid])
+        untouched = uniq[~tbl.dense_active[uniq]]
+
+        def verdict(errors: dict) -> None:
+            try:
+                decide(errors)
+            except BaseException as e:  # noqa: BLE001 — a late pass's
+                # callback has no caller to raise to: the job fails
+                ob.fresh = np.zeros(0, np.int64)
+                self._complete_job(job, job.host, e)
+
+        def decide(errors: dict) -> None:
+            if errors:
+                ob.fail(ob.valid & np.isin(ob.keys, np.fromiter(
+                    errors, np.int64, len(errors))), errors.get)
+            live = np.unique(ob.keys[ob.valid])
+            waiting = tbl.uninit.intersection(live.tolist()) \
+                if tbl.uninit else ()
+            fresh = live[~tbl.dense_active[live]]
+            if waiting:
+                # their first write is enqueued and unclaimed: the
+                # delivery initialises the row, the write finds it made
+                tbl.uninit.difference_update(waiting)
+                fresh = np.union1d(fresh, np.fromiter(
+                    waiting, np.int64, len(waiting)))
+            tbl.mark_dense_active(fresh)
+            ob.fresh = fresh
+            if span is not None:
+                span.close()
+                job.t_hand = time.perf_counter()
+            if self.offloop_tick:
+                self._ensure_worker()
+                self._worker_q.put(job)
+            else:
+                self._run_job(job, offloop=False)
+
+        if untouched.size and self.receiver_recovery is not None:
+            self.receiver_recovery(ob.cls, untouched.tolist(), verdict)
+        else:
+            verdict({})
+
+    def _exchange(self, job: _TickJob) -> None:
+        """Tick worker, under the fence: deliver a sending job's outbox.
+        The host plans PASSES from the keys it read back: a source
+        shard's messages for one destination shard, in lane order,
+        ``capacity`` a pass — so ``route`` (one ``all_to_all`` a pass)
+        never meets an overflow, and what is past the capacity is sent in
+        the next pass, not dropped. A pass's received lanes are applied
+        as the destination method in ROUNDS: the first message for each
+        actor wins the round (``_apply_resolver``'s dedup), the others go
+        again, so two messages for one actor land in successive ticks, in
+        lane order. A fresh receiver's row is initialised by the first
+        round of the first pass that reaches it. Every delivered receiver
+        is marked dirty for the write-behind. A count that does not add
+        up fails the job: nothing is dropped silently."""
+        ob = job.outbox
+        st, sink = self.stats, job.stats
+        tbl = self.tables[ob.cls]
+        n, per = tbl.n_shards, max(tbl.dense_per_shard, 1)
+        keys, valid = ob.keys, ob.valid
+        span = StageSpan(st, "exchange", sink, tick=job.tick) \
+            if st is not None else None
+        lanes = keys.shape[1]
+        capacity = min(_EXCHANGE_CAP, max(MIN_BUCKET, lanes // (2 * n)))
+        dest = keys // per
+        rank = np.zeros(keys.shape, np.int64)
+        for d in range(n):
+            to_d = valid & (dest == d)
+            rank[to_d] = (np.cumsum(to_d, axis=1) - 1)[to_d]
+        in_pass = rank // capacity
+        sent = int(valid.sum())
+        fresh_left = ob.fresh
+        resolve = self._apply_resolver(ob.cls, False)
+        rounds = dropped = delivered = 0
+        n_passes = int(in_pass[valid].max()) + 1 if sent else 0
+        for p in range(n_passes):
+            mask = valid & (in_pass == p)
+            fresh = mask & np.isin(keys, fresh_left)
+            with StageSpan(st, "exchange.route", sink, tick=job.tick) \
+                    if st is not None else NO_SPAN:
+                recv_keys, recv, left, drops = self.route(
+                    ob.cls, ob.keys_dev,
+                    {**ob.payload, _FRESH: tbl._put(fresh)},
+                    tbl._put(mask), capacity=capacity)
+                recv_fresh = recv.pop(_FRESH)
+            with StageSpan(st, "exchange.apply", sink, tick=job.tick) \
+                    if st is not None else NO_SPAN:
+                got = 0
+                got, init = 0, recv_fresh
+                while True:
+                    slots, applied, khash, left, counts = resolve(
+                        recv_keys, left)
+                    self._device_tick(ob.cls, ob.method, slots, khash,
+                                      init, applied, recv, sink)
+                    rounds += 1
+                    done, more = np.asarray(counts).sum(axis=0).tolist()
+                    got += done
+                    if not more or not done:
+                        break
+                    if init is recv_fresh:   # only the first round does
+                        init = jnp.zeros_like(applied)
+            delivered += got
+            dropped += int(np.asarray(drops).sum())
+            if got != int(mask.sum()):
+                raise RuntimeError(
+                    f"{job.cls.__name__}.{job.method}: pass {p} of the "
+                    f"exchange applied {got} of {int(mask.sum())} messages "
+                    f"({dropped} reported dropped)")
+            if fresh.any():
+                fresh_left = np.setdiff1d(fresh_left, keys[fresh])
+        self._mark_dirty(ob.cls, np.unique(keys[valid]))
+        ob.delivered = delivered
+        if st is not None:
+            span.close()
+            src = np.arange(n)[:, None]
+            for name, v in (
+                    ("jobs", 1), ("sent", sent), ("delivered", delivered),
+                    ("cross_shard", int((valid & (dest != src)).sum())),
+                    ("rounds", rounds),
+                    ("lanes", n_passes * n * n * capacity),
+                    ("activated", int(ob.fresh.size)),
+                    ("dropped", dropped)):
+                sink.append((_EXCH[name], v))
 
     # ------------------------------------------------------------------
     # Bulk-population collectives (MapReduce over actors — ROADMAP's
@@ -2482,6 +2784,12 @@ class VectorRuntime:
         init = cls.initial_state
         mesh = tbl.mesh
         read_only = m.read_only
+        sending = isinstance(m, SendingMethod)
+        if sending and layout is None:
+            raise NotImplementedError(
+                f"{cls.__name__}.{method} sends messages: it is served "
+                f"through call / call_group (a client's call), not "
+                f"through the bulk and device-batch entry points")
 
         def make_access(slots_l):
             """(read, write_at) for this tick's slot addressing. The
@@ -2513,6 +2821,8 @@ class VectorRuntime:
             rows = jax.tree_util.tree_map(
                 lambda ir, r: sel(fresh_l, ir, r), init_rows, rows)
             new_rows, results = jax.vmap(handler)(rows, args_l)
+            if sending:
+                results, sent = results
             if read_only:
                 # no state output: a table passed through a jit that
                 # does not donate it comes back as a COPY, a whole-table
@@ -2525,8 +2835,18 @@ class VectorRuntime:
                     state_l, new_rows, rows)
                 out_state = jax.tree_util.tree_map(
                     lambda a: a[None], new_state_l)
-            return out_state, jax.tree_util.tree_map(
-                lambda a: a[None], results)
+            out = (out_state, jax.tree_util.tree_map(
+                lambda a: a[None], results))
+            if sending:
+                # the outbox, [B, K, ...] a leaf, flattened to the
+                # exchange's lanes: lane i * K + j is message j of call i,
+                # and an idle call's messages are no messages
+                okeys, ovalid, payload = sent
+                ovalid = ovalid & valid_l[:, None]
+                out += (jax.tree_util.tree_map(
+                    lambda a: a.reshape(-1, *a.shape[2:])[None],
+                    (okeys.astype(jnp.int32), ovalid, payload)),)
+            return out
 
         if scan_rounds:
             import jax.lax as lax
@@ -2611,7 +2931,8 @@ class VectorRuntime:
                 body, mesh=mesh,
                 in_specs=(spec, spec) if layout is not None else
                 (spec, spec, spec, spec, spec, pspec),
-                out_specs=(spec, P(None, SILO_AXIS) if scan_rounds else spec),
+                out_specs=(spec, P(None, SILO_AXIS) if scan_rounds else spec)
+                + (spec,) * sending,
                 check_vma=False)
         # else: single-shard — shard_map would be a no-op; plain jit over
         # the table's committed arrays runs on the mesh's one device

@@ -90,6 +90,10 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
         from ..storage.checkpoint import VectorStorageBridge
 
         silo.vector.enable_dirty_tracking()
+        # the receivers of device-made messages (@sends) recover from
+        # this storage on their first touch, as a client's calls do
+        silo.vector.receiver_recovery = \
+            lambda *a: silo.dispatcher.recover_receivers(*a)
         if not hasattr(silo, "vector_bridges"):
             silo.vector_bridges = {}
         for cls in grain_classes:

@@ -119,10 +119,14 @@ class ShardedActorTable:
         # device state: [n_shards, capacity+1, *shape]; row `capacity` is the
         # padding write sink
         self.state: dict[str, jax.Array] = {}
+        # allocated where it will live, a shard a device: zeros made on
+        # one device and then spread would hold the whole table there
+        # first (8.7 GB of 33 KB rows do not fit one chip's 16 GB once the
+        # slices' layout pads them)
         for name, (dtype, shape) in grain_class.STATE.items():
-            self.state[name] = self._put(
-                jnp.zeros((self.n_shards, self.capacity + 1, *shape),
-                          dtype=dtype))
+            self.state[name] = jnp.zeros(
+                (self.n_shards, self.capacity + 1, *shape), dtype=dtype,
+                device=self.sharding)
         # hot-spot telemetry: per-slot invocation counters, [n_shards,
         # capacity+1] with the sink row absorbing padding lanes. Off by
         # default (an extra scatter-add per tick is pure overhead unless a

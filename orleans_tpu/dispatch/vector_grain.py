@@ -17,6 +17,16 @@ A VectorGrain declares:
 * handler methods decorated ``@actor_method``: pure
   ``(state_row, args_row) -> (new_state_row, result)`` functions, vmapped
   by the engine. No Python side effects; jnp ops only.
+* optionally, handlers decorated ``@sends(...)``: a handler that also
+  EMITS MESSAGES to other device-tier actors from inside the tick —
+  ``(state_row, args_row) -> (new_state_row, result, (keys, valid,
+  payload))`` with ``keys`` the ``[K]`` dense keys of the destination
+  class, ``valid`` the ``[K]`` mask of the lanes that carry a message and
+  ``payload`` one ``[K, ...]`` array per field of the destination
+  method's arguments. The engine carries the job's outboxes over the
+  mesh exchange (one ``all_to_all``) and applies them as invocations of
+  the destination method before the sender's reply resolves
+  (``VectorRuntime._exchange``): grain-to-grain calls on the device tier.
 """
 
 from __future__ import annotations
@@ -25,7 +35,7 @@ from typing import Any, Callable
 
 import numpy as np
 
-__all__ = ["VectorGrain", "actor_method", "vector_methods"]
+__all__ = ["VectorGrain", "actor_method", "sends", "vector_methods"]
 
 
 class ActorMethod:
@@ -65,6 +75,43 @@ def actor_method(fn: Callable | None = None, *, args: dict | None = None,
         return ActorMethod(f, args, read_only)
     if fn is not None:
         return wrap(fn)
+    return wrap
+
+
+class SendingMethod(ActorMethod):
+    """A handler that emits up to ``fanout`` messages a call for
+    ``dest_method`` of ``dest_class`` (None: the declaring class).
+    ``fn`` keeps the two-value form every tool that traces a handler
+    expects, ``(new_state, (result, outbox))``: the outbox is part of
+    what the tick writes."""
+
+    def __init__(self, fn: Callable, args_schema: dict | None,
+                 dest_class: type | None, dest_method: str, fanout: int):
+        def paired(state, args):
+            new_state, result, outbox = fn(state, args)
+            return new_state, (result, outbox)
+
+        paired.__name__ = fn.__name__
+        super().__init__(paired, args_schema, read_only=False)
+        self.dest_class = dest_class
+        self.dest_method = dest_method
+        self.fanout = int(fanout)
+
+
+def sends(method: str, *, fanout: int, to: type | None = None,
+          args: dict | None = None):
+    """Mark a VectorGrain handler that messages other actors.
+
+    ``@sends("receive", fanout=K)``: every call may emit up to K messages
+    (K is static: the class's follower capacity) for ``to.<method>``
+    (``to=None``: the declaring class), whose declared ``args`` schema is
+    the payload's. The handler returns ``(new_state, result, (keys,
+    valid, payload))`` — see the module docstring. A sending method
+    writes (it cannot be read-only): its reply is acknowledged only
+    after every one of its messages has been applied.
+    """
+    def wrap(f: Callable) -> SendingMethod:
+        return SendingMethod(f, args, to, method, fanout)
     return wrap
 
 

@@ -22,7 +22,7 @@ __all__ = ["StatsRegistry", "Histogram", "QueueWaitTrend", "CallSiteStats",
            "StageSpan", "NO_SPAN", "observe_or_defer", "open_stage_registry",
            "close_stage_registry", "STAGES", "FLUSH_STATS", "RECOVER_STATS",
            "DISPATCH_STATS", "REBALANCE_STATS", "INGEST_STATS",
-           "INGEST_STAGES", "MESH_STATS", "EGRESS_STATS", "EGRESS_STAGES",
+           "INGEST_STAGES", "MESH_STATS", "EXCHANGE_STATS", "EGRESS_STATS", "EGRESS_STAGES",
            "RING_STATS", "RING_STAGES", "SLO_STATS", "SIZE_BOUNDS",
            "COUNT_BOUNDS"]
 
@@ -115,6 +115,20 @@ MESH_STATS = {
     "slots": "mesh.job.slots",                      # n_shards * B computed
 }
 
+# What a sending method's job (``@sends``) carried over the mesh exchange
+# after its tick (engine._exchange): counters, summed over jobs, stamped
+# through the job's sink and replayed on the loop like MESH_STATS (nothing
+# is stamped with metrics off). A job with an empty outbox stamps nothing.
+EXCHANGE_STATS = {
+    "jobs": "exchange.jobs",            # jobs whose outbox held a message
+    "sent": "exchange.sent",            # valid outbox lanes (messages made)
+    "delivered": "exchange.delivered",  # of them, applied at a receiver
+    "cross_shard": "exchange.cross_shard",  # dest shard != source shard
+    "rounds": "exchange.rounds",        # apply ticks (dedup rounds), all passes
+    "lanes": "exchange.lanes",          # slots the collective carried
+    "activated": "exchange.activated",  # receivers fresh-initialised
+    "dropped": "exchange.dropped",      # what ``route`` reported dropped
+}
 
 # Canonical egress-pipeline stage metrics — the response-path twin of
 # INGEST_STATS (the batched-egress pipeline: Dispatcher.send_response →
@@ -255,6 +269,13 @@ SLO_STATS = {
 #   flush                 one write-behind pass of hosting.flush_all
 #   flush.locate/.gather  VectorStorageBridge.flush under the tick fence
 #   flush.write           the gather of per-key storage writes
+#   exchange              a sending job's outbox -> last apply round done
+#                         (tick worker, under the fence; in it
+#                         exchange.route: one all_to_all a pass, and
+#                         exchange.apply: the dedup rounds of one pass)
+#   exchange.activate     loop-side: the job's receivers that were never
+#                         touched get their first-touch recovery pass and
+#                         are marked for fresh-init on delivery
 #   recover               one first-touch recovery pass: a decoded
 #                         read's fresh keys -> their one bridge.load done
 #                         (beside it RECOVER_STATS: the messages that met
@@ -270,7 +291,8 @@ STAGES = ("pump.batch", "engine.claim", "engine.worker_queue",
           "engine.fence_wait", "ingest.staging", "ingest.transfer",
           "ingest.tick.dispatch", "ingest.tick.sync", "engine.complete_hop",
           "engine.resolve", "egress.flush", "flush", "flush.locate",
-          "flush.gather", "flush.write", "recover")
+          "flush.gather", "flush.write", "recover", "exchange",
+          "exchange.route", "exchange.apply", "exchange.activate")
 
 FLUSH_STATS = {
     "rows": "vector.storage.flush.rows",   # COUNT_BOUNDS: rows per flush

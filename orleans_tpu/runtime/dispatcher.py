@@ -74,9 +74,12 @@ class _RecoveryPass:
     its ``keys`` and its ``recover`` span (None with metrics off),
     ``landed`` once its load is done, ``errors`` the keys whose read
     failed (key hash → exception), ``parked`` the calls that wait behind
-    it while it is in flight (method → items in arrival order)."""
+    it while it is in flight (method → items in arrival order),
+    ``after`` what else waits for it to land (callbacks taking the
+    pass: device-made messages for its keys,
+    ``Dispatcher.recover_receivers``)."""
 
-    __slots__ = ("keys", "span", "landed", "errors", "parked")
+    __slots__ = ("keys", "span", "landed", "errors", "parked", "after")
 
     def __init__(self, keys: list, span) -> None:
         self.keys = keys
@@ -84,6 +87,7 @@ class _RecoveryPass:
         self.landed = False
         self.errors: dict = {}
         self.parked: dict = {}
+        self.after: list = []
 
 
 # Bulk-population collective methods (MapReduce over actors): reserved
@@ -1005,6 +1009,56 @@ class Dispatcher:
         if rec.errors:
             self._fail_vector_items(rec.parked, rec.errors)
         self._call_vector_groups(rt, vcls, rec.parked)
+        for landed in rec.after:
+            landed(rec)
+
+    def recover_receivers(self, vcls: type, keys: list, then) -> None:
+        """First-touch recovery for the receivers of device-made messages
+        (the engine's ``receiver_recovery``, ``VectorRuntime.
+        _activate_receivers``): ``keys`` are dense keys nothing has
+        touched on this silo, about to be written by a delivery. Those
+        no pass has in flight are read in one pass of their own
+        (:meth:`_recover_keys`: found rows are scattered and marked
+        active; a client's call for one of them meanwhile parks behind
+        the pass as behind any other); ``then(errors)`` — key → the
+        exception its read raised — is called once every pass these keys
+        wait for has landed: here and now where nothing suspended."""
+        rt = self.silo.vector
+        bridge = getattr(self.silo, "vector_bridges", {}).get(vcls)
+        errors: dict = {}
+        if bridge is None:
+            then(errors)
+            return
+        recovering, tbl = self._vector_recoveries, rt.table(vcls)
+        waits: dict = {}   # passes in flight that hold one of the keys
+        fresh = []
+        for k in keys:
+            held = recovering.get((vcls, k)) if recovering else None
+            if held is not None:
+                waits[id(held)] = held
+            elif self._vector_key_is_fresh(tbl, k):
+                fresh.append(k)
+        if fresh:
+            rec = self._recover_keys(rt, vcls, bridge, fresh)
+            if rec.landed:
+                errors.update(rec.errors)
+            else:
+                waits[id(rec)] = rec
+        if not waits:
+            then(errors)
+            return
+        mine, left = set(keys), len(waits)
+
+        def landed(rec: _RecoveryPass) -> None:
+            nonlocal left
+            errors.update((k, e) for k, e in rec.errors.items()
+                          if k in mine)
+            left -= 1
+            if not left:
+                then(errors)
+
+        for rec in waits.values():
+            rec.after.append(landed)
 
     def receive_request(self, activation: ActivationData, msg: Message) -> None:
         """ReceiveRequest:262 — gate, then run or enqueue."""

@@ -138,10 +138,12 @@ def presence_1m():
     return _presence_runtime(make_mesh(1), N_PLAYERS)
 
 
-def _served_tick(rt, cls, method, B, state, sharding):
+def _served_tick(rt, cls, method, B, state, sharding, leaves_read=None):
     """The per-tick kernel as the served path launches it, compiled for
     the described chip: ``(state, packed)``, one operand buffer that the
-    kernel unpacks by the staging set's layout, both donated."""
+    kernel unpacks by the staging set's layout, both donated.
+    ``leaves_read``: how many state leaves the method reads, where a
+    read-only method leaves some out (the compiler drops those)."""
     from orleans_tpu.dispatch.engine import _packed_layout
 
     layout = _packed_layout(B, rt.method_of(cls, method).args_schema)
@@ -156,7 +158,7 @@ def _served_tick(rt, cls, method, B, state, sharding):
     assert not kwargs and len(args) == 2
     assert len(jax.tree_util.tree_leaves(args[1])) == 1
     entry = compiled.as_text().split("ENTRY", 1)[1]
-    assert entry.count(" parameter(") == len(state) + 1
+    assert entry.count(" parameter(") == (leaves_read or len(state)) + 1
     return compiled
 
 
@@ -320,3 +322,117 @@ def test_sharded_scan_and_exchange_compile_for_v5e_2x2(
     assert "tpu_custom_call" in text
     assert "all-to-all" in text
     assert compiled.memory_analysis().output_size_in_bytes < 1 << 30
+
+
+# ---------------------------------------------------------------------------
+# Chirper on the 2x2: 262,144 accounts of 33 KB, a publish's tick, its
+# exchange and the delivery rounds (chipbench/apps/chirper.py)
+# ---------------------------------------------------------------------------
+
+CHIRPER_ROWS = 65536 + 1     # a chip's accounts and the sink row
+
+
+@pytest.fixture(scope="module")
+def chirper(four_chips):
+    import importlib.util
+
+    from orleans_tpu.dispatch import VectorRuntime
+    from orleans_tpu.parallel import SILO_AXIS, make_mesh
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_compile_chirper", os.path.join(
+            os.path.dirname(__file__), "..", "chipbench", "apps",
+            "chirper.py"))
+    app = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(app)
+    Account = app.GRAINS["ChirperAccount"]
+    # the kernel builder needs the class and a mesh, not an 8.7 GB table
+    rt = VectorRuntime(mesh=make_mesh(4), capacity_per_shard=64)
+    rt.register(Account)
+    rt.table(Account).ensure_dense(256)
+    rt.mesh = rt.table(Account).mesh = four_chips
+    shard = NamedSharding(four_chips, P(SILO_AXIS))
+    state = {f: _struct((4, CHIRPER_ROWS, *shape), dtype, shard)
+             for f, (dtype, shape) in Account.STATE.items()}
+    return rt, Account, state, shard
+
+
+def _chip_table_bytes(state) -> int:
+    return sum(int(np.prod(v.shape[1:])) * v.dtype.itemsize
+               for v in state.values())
+
+
+@pytest.mark.parametrize("method,B", [("publish", 32), ("publish", 256),
+                                      ("get_received", 256)])
+def test_chirper_served_ticks_touch_rows_not_the_table(chirper, method, B):
+    """The 32 KB-row table (``timeline`` u8[32768], a 1,024-multiple) is
+    ticked in place on every chip: ``publish`` aliases the table and
+    writes its outbox beside it, ``get_received`` returns no table; no
+    copy of a chip's 2.1 GB leaf, and no collective — a client's call is
+    routed to its shard on the host."""
+    rt, Account, state, shard = chirper
+    compiled = _served_tick(
+        rt, Account, method, B, state, shard,
+        # a read answers from the ring, its head and the count
+        leaves_read=3 if method == "get_received" else None)
+    mem = compiled.memory_analysis()
+    table = _chip_table_bytes(state)
+    assert table == CHIRPER_ROWS * 33296
+    assert not _table_sized_copies(compiled, (1, CHIRPER_ROWS, 32768))
+    assert mem.temp_size_in_bytes < 512 << 20
+    if method == "publish":
+        assert mem.alias_size_in_bytes >= table          # in place
+        # the outbox: B x 128 lanes of a 320-byte chirp, a key and a mask
+        assert mem.output_size_in_bytes - table < 2 * B * 128 * 512
+    else:
+        assert mem.alias_size_in_bytes == 0
+        assert mem.output_size_in_bytes < 4 << 20        # replies only
+    text = compiled.as_text()
+    assert not [c for c in _COLLECTIVES if c in text]
+
+
+@pytest.mark.parametrize("lanes", [512, 4096])
+def test_chirper_delivery_round_ticks_in_place(chirper, lanes):
+    """One apply round of the exchange: ``receive`` over the lanes a pass
+    delivered, the table aliased, rows gathered and scattered (``lanes``
+    rows of temporaries, not a table), no collective; and the resolver
+    that dedups the lanes before it."""
+    rt, Account, state, shard = chirper
+    kern = rt._build_kernel(Account, "receive")
+    lane = lambda dt, *s: _struct((4, lanes, *s), dt, shard)  # noqa: E731
+    compiled = kern.lower(
+        state, lane(jnp.int32), lane(jnp.int32), lane(jnp.bool_),
+        lane(jnp.bool_), {"chirp": lane(jnp.uint8, 320)}).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _chip_table_bytes(state)
+    assert not _table_sized_copies(compiled, (1, CHIRPER_ROWS, 32768))
+    assert mem.temp_size_in_bytes < 8 * lanes * 32768 + (64 << 20)
+    text = compiled.as_text()
+    assert not [c for c in _COLLECTIVES if c in text]
+    resolver = rt._apply_resolver(Account, False).lower(
+        lane(jnp.int32), lane(jnp.bool_)).compile()
+    assert not [c for c in _COLLECTIVES if c in resolver.as_text()]
+
+
+def test_chirper_exchange_is_one_all_to_all_and_no_other_collective(
+        chirper, monkeypatch):
+    """A pass of a publish job's exchange on the 2x2, at the shapes of a
+    job of 32 publishes a shard (4,096 outbox lanes, 512 a pair): the
+    chirps, their keys and the fresh marks cross in ``all-to-all``s and
+    nothing else talks — no all-reduce, all-gather, collective-permute
+    or reduce-scatter."""
+    from orleans_tpu.parallel.transport import build_exchange
+
+    rt, Account, state, shard = chirper
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    lanes, capacity = 4096, 512
+    ex = build_exchange(rt.table(Account).mesh, capacity=capacity)
+    lane = lambda dt, *s: _struct((4, lanes, *s), dt, shard)  # noqa: E731
+    compiled = ex.lower(
+        lane(jnp.int32), lane(jnp.bool_),
+        {"__key__": lane(jnp.int32), "__fresh__": lane(jnp.bool_),
+         "chirp": lane(jnp.uint8, 320)}).compile()
+    text = compiled.as_text()
+    assert "all-to-all" in text and "tpu_custom_call" in text
+    assert [c for c in _COLLECTIVES if c in text] == ["all-to-all"]
+    assert compiled.memory_analysis().output_size_in_bytes < 64 << 20

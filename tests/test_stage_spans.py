@@ -28,9 +28,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from orleans_tpu.dispatch import VectorGrain, actor_method, add_vector_grains
+from orleans_tpu.dispatch import (VectorGrain, actor_method,
+                                  add_vector_grains, sends)
 from orleans_tpu.observability import stats as stats_mod
-from orleans_tpu.observability.stats import (FLUSH_STATS, RECOVER_STATS,
+from orleans_tpu.observability.stats import (EXCHANGE_STATS, FLUSH_STATS,
+                                             RECOVER_STATS,
                                              STAGES, StageSpan,
                                              StatsRegistry,
                                              close_stage_registry,
@@ -197,6 +199,10 @@ def test_stage_observed_once_per_unit_of_work(served, stage):
         keys = st.histograms[RECOVER_STATS["keys"]]
         assert 1 <= got == keys.total <= N_KEYS
         assert keys.sum == N_KEYS == st.get(RECOVER_STATS["first_touch"])
+    elif stage.startswith("exchange"):
+        # these shapes have no sending method: the sender's cases are
+        # below (test_exchange_stages_*)
+        assert got == 0
     else:
         raise AssertionError(f"stage {stage} has no case")
 
@@ -260,6 +266,100 @@ async def test_metrics_off_registers_none_of_the_new_names():
     assert not names & new
     assert not [n for n in names if n.startswith("compile.")]
     assert silo.stats.get(FLUSH_STATS["flushed"]) >= 4  # it did flush
+
+
+class Pinger(VectorGrain):
+    """``ping(to)`` sends one message to ``to``'s ``pong``."""
+
+    STATE = {"pongs": (jnp.int32, ())}
+
+    @staticmethod
+    def initial_state(key_hash):
+        return {"pongs": jnp.int32(0)}
+
+    @actor_method(args={"x": (jnp.int32, ())})
+    def pong(state, args):
+        n = state["pongs"] + args["x"]
+        return {"pongs": n}, n
+
+    @sends("pong", fanout=2, args={"to": (jnp.int32, ())})
+    def ping(state, args):
+        return state, state["pongs"], (
+            jnp.stack([args["to"], args["to"]]), jnp.array([True, True]),
+            {"x": jnp.ones(2, jnp.int32)})
+
+
+async def _serve_pings(metrics: bool, offloop: bool):
+    b = (SiloBuilder().with_name(f"ex-{metrics}-{offloop}")
+         .with_fabric(SocketFabric())
+         .with_config(metrics_enabled=metrics, offloop_tick=offloop))
+    add_vector_grains(b, Pinger, mesh=make_mesh(4), dense={Pinger: 64},
+                      capacity_per_shard=16, storage=MemoryStorage(),
+                      flush_period=0.05)
+    silo = b.build()
+    await silo.start()
+    observers: set = set()
+    observe, increment = silo.stats.observe, silo.stats.increment
+
+    def spy_observe(key, value):
+        observers.add(threading.get_ident())
+        observe(key, value)
+
+    def spy_increment(key, value=1):
+        observers.add(threading.get_ident())
+        increment(key, value)
+
+    silo.stats.observe, silo.stats.increment = spy_observe, spy_increment
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    try:
+        for r in range(ROUNDS):
+            await asyncio.gather(*(
+                client.get_grain(Pinger, k).ping(to=np.int32(63 - k))
+                for k in range(8)))
+    finally:
+        await client.close_async()
+        await silo.stop()
+    return silo, observers, threading.get_ident()
+
+
+@pytest.mark.parametrize("offloop", [True, False],
+                         ids=["worker", "lever-off"])
+async def test_exchange_stages_reach_the_registry_through_the_sink(offloop):
+    silo, observers, loop_thread = await _serve_pings(True, offloop)
+    st = silo.stats
+    assert observers == {loop_thread}
+    jobs = st.get(EXCHANGE_STATS["jobs"])
+    assert ROUNDS <= jobs <= 8 * ROUNDS
+    # one exchange and one activate span a sending job; a route and an
+    # apply span a pass (16 messages of one shard for one other, 8 a
+    # pass: two passes a job); two dedup rounds a pass
+    for stage in ("exchange", "exchange.activate"):
+        assert _count(st, stage + ".seconds") == jobs, stage
+    passes = st.get(EXCHANGE_STATS["lanes"]) // (4 * 4 * 8)
+    assert passes >= jobs
+    for stage in ("exchange.route", "exchange.apply"):
+        assert _count(st, stage + ".seconds") == passes, stage
+    assert st.get(EXCHANGE_STATS["rounds"]) == 2 * passes
+    assert st.get(EXCHANGE_STATS["sent"]) \
+        == st.get(EXCHANGE_STATS["delivered"]) == 2 * 8 * ROUNDS
+    assert st.get(EXCHANGE_STATS["cross_shard"]) == 2 * 8 * ROUNDS
+    assert st.get(EXCHANGE_STATS["activated"]) == 8
+    assert st.get(EXCHANGE_STATS["dropped"]) == 0
+    assert st.get(EXCHANGE_STATS["lanes"]) >= st.get(EXCHANGE_STATS["sent"])
+    assert stats_mod._thread.stage is None
+    # the first pass's programs compiled inside the stages that ran them
+    compiled = [n for n in st.histograms if n.startswith("compile.")]
+    assert any(n.startswith("compile.exchange.") for n in compiled)
+    assert "compile.other.seconds" not in compiled
+
+
+async def test_exchange_stamps_nothing_with_metrics_off():
+    silo, observers, _loop = await _serve_pings(False, True)
+    names = set(silo.stats.histograms) | set(silo.stats.counters)
+    assert not [n for n in names if n.startswith(("exchange", "compile."))]
+    assert not names & set(EXCHANGE_STATS.values())
+    # it did deliver: the receivers' rows were flushed
+    assert silo.stats.get(FLUSH_STATS["flushed"]) >= 8
 
 
 class _GatedStorage(MemoryStorage):
