@@ -954,7 +954,12 @@ class VectorRuntime:
         table state outside the tick path — rebalance shard moves,
         checkpoint capture, write-behind gathers — takes it around the
         touch so it can never interleave with a worker-side batch whose
-        donated state/staging upload is still in flight."""
+        donated state/staging upload is still in flight. A write-behind
+        pass holds it while it locates its keys and launches its gathers
+        (``VectorStorageBridge.flush``: the launches are the snapshot, so
+        the waits for the rows and the writes run without it, and it is
+        never held across an ``await``); checkpoint capture holds it
+        through its device→host copy."""
         return self._fence
 
     def _bind_loop(self) -> None:

@@ -177,7 +177,7 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
             from ..observability.stats import (COUNT_BOUNDS, FLUSH_STATS,
                                                StageSpan)
 
-            n = batched = 0
+            n = batched = pipelined = 0
             first_error: BaseException | None = None
             st = silo.ingest_stats
             span = None
@@ -193,11 +193,13 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
                         st, "flush", nest=False,
                         flush=st.get(FLUSH_STATS["flushes"]) + 1)
                 bridge = silo.vector_bridges[cls]
+                before = bridge.pipelined
                 try:
                     wrote = await bridge.flush(keys, strict=strict)
                     n += wrote
                     if bridge.batched:
                         batched += wrote
+                    pipelined += bridge.pipelined - before
                 except asyncio.CancelledError:
                     # cancelled mid-flush: the keys are already drained —
                     # re-mark them so the final stop() drain retries
@@ -221,6 +223,7 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
             if n:
                 silo.stats.increment(FLUSH_STATS["flushed"], n)
                 silo.stats.increment(FLUSH_STATS["batched"], batched)
+                silo.stats.increment(FLUSH_STATS["pipelined"], pipelined)
             if first_error is not None:
                 raise first_error
             return n

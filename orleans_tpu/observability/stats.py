@@ -267,8 +267,11 @@ SLO_STATS = {
 #   engine.resolve        _resolve_batch (loop)
 #   egress.flush          EgressBatcher.flush (loop)
 #   flush                 one write-behind pass of hosting.flush_all
-#   flush.locate/.gather  VectorStorageBridge.flush under the tick fence
-#   flush.write           the gather of per-key storage writes
+#   flush.locate          VectorStorageBridge.flush under the tick fence
+#   flush.gather          the pass's launches (under the fence) and its
+#                         waits for each chunk's rows, summed
+#   flush.write           building a chunk's rows and the provider's
+#                         write_many, summed over the pass's chunks
 #   exchange              a sending job's outbox -> last apply round done
 #                         (tick worker, under the fence; in it
 #                         exchange.route: one all_to_all a pass, and
@@ -301,6 +304,9 @@ FLUSH_STATS = {
     # counter: of those, rows that went through a provider's own
     # write_many (not GrainStorage's per-key default)
     "batched": "vector.storage.flush.batched",
+    # counter: of those, rows written while a later chunk of their pass
+    # was still to come down (0 for a pass of one chunk)
+    "pipelined": "vector.storage.flush.pipelined",
 }
 
 RECOVER_STATS = {

@@ -32,7 +32,7 @@ import numpy as np
 
 from ..core.errors import InconsistentStateError
 from ..core.ids import GrainId, GrainType
-from ..dispatch.engine import _bucket
+from ..dispatch.engine import MIN_BUCKET, _bucket
 from ..observability.stats import NO_SPAN, StageSpan
 from .core import ADOPT_ETAG, GrainStorage
 
@@ -44,6 +44,9 @@ __all__ = ["VectorCheckpointer", "VectorStorageBridge"]
 # a flushed row's vector field of up to this many values becomes a Python
 # list; a wider one stays a numpy row (VectorStorageBridge._rows)
 _LIST_MAX = 16
+# a pass's rows come down in chunks of at most this many bytes, counted
+# over every leaf of the table's state (VectorStorageBridge._chunk_rows)
+_CHUNK_BYTES = 32 << 20
 
 
 @jax.jit
@@ -187,6 +190,20 @@ class VectorCheckpointer:
         self.manager.close()
 
 
+def _observe_laps(stats, laps: list) -> None:
+    """A pass's deferred stage observations: each stage's seconds summed
+    over the pass's chunks into one observation, every compile by itself
+    (its count is what ``compile.<stage>`` is read for)."""
+    sums: dict[str, float] = {}
+    for key, secs in laps:
+        if key.startswith("compile."):
+            stats.observe(key, secs)
+        else:
+            sums[key] = sums.get(key, 0.0) + secs
+    for key, secs in sums.items():
+        stats.observe(key, secs)
+
+
 class VectorStorageBridge:
     """Write-behind per-actor persistence for one VectorGrain class: rows
     flushed to / loaded from an ordinary ``GrainStorage`` provider, with
@@ -203,6 +220,10 @@ class VectorStorageBridge:
         self._ids: dict[int, GrainId] = {}  # beside _etags: one per key seen
         self.storage_conflicts = 0
         self.flushes = 0  # flush() calls: the stage spans' unit of work
+        # rows written while a later chunk of their pass was still to
+        # come down (every chunk's but the last's): how far flush() runs
+        # download and write at the same time
+        self.pipelined = 0
         # observed, not configured: does the provider bring its own
         # write_many, or does a flush go through the per-key default?
         self.batched = (type(storage).write_many
@@ -244,19 +265,57 @@ class VectorStorageBridge:
             k, shards, slots = k[keep], shards[keep], slots[keep]
         return k.tolist(), shards, slots
 
+    @staticmethod
+    def _chunk_rows(tbl) -> int:
+        """Rows a chunk of a pass: the largest power of two whose rows,
+        over every leaf of the table's state, stay under ``_CHUNK_BYTES``
+        (512 rows of 33 KB; a pass of 16 B or 1 KB rows is one chunk)."""
+        row = sum(math.prod(a.shape[2:]) * a.dtype.itemsize
+                  for a in tbl.state.values())
+        rows = max(MIN_BUCKET, _CHUNK_BYTES // max(row, 1))
+        return 1 << (rows.bit_length() - 1)
+
+    def _launch(self, tbl, shards: np.ndarray, slots: np.ndarray
+                ) -> list[tuple[int, dict]]:
+        """Launch the gather of the rows at (shards, slots) and ask for
+        the host copies, a chunk at a time: ``(rows, device columns)`` per
+        chunk, in order. Full chunks are ``_chunk_rows`` long, the tail is
+        padded to the engine's power-of-two bucket (slot (0, 0) always
+        exists; ``_land`` slices the padding off), so the programs a table
+        compiles are ``_gather_rows`` at powers of two up to the chunk.
+        Call under the tick fence: the launches are enqueued before any
+        later tick and their results are arrays no tick donates, so what
+        lands later is the rows as they stood here."""
+        n = len(shards)
+        chunk = self._chunk_rows(tbl)
+        out = []
+        for lo in range(0, max(n, 1), chunk):
+            m = min(chunk, n - lo)
+            index = np.zeros((2, _bucket(m)), np.int32)
+            index[0, :m] = shards[lo:lo + m]
+            index[1, :m] = slots[lo:lo + m]
+            dev = _gather_rows(tbl.state, index)
+            for a in dev.values():
+                a.copy_to_host_async()
+            out.append((m, dev))
+        return out
+
+    @staticmethod
+    def _land(m: int, dev: dict) -> dict[str, np.ndarray]:
+        """Wait for one launched chunk: its ``m`` rows as host columns."""
+        return {f: np.asarray(a)[:m] for f, a in dev.items()}
+
     def _gather(self, tbl, shards: np.ndarray, slots: np.ndarray
                 ) -> dict[str, np.ndarray]:
-        """The rows at (shards, slots) as host columns: one compiled
-        gather over the whole state tree, its index array padded to the
-        engine's power-of-two bucket (slot (0, 0) always exists; the
-        padding rows are sliced off on the host). Call under the tick
-        fence."""
-        n = len(shards)
-        index = np.zeros((2, _bucket(n)), np.int32)
-        index[0, :n] = shards
-        index[1, :n] = slots
-        host = jax.device_get(_gather_rows(tbl.state, index))
-        return {f: v[:n] for f, v in host.items()}
+        """The rows at (shards, slots) as host columns: ``_launch`` and
+        every chunk's ``_land`` in one synchronous call (the launches and
+        waits of a pass, without its writes). Call under the tick fence."""
+        chunks = [self._land(m, dev)
+                  for m, dev in self._launch(tbl, shards, slots)]
+        if len(chunks) == 1:
+            return chunks[0]
+        return {f: np.concatenate([c[f] for c in chunks])
+                for f in chunks[0]}
 
     @staticmethod
     def _rows(host: dict[str, np.ndarray], n: int):
@@ -317,22 +376,35 @@ class VectorStorageBridge:
 
     async def flush(self, keys: Iterable[int], strict: bool = False) -> int:
         """Write-behind: persist the current device rows for ``keys`` in
-        one columnar pass: one compiled device→host gather (``_gather``),
-        the columns turned into rows as the provider consumes them
-        (``_rows``), and one ``storage.write_many`` with each key's
+        one columnar pass. Under the tick fence the keys are located and
+        the device→host gather is launched, a chunk of ``_chunk_rows``
+        rows at a time, with every chunk's host copy asked for
+        (``_launch``): that is the snapshot, and the fence goes back
+        there. Then, in chunk order: wait for a chunk's columns
+        (``_land``), turn them into rows as the provider consumes them
+        (``_rows``), and ``storage.write_many`` them with each key's
         remembered etag (or ``ADOPT_ETAG`` where this bridge has none: a
         fresh bridge after a checkpoint restore has no etag memory but IS
         the legitimate writer — the device row is the truth being
-        flushed).
+        flushed) — while the runtime's own threads bring the later chunks
+        down. A pass that fits one chunk is one launch at the power-of-two
+        bucket, one wait and one write.
 
         What the loop does meanwhile is the provider's choice. One that
-        overrides ``write_many`` (``MemoryStorage``) takes the whole
-        batch in a single synchronous pass: no coroutine or task per row,
-        and nothing else runs on the loop until it returns. Every other
-        provider gets the base class's default, one concurrent
-        ``read``/``write`` per key, and the loop interleaves wherever
-        those suspend (never, under an eager task factory, for a
+        overrides ``write_many`` (``MemoryStorage``) takes a chunk in a
+        single synchronous pass: no coroutine or task per row, and
+        nothing else runs on the loop from the launch to the last chunk's
+        return. Every other provider gets the base class's default, one
+        concurrent ``read``/``write`` per key, and the loop interleaves
+        wherever those suspend (never, under an eager task factory, for a
         provider that does not await).
+
+        The bookkeeping is done a chunk at a time, as its ``write_many``
+        returns: etags remembered, conflicted rows released, failed keys
+        re-marked. So a later chunk that raises (its download, the
+        provider) leaves no written key with a stale etag: the caller
+        re-marks the pass (``hosting.flush_all``) and the next pass
+        rewrites it.
 
         Per-key failure isolation: keys whose activation slot is gone
         (released) are dropped with a log — there is no row left to
@@ -344,10 +416,12 @@ class VectorStorageBridge:
         is set OR when the runtime has no dirty tracking to hold the
         retry — a standalone bridge must never report silent success.
 
-        With the runtime's stage metrics on, three spans of unit
-        ``flush=<n>``: flush.locate and flush.gather (both holding the
-        fence, so ticks wait for them) and flush.write (building the
-        rows and the provider's ``write_many``)."""
+        With the runtime's stage metrics on, three observations of unit
+        ``flush=<n>`` a pass: flush.locate (under the fence),
+        flush.gather (loop time obtaining rows: the launches under the
+        fence and the waits for the chunks, summed) and flush.write
+        (building the rows and the provider's ``write_many``, summed over
+        the chunks)."""
         keys = np.asarray(keys if isinstance(keys, np.ndarray)
                           else list(keys), np.int64)
         if not keys.size:
@@ -356,36 +430,57 @@ class VectorStorageBridge:
         st = self.runtime.stats
         self.flushes += 1
         n = self.flushes
-        # under the tick fence: the gather materializes state rows, which
-        # must not race an off-loop tick that has the state donated
-        with self.runtime.tick_fence():
-            with StageSpan(st, "flush.locate", flush=n) \
-                    if st is not None else NO_SPAN:
-                kept, shards, slots = self._locate(keys, drop_missing=True)
-            if not kept:
-                return 0
-            with StageSpan(st, "flush.gather", flush=n, rows=len(kept)) \
-                    if st is not None else NO_SPAN:
-                host = self._gather(tbl, shards, slots)
+        # a chunk's spans defer into ``laps``: one observation a pass
+        laps: list = []
 
-        with StageSpan(st, "flush.write", nest=False, flush=n) \
-                if st is not None else NO_SPAN:
-            results = await self.storage.write_many(
-                self.grain_type, self._entries(kept, host))
-        etags = self._etags
-        failed, first, conflicts = [], None, 0
-        for key, r in zip(kept, results):
-            if not isinstance(r, BaseException):
-                etags[key] = r
-            elif isinstance(r, InconsistentStateError):
-                self._release_conflicted(tbl, key)
-                conflicts += 1
-            else:
-                failed.append(key)
-                if first is None:
-                    first = r
+        def span(stage: str, **unit):
+            return StageSpan(st, stage, sink=laps, flush=n, **unit) \
+                if st is not None else NO_SPAN
+
+        try:
+            # under the tick fence: the gather reads state rows, which
+            # must not race an off-loop tick that has the state donated
+            with self.runtime.tick_fence():
+                with span("flush.locate"):
+                    kept, shards, slots = self._locate(keys,
+                                                       drop_missing=True)
+                if not kept:
+                    return 0
+                with span("flush.gather", rows=len(kept)):
+                    chunks = self._launch(tbl, shards, slots)
+            chunks.reverse()  # popped in launch order, let go as written
+            etags = self._etags
+            failed, first, written, lo = [], None, 0, 0
+            while chunks:
+                m, dev = chunks.pop()
+                with span("flush.gather"):
+                    host = self._land(m, dev)
+                part = kept[lo:lo + m]
+                lo += m
+                with span("flush.write", nest=False):
+                    results = await self.storage.write_many(
+                        self.grain_type, self._entries(part, host))
+                wrote, bad = 0, []
+                for key, r in zip(part, results):
+                    if not isinstance(r, BaseException):
+                        etags[key] = r
+                        wrote += 1
+                    elif isinstance(r, InconsistentStateError):
+                        self._release_conflicted(tbl, key)
+                    else:
+                        bad.append(key)
+                        if first is None:
+                            first = r
+                if bad:
+                    self.runtime._mark_dirty(self.grain_class, bad)
+                    failed += bad
+                written += wrote
+                if chunks:  # a later chunk was still to come down
+                    self.pipelined += wrote
+        finally:
+            if laps:
+                _observe_laps(st, laps)
         if failed:
-            self.runtime._mark_dirty(self.grain_class, failed)
             logging.getLogger("orleans.vector").warning(
                 "write-behind: %d/%d key writes failed (re-marked): %r",
                 len(failed), len(kept), first)
@@ -394,7 +489,7 @@ class VectorStorageBridge:
                 # demanded completeness — the final stop() drain): surface
                 # the failure instead of reporting partial success
                 raise first
-        return len(kept) - len(failed) - conflicts
+        return written
 
     async def load(self, keys: Iterable[int],
                    errors: dict | None = None) -> list[int]:

@@ -436,3 +436,28 @@ def test_chirper_exchange_is_one_all_to_all_and_no_other_collective(
     assert "all-to-all" in text and "tpu_custom_call" in text
     assert [c for c in _COLLECTIVES if c in text] == ["all-to-all"]
     assert compiled.memory_analysis().output_size_in_bytes < 64 << 20
+
+
+def test_chirper_write_behind_gather_compiles_at_the_chunk(chirper):
+    """A write-behind pass brings Chirper's 33 KB rows down 512 at a time
+    (``VectorStorageBridge._chunk_rows``: the largest power of two under
+    ``_CHUNK_BYTES``): that gather on the 2x2 is 17 MB of result a device
+    and no temporaries; its result is replicated (an ``all-reduce`` of
+    what each shard gathered), so any one device's copy is the rows."""
+    from types import SimpleNamespace
+
+    from orleans_tpu.storage.checkpoint import (VectorStorageBridge,
+                                                _gather_rows)
+
+    rt, _Account, state, _shard = chirper
+    mesh = rt.table(_Account).mesh
+    rows = VectorStorageBridge._chunk_rows(SimpleNamespace(state=state))
+    assert rows == 512
+    compiled = _gather_rows.lower(
+        state, _struct((2, rows), jnp.int32, NamedSharding(mesh, P()))
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes <= rows * 33296 + 4096
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert all(s.is_fully_replicated
+               for s in jax.tree.leaves(compiled.output_shardings))

@@ -39,7 +39,7 @@ from orleans_tpu.observability.stats import (EXCHANGE_STATS, FLUSH_STATS,
                                              open_stage_registry)
 from orleans_tpu.parallel import make_mesh
 from orleans_tpu.runtime import GatewayClient, SiloBuilder, SocketFabric
-from orleans_tpu.storage import MemoryStorage
+from orleans_tpu.storage import MemoryStorage, checkpoint
 from orleans_tpu.storage.checkpoint import _gather_rows
 
 N_KEYS = 16
@@ -247,6 +247,41 @@ def test_flush_rows_sum_to_flushed(served):
     assert rows.sum == st.get(FLUSH_STATS["flushed"]) >= N_KEYS
     assert rows.total == st.get(FLUSH_STATS["flushes"])
     assert st.get("vector.storage.recovered") == 0  # counted, none stored
+    # every pass here fits one chunk: the counter is there, at 0
+    assert st.counters[FLUSH_STATS["pipelined"]] == 0
+
+
+async def test_a_pass_in_chunks_is_one_observation_a_stage(monkeypatch):
+    """30 rows in chunks of 8 (the stop drain's one pass): flush.locate,
+    flush.gather and flush.write are still observed once, their seconds
+    summed over the chunks, and tile the pass's ``flush``; the rows of
+    every chunk but the last count as pipelined; the one program the
+    launches compiled is booked to compile.flush.gather."""
+    monkeypatch.setattr(checkpoint, "_CHUNK_BYTES", 4 * 8)
+    silo = _build(True, MemoryStorage(), period=3600.0)
+    await silo.start()
+    client = await GatewayClient([silo.gateway_endpoint]).connect()
+    _gather_rows.clear_cache()
+    try:
+        await _rounds(client, range(30), rounds=1)
+    finally:
+        await client.close_async()
+        await silo.stop()
+    st = silo.stats
+    assert st.get(FLUSH_STATS["flushes"]) == 1
+    assert st.get(FLUSH_STATS["flushed"]) == 30
+    assert st.get(FLUSH_STATS["pipelined"]) == 24
+    h = st.histograms
+    parts = [h[f"flush.{s}.seconds"] for s in ("locate", "gather", "write")]
+    assert [p.total for p in parts] == [1, 1, 1]
+    assert h["flush.seconds"].total == 1
+    assert all(p.sum > 0 for p in parts)
+    assert h["flush.seconds"].sum >= sum(p.sum for p in parts) - 1e-9
+    compiled = {n: x.total for n, x in h.items()
+                if n.startswith("compile.flush.")}
+    assert compiled == {"compile.flush.gather.seconds": 1}  # 8 rows
+    assert "compile.other.seconds" not in h
+    assert stats_mod._thread.stage is None
 
 
 async def test_metrics_off_registers_none_of_the_new_names():

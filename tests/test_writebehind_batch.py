@@ -5,6 +5,7 @@ paths — and the pass's cost guards (tasks created, gathers compiled), none
 of which reads a clock."""
 
 import asyncio
+import itertools
 
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +20,7 @@ from orleans_tpu.runtime import ClusterClient, SiloBuilder
 from orleans_tpu.storage import (ADOPT_ETAG, ErrorInjectionStorage,
                                  GrainStorage, LatencyStorage, MemoryStorage,
                                  VectorStorageBridge)
+from orleans_tpu.storage import checkpoint
 from orleans_tpu.storage.checkpoint import _gather_rows
 
 
@@ -484,3 +486,297 @@ async def test_forty_dirty_counts_in_one_bucket_compile_one_gather():
     assert _gather_rows._cache_size() == 1
     assert await bridge.flush(range(64)) == 64  # the bucket below: one more
     assert _gather_rows._cache_size() == 2
+
+
+# -- a pass in chunks: download and write at the same time -------------------
+
+Wide = _grain(jnp.uint8, (64,))     # 64 B + n: a 68 B row
+Narrow = _grain(jnp.float32, (3,))  # 12 B + n: a 16 B row
+CHUNK = 16                          # rows a chunk where `chunked` asks
+
+
+@pytest.fixture
+def chunked(monkeypatch):
+    """``Wide``'s rows come down ``CHUNK`` at a time."""
+    monkeypatch.setattr(checkpoint, "_CHUNK_BYTES", 68 * CHUNK)
+
+
+async def _put_rows(rt, cls, n: int, salt: int = 0) -> None:
+    """Row k of ``cls`` gets bytes that differ from every other row's."""
+    width = cls.STATE["v"][1][0]
+    futs = [rt.call(cls, k, "put", v=((np.arange(width) * 7 + k + salt) % 251)
+                    .astype(np.uint8)) for k in range(n)]
+    await rt.flush()
+    await asyncio.gather(*futs)
+
+
+@pytest.mark.parametrize("mesh", [1, 8])
+@pytest.mark.parametrize("kind", ["batched", "per_key"])
+async def test_chunks_store_every_row_as_the_device_holds_it(chunked, kind,
+                                                             mesh):
+    """53 rows in chunks of 16: three full chunks and a tail of 5 padded to
+    8. Every stored row is its device row byte for byte, across the chunk
+    edges and in the padded tail, and every key's etag is its own."""
+    n = 3 * CHUNK + 5
+    rt = _runtime(Wide, n, mesh=mesh, cap=64)
+    await _put_rows(rt, Wide, n)
+    storage = PROVIDERS[kind]()
+    bridge = VectorStorageBridge(rt, Wide, storage)
+    assert bridge._chunk_rows(rt.table(Wide)) == CHUNK
+    keys = np.random.default_rng(3).permutation(n)  # not in slot order
+    assert await bridge.flush(keys) == n
+    assert bridge.pipelined == 3 * CHUNK  # every chunk's rows but the last's
+    device = _device_rows(rt, Wide, n)
+    for k in range(n):
+        state, etag = await storage.read(Wide.__name__, bridge._grain_id(k))
+        assert isinstance(state["v"], np.ndarray)
+        assert state["v"].tobytes() == device["v"][k].tobytes()
+        assert state["n"] == int(device["n"][k]) == 1
+        assert bridge._etags[k] == etag
+    # the second pass writes with the etags the first one remembered
+    await _put_rows(rt, Wide, n, salt=9)
+    assert await bridge.flush(keys) == n
+    assert bridge.storage_conflicts == 0
+    state, _ = await storage.read(Wide.__name__, bridge._grain_id(CHUNK))
+    assert state["n"] == 2
+
+
+@pytest.mark.parametrize("rows_a_chunk", [CHUNK, None],
+                         ids=["chunks-of-16", "one-chunk"])
+async def test_a_pass_of_any_width_compiles_nothing_after_the_warm_up(
+        monkeypatch, rows_a_chunk):
+    """The programs a table compiles are ``_gather_rows`` at powers of two
+    up to the chunk; after ``_gather`` ran at every power of two up to
+    16,384 (the benchmark's warm-up) no pass compiles."""
+    if rows_a_chunk:
+        monkeypatch.setattr(checkpoint, "_CHUNK_BYTES", 68 * rows_a_chunk)
+    rt = _runtime(Wide, 200, mesh=1, cap=256)
+    await _put_rows(rt, Wide, 200)
+    tbl = rt.table(Wide)
+    bridge = VectorStorageBridge(rt, Wide, MemoryStorage())
+    _gather_rows.clear_cache()
+    if rows_a_chunk:
+        for n in (5, 16, 17, 40, 53, 200):
+            assert await bridge.flush(range(n)) == n
+        assert _gather_rows._cache_size() == 2  # 8 and 16 rows
+    for b in (1 << i for i in range(3, 15)):
+        at = np.zeros(b, np.int32)
+        with rt.tick_fence():
+            host = bridge._gather(tbl, at, at)
+        assert {len(c) for c in host.values()} == {b}
+    warmed = _gather_rows._cache_size()
+    assert warmed == (2 if rows_a_chunk else 12)
+    for n in (1, 7, 9, 16, 31, 33, 100, 129, 200):
+        assert await bridge.flush(range(n)) == n
+    assert _gather_rows._cache_size() == warmed
+
+
+async def test_a_16_byte_row_pass_is_one_launch_at_its_bucket(monkeypatch):
+    launched = []
+
+    def spy(state, index):
+        launched.append(index.shape)
+        return _gather_rows(state, index)
+
+    monkeypatch.setattr(checkpoint, "_gather_rows", spy)
+    rt = _runtime(Narrow, 2000, mesh=1, cap=2048)
+    bridge = VectorStorageBridge(rt, Narrow, MemoryStorage())
+    assert bridge._chunk_rows(rt.table(Narrow)) == (32 << 20) // 16
+    assert await bridge.flush(range(2000)) == 2000
+    assert launched == [(2, 2048)]
+    assert bridge.pipelined == 0
+    assert len(bridge.storage._data) == 2000
+
+
+async def test_every_host_copy_is_asked_for_before_the_first_write(
+        chunked, monkeypatch):
+    """Under the fence: every chunk launched and its host copy requested.
+    Then chunk by chunk: waited for, written — the later ones coming down
+    meanwhile."""
+    log = []
+
+    class Leaf:
+        """A device column that records when it is asked for and read."""
+
+        def __init__(self, a, chunk):
+            self.a, self.chunk = a, chunk
+
+        def copy_to_host_async(self):
+            log.append(("copy", self.chunk))
+            self.a.copy_to_host_async()
+
+        def __array__(self, dtype=None, copy=None):
+            log.append(("land", self.chunk))
+            return np.asarray(self.a)
+
+    launches = iter(range(100))
+
+    def gather(state, index):
+        chunk = next(launches)
+        assert rt.tick_fence()._is_owned()
+        return {f: Leaf(a, chunk)
+                for f, a in _gather_rows(state, index).items()}
+
+    class Recording(MemoryStorage):
+        async def write_many(self, grain_type, entries):
+            log.append(("write", None))
+            assert not rt.tick_fence()._is_owned()
+            return await super().write_many(grain_type, entries)
+
+    n = 2 * CHUNK + 3
+    rt = _runtime(Wide, n, mesh=1, cap=64)
+    await _put_rows(rt, Wide, n)
+    monkeypatch.setattr(checkpoint, "_gather_rows", gather)
+    bridge = VectorStorageBridge(rt, Wide, Recording())
+    assert await bridge.flush(range(n)) == n
+    # a chunk's two leaves are asked for, and read, one after the other
+    steps = [step for step, _ in itertools.groupby(log)]
+    assert steps == [("copy", 0), ("copy", 1), ("copy", 2),
+                     ("land", 0), ("write", None),
+                     ("land", 1), ("write", None),
+                     ("land", 2), ("write", None)]
+
+
+async def test_a_launched_gather_holds_the_rows_as_they_stood(chunked):
+    """The launch under the fence is the snapshot: a tick that donates the
+    state afterwards does not change what lands."""
+    n = 2 * CHUNK + 3
+    rt = _runtime(Wide, n, mesh=1, cap=64)
+    await _put_rows(rt, Wide, n)
+    bridge = VectorStorageBridge(rt, Wide, MemoryStorage())
+    before = _device_rows(rt, Wide, n)
+    _, shards, slots = bridge._locate(range(n))
+    with rt.tick_fence():
+        chunks = bridge._launch(rt.table(Wide), shards, slots)
+    await _put_rows(rt, Wide, n, salt=100)  # ticks over the same rows
+    after = _device_rows(rt, Wide, n)
+    assert not (after["v"] == before["v"]).all()
+    landed = [bridge._land(m, dev) for m, dev in chunks]
+    assert [len(c["n"]) for c in landed] == [CHUNK, CHUNK, 3]
+    for f in ("v", "n"):
+        assert (np.concatenate([c[f] for c in landed]) == before[f]).all()
+
+
+async def test_a_key_failing_in_the_second_chunk_is_remarked_alone(chunked):
+    n = 3 * CHUNK + 5
+    rt = _runtime(Wide, n, mesh=1, cap=64)
+    rt.enable_dirty_tracking()
+    inner = MemoryStorage()
+    bad = CHUNK + 4
+    bridge = VectorStorageBridge(rt, Wide, FailKeys(inner, {bad}))
+    await _put_rows(rt, Wide, n)
+    rt.drain_dirty(Wide)
+    assert await bridge.flush(range(n)) == n - 1
+    assert rt.drain_dirty(Wide).tolist() == [bad]
+    assert bridge.pipelined == 3 * CHUNK - 1
+    assert bad not in bridge._etags and len(bridge._etags) == n - 1
+    assert len(inner._data) == n - 1
+    with pytest.raises(IOError, match="injected write failure"):
+        await bridge.flush(range(n), strict=True)
+    assert rt.drain_dirty(Wide).tolist() == [bad]
+
+
+def _hosted_chunks(monkeypatch, rows: int) -> None:
+    """HostedCounter's 4 B rows come down ``rows`` at a time."""
+    monkeypatch.setattr(checkpoint, "_CHUNK_BYTES", 4 * rows)
+
+
+async def _add(client, n: int) -> None:
+    await asyncio.gather(*(client.get_grain(HostedCounter, k)
+                           .add(x=np.int32(k + 1)) for k in range(n)))
+
+
+async def _until(cond) -> None:
+    for _ in range(800):
+        if cond():
+            return
+        await asyncio.sleep(0.01)
+    raise AssertionError("never happened")
+
+
+async def test_a_failed_download_remarks_the_pass_and_the_next_persists_it(
+        monkeypatch):
+    """The third chunk's download fails after two chunks were written: the
+    flusher re-marks the whole pass, and because the written chunks' etags
+    were remembered as they returned, the next pass rewrites them without
+    a conflict — no row is released."""
+    _hosted_chunks(monkeypatch, 8)
+    storage = MemoryStorage()
+    silo = _silo(storage, 0.02)
+    await silo.start()
+    client = await ClusterClient(silo.fabric).connect()
+    bridge = silo.vector_bridges[HostedCounter]
+    tbl = silo.vector.table(HostedCounter)
+    flushed = lambda: silo.stats.get(FLUSH_STATS["flushed"])  # noqa: E731
+    seen = []
+    try:
+        await _add(client, 30)  # a first pass: every key has an etag
+        await _until(lambda: flushed() >= 30)
+        first = dict(bridge._etags)
+        land, calls = bridge._land, iter(range(1000))
+
+        def failing_land(m, dev):
+            if next(calls) == 2:
+                seen.append({k: e for k, e in bridge._etags.items()
+                             if e != first[k]})
+                raise RuntimeError("the download failed")
+            return land(m, dev)
+
+        bridge._land = failing_land
+        await _add(client, 30)
+        await _until(lambda: flushed() >= 60)
+    finally:
+        await client.close_async()
+        await silo.stop()
+    # when the download failed, two chunks of 8 were written and remembered
+    assert len(seen) == 1 and len(seen[0]) == 16
+    assert bridge.storage_conflicts == 0
+    assert tbl.dense_active[:30].all()
+    for k in range(30):
+        state, etag = await storage.read("HostedCounter", bridge._grain_id(k))
+        assert state == {"total": 2 * (k + 1)}
+        assert bridge._etags[k] == etag
+
+
+async def test_a_pass_cancelled_between_chunks_is_persisted_by_the_stop_drain(
+        monkeypatch):
+    """stop() cancels the flusher while the second chunk's writes are
+    suspended: the first chunk's etags are remembered, every key is
+    re-marked, and the strict drain writes all of them without a
+    conflict."""
+    _hosted_chunks(monkeypatch, 8)
+    entered = []
+
+    class Stuck(PerKeyOnly):
+        block = False
+
+        async def write(self, grain_type, grain_id, state, etag):
+            entered.append(grain_id.key)
+            if self.block and grain_id.key >= 8:
+                await asyncio.Event().wait()  # until cancelled
+            return await super().write(grain_type, grain_id, state, etag)
+
+    storage = Stuck()
+    silo = _silo(storage, 0.02)
+    await silo.start()
+    client = await ClusterClient(silo.fabric).connect()
+    bridge = silo.vector_bridges[HostedCounter]
+    try:
+        await _add(client, 20)
+        await _until(lambda: silo.stats.get(FLUSH_STATS["flushed"]) >= 20)
+        del entered[:]
+        storage.block = True
+        await _add(client, 20)
+        await _until(lambda: len(entered) >= 16)  # chunk 2 sits in its writes
+        await asyncio.sleep(0.05)
+        assert sorted(entered) == list(range(16))  # chunk 3 not started
+        storage.block = False
+    finally:
+        await client.close_async()
+        await silo.stop()
+    assert bridge.storage_conflicts == 0
+    assert silo.vector.table(HostedCounter).dense_active[:20].all()
+    for k in range(20):
+        state, etag = await storage.read("HostedCounter", bridge._grain_id(k))
+        assert state == {"total": 2 * (k + 1)}
+        assert bridge._etags[k] == etag
