@@ -9,7 +9,7 @@ AND device-tier tick — with `metrics_enabled`, then reads the stage
 histograms (observability.stats.INGEST_STATS) back out of the silo's
 registry:
 
-    decode / enqueue / queue_wait        host-side, per socket frame
+    decode / queue_wait                  host-side, per socket frame
     staging / transfer / tick            device-side, per vector batch
 
 Stage *shares* are each stage's summed seconds over the total of all

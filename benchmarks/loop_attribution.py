@@ -10,10 +10,6 @@ with the host-loop occupancy profiler on (``profiling_enabled``), then
 reads the per-category loop shares back out:
 
     turns                    host grain turns
-    tick_schedule            the device tick's loop side: the claim, the
-                             hand-off to the tick worker and the
-                             completion (staging, transfer, dispatch and
-                             sync run on the worker, off the loop)
     pump                     socket reads + wire decode + batched routing
     client                   client-side gateway machinery (pumps,
                              senders, reconnector) — first-class since
@@ -193,8 +189,6 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
             await c.close_async()
         await silo.stop()
     busy = round(1.0 - shares.get("idle", 0.0), 4)
-    tick_total = round(sum(v for k, v in shares.items()
-                           if k.startswith("tick_")), 4)
     return {
         "metric": "loop_occupancy_busy_share",
         "value": busy,
@@ -213,7 +207,6 @@ async def run(seconds: float = 2.0, concurrency: int = 32,
             "shares": shares,
             "shares_sum": round(sum(shares.values()), 4),
             "seconds_by_category": sec,
-            "device_tick_share": tick_total,
             "turns_share": shares.get("turns", 0.0),
             "pump_share": shares.get("pump", 0.0),
             "egress_share": shares.get("egress", 0.0),

@@ -79,8 +79,8 @@ def main() -> None:
     # loop attribution: per-category occupancy of the silo's event loop
     # at closed-loop saturation (c=32 mixed host+vector over TCP) — the
     # measured split behind "residual queue-wait is loop contention":
-    # turns vs the device tick's loop side (tick_schedule) vs pump vs
-    # observability vs idle, shares summing to ~1.0 of loop wall time
+    # turns vs pump vs observability vs idle, shares summing to ~1.0 of
+    # loop wall time
     print(json.dumps(asyncio.run(loop_attribution.run(
         seconds=2.0, concurrency=32))))
     # multi-loop silo A/B (ISSUE 11): 1 vs 2 ingress pump loops on

@@ -67,6 +67,8 @@ _DEFERRED = "engine.deferred"               # counter: msgs deferred >= once
 _DEFER_WAIT = "engine.defer_wait.seconds"   # first deferral -> claimed
 _HELD = "engine.held"                       # counter: msgs held >= once
 _COMPLETE_HOP = "engine.complete_hop.seconds"
+_WORKER_IDLE = "engine.worker_idle.seconds"  # previous job's end -> dequeue
+_IDLE_PIECE_S = 0.05  # a longer wait for a job is posted in pieces of this
 # worker-side ledger stamp (cost attribution, observability.ledger): the
 # payload rides the job's deferred-stats list and replays loop-side in
 # _complete_job — the CostLedger is loop-confined like the registries
@@ -425,13 +427,33 @@ def _worker_main(ref: "weakref.ref[VectorRuntime]",
     """The tick worker's thread: jobs FIFO until the stop sentinel. It
     holds the runtime only while a job runs. The finished job stays
     referenced here until the next one arrives, so its arrays are freed
-    on this thread and not on the loop's."""
+    on this thread and not on the loop's. With metrics on, its wait for
+    a job (the previous job's end -> this dequeue) rides the job's sink
+    to the loop like every worker observation; a longer wait is posted
+    to the loop every ``_IDLE_PIECE_S`` as it goes, so a window of the
+    registry holds the worker's idle of that window and not of a quiet
+    stretch before it."""
+    idle_from = None
     while True:
-        job = q.get()
+        try:
+            job = q.get() if idle_from is None \
+                else q.get(timeout=_IDLE_PIECE_S)
+        except _queue.Empty:
+            rt = ref()
+            if rt is None:
+                return
+            now = time.perf_counter()
+            rt._post_idle(now - idle_from)
+            idle_from = now
+            del rt
+            continue
         rt = ref()
         if job is None or rt is None:
             return
+        if idle_from is not None:
+            job.stats.append((_WORKER_IDLE, time.perf_counter() - idle_from))
         rt._run_job(job)
+        idle_from = time.perf_counter() if rt.stats is not None else None
         del rt
 
 
@@ -517,11 +539,6 @@ class VectorRuntime:
         # the on-device per-slot cost twin (table.record_cost)
         self.ledger = None
         self.track_cost = False
-        # host-loop occupancy profiler, injected by the owning silo when
-        # profiling_enabled: the two loop-side callbacks of a tick (the
-        # claim in _tick, the completion in _complete_job) book their
-        # time to its tick_schedule category
-        self.loop_prof = None
         # stateless-worker (mesh-replicated) hosts per class — see
         # dispatch.replicated (StatelessWorkerPlacement.cs:6 on device)
         self._replicated_hosts: dict[type, Any] = {}
@@ -1052,6 +1069,15 @@ class VectorRuntime:
             # breaking outstanding futures; nothing left to resolve
             pass
 
+    def _post_idle(self, seconds: float) -> None:
+        """Tick worker: a piece of a long wait for a job, observed on the
+        loop (the registry is loop-confined)."""
+        try:
+            self._loop.call_soon_threadsafe(self.stats.observe,
+                                            _WORKER_IDLE, seconds)
+        except RuntimeError:
+            pass  # loop closed: nobody reads it
+
     def _submit_job(self, job: _TickJob) -> None:
         self._bind_loop()
         self._inflight += 1
@@ -1131,11 +1157,13 @@ class VectorRuntime:
         loop-side failure here fails the batch's futures; it never
         leaves callers hanging."""
         st = self.stats
-        lp = self.loop_prof
-        if lp is not None:
-            # resolving futures and replaying the worker's observations
-            # is tick scheduling work on the loop, like the claim
-            lp.set_category("tick_schedule")
+        with StageSpan(st, "engine.complete", tick=job.tick) \
+                if st is not None else NO_SPAN:
+            self._finish_job(job, host, err)
+
+    def _finish_job(self, job: _TickJob, host, err) -> None:
+        """:meth:`_complete_job` inside its ``engine.complete`` span."""
+        st = self.stats
         ob = job.outbox
         if err is None and ob is not None and ob.fresh is None:
             # a sending job back from its tick: its messages wait for
@@ -1251,11 +1279,6 @@ class VectorRuntime:
         self._tick_scheduled = False
         if not self.pending:
             return
-        lp = self.loop_prof
-        if lp is not None:
-            # claiming, conflict defer, rescheduling and the worker
-            # hand-off are tick scheduling work on the loop
-            lp.set_category("tick_schedule")
         st = self.stats
         busy = self._inflight_groups
         held = 0

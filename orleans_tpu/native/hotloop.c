@@ -14,13 +14,25 @@
  * window-category dict.
  *
  * Division of labour: the Runner owns the HOT state (mark / last_end /
- * win_start / top_min / depth / closed scalars, the current category +
- * label, the open window's category->seconds dict) and exposes every
- * field as a writable member, so the Python LoopProfiler's slow paths
- * (window finalize, flight-recorder trigger, flush, enter/exit token
- * discipline) keep operating on the very same state through delegating
- * properties.  The two rare epilogue branches — top-K admission and
- * window finalize — call back into the Python profiler.
+ * win_start / top_min / next_slow / stop_s / depth / closed / adopted
+ * scalars, the current category + label, the open window's
+ * category->seconds dict) and exposes every field as a writable member,
+ * so the Python LoopProfiler's slow paths (the fold into the stats
+ * registry, window finalize, flight-recorder trigger, flush, enter/exit
+ * token discipline) keep operating on the very same state through
+ * delegating properties.  The two rare epilogue branches — a slow
+ * callback (top-K admission, or a stop: longer than stop_s) and the
+ * periodic slow path (next_slow) — call back into the Python profiler.
+ *
+ * A callback that switches category (set_category) keeps its segments'
+ * seconds by category (up to NSEG), so a slow callback is recorded under
+ * the category that held most of it: the owner of a stop.
+ *
+ * ``adopted``: the callback that installed the account was already
+ * running when the hooks went in, so no runner opened it; install marks
+ * it open (depth 1, adopted 1) and the next runner entry — which the
+ * loop only makes once that callback has returned — closes it there, so
+ * its time is busy and not idle.
  *
  * Clock: CLOCK_MONOTONIC, the same base CPython uses for
  * time.perf_counter on Linux, so C-side stamps and Python-side stamps
@@ -44,7 +56,9 @@ static inline double mono_clock(void) {
 }
 
 /* interned keys / method names, set at module init */
-static PyObject *s_idle, *s_other, *s_record_top, *s_finalize_window;
+static PyObject *s_idle, *s_other, *s_record_top, *s_slow;
+
+#define NSEG 8
 
 typedef struct {
     PyObject_HEAD
@@ -54,8 +68,15 @@ typedef struct {
     double win_start;   /* current window start */
     double top_min;     /* top-K admission bar */
     double window;      /* seconds per occupancy slice */
+    double next_slow;   /* the periodic slow path is due at this time */
+    double stop_s;      /* a callback longer than this is a stop */
+    double start;       /* start of the open callback */
     int depth;          /* >0 while inside a wrapped callback */
     int closed;         /* uninstalled: pass callbacks straight through */
+    int adopted;        /* the open callback is the one that installed */
+    int nseg;           /* categories the open callback switched through */
+    PyObject *seg_cat[NSEG]; /* their categories (owned) */
+    double seg_s[NSEG];      /* and seconds */
     PyObject *cur;      /* category accruing since mark (str) */
     PyObject *cb_label; /* label for the top-K record, or None */
     PyObject *win_cats; /* dict: category -> seconds (open window) */
@@ -90,18 +111,88 @@ fail:
 }
 
 /* call profiler.<name>(...) with any pending exception preserved
- * (a2/a3 may be NULL — ObjArgs terminates at the first NULL) */
+ * (a2..a4 may be NULL — ObjArgs terminates at the first NULL) */
 static void call_slow_path(Runner *r, PyObject *name, PyObject *a1,
-                           PyObject *a2, PyObject *a3) {
+                           PyObject *a2, PyObject *a3, PyObject *a4) {
     PyObject *exc_type, *exc_val, *exc_tb;
     PyErr_Fetch(&exc_type, &exc_val, &exc_tb);
     PyObject *res = PyObject_CallMethodObjArgs(r->profiler, name, a1, a2,
-                                               a3, NULL);
+                                               a3, a4, NULL);
     if (res == NULL)
         PyErr_WriteUnraisable(r->profiler);
     else
         Py_DECREF(res);
     PyErr_Restore(exc_type, exc_val, exc_tb);
+}
+
+/* book d seconds of the open callback to its current category, in the
+ * window and among the callback's own segments */
+static void seg_add(Runner *r, double d) {
+    dict_add(r->win_cats, r->cur, d);
+    for (int i = 0; i < r->nseg; i++) {
+        if (r->seg_cat[i] == r->cur) {
+            r->seg_s[i] += d;
+            return;
+        }
+    }
+    if (r->nseg < NSEG) {
+        r->seg_cat[r->nseg] = Py_NewRef(r->cur);
+        r->seg_s[r->nseg++] = d;
+    }
+}
+
+static void seg_clear(Runner *r) {
+    for (int i = 0; i < r->nseg; i++)
+        Py_CLEAR(r->seg_cat[i]);
+    r->nseg = 0;
+}
+
+/* the epilogue of one callback (``cb`` is what the top-K record
+ * describes): accrue its last segment, record it if it was slow, run the
+ * periodic slow path when due.  The caller holds any pending exception. */
+static void close_callback(Runner *r, PyObject *cb, double end) {
+    r->depth = 0;
+    double d = end - r->mark;
+    double dur = end - r->start;
+    if (r->nseg && (dur > r->top_min || dur > r->stop_s)) {
+        if (d > 0.0)
+            seg_add(r, d);
+    } else if (d > 0.0) {
+        dict_add(r->win_cats, r->cur, d);
+    }
+    r->last_end = end;
+    if (dur > r->top_min || dur > r->stop_s) {
+        /* a slow callback (rare: the top-K bar rises to the K-th
+         * slowest as the window fills; a stop is longer than the switch
+         * interval).  Recorded under the category that held most of
+         * it, at its start offset WITHIN the open window, so the
+         * Perfetto flame row places it exactly. */
+        PyObject *owner = r->cur;
+        double most = -1.0;
+        for (int i = 0; i < r->nseg; i++) {
+            if (r->seg_s[i] > most) {
+                most = r->seg_s[i];
+                owner = r->seg_cat[i];
+            }
+        }
+        Py_INCREF(owner);
+        PyObject *durf = PyFloat_FromDouble(dur);
+        PyObject *off = PyFloat_FromDouble(r->start - r->win_start);
+        if (durf != NULL && off != NULL)
+            call_slow_path(r, s_record_top, cb, durf, off, owner);
+        Py_XDECREF(durf);
+        Py_XDECREF(off);
+        Py_DECREF(owner);
+    }
+    seg_clear(r);
+    if (end >= r->next_slow) {
+        /* the fold into the stats registry and the window's finalize */
+        PyObject *endf = PyFloat_FromDouble(end);
+        if (endf != NULL) {
+            call_slow_path(r, s_slow, endf, NULL, NULL, NULL);
+            Py_DECREF(endf);
+        }
+    }
 }
 
 static PyObject *runner_vectorcall(PyObject *self, PyObject *const *args,
@@ -117,7 +208,7 @@ static PyObject *runner_vectorcall(PyObject *self, PyObject *const *args,
     PyObject *cb = args[0];
     if (r->closed)
         return PyObject_Vectorcall(cb, args + 1, nargs - 1, NULL);
-    if (r->depth) {
+    if (r->depth && !r->adopted) {
         /* nested invocation (a wrapped fn called synchronously from
          * inside another): inner boundaries are a no-op */
         r->depth++;
@@ -126,12 +217,18 @@ static PyObject *runner_vectorcall(PyObject *self, PyObject *const *args,
         return res;
     }
     double now = mono_clock();
+    if (r->depth) {
+        /* the loop runs a callback only after the one before returned:
+         * the callback that installed the account ended by now */
+        r->adopted = 0;
+        close_callback(r, Py_None, now);
+    }
     double gap = now - r->last_end;
     if (gap > 0.0)
         /* the loop was in select() between callbacks: idle */
         dict_add(r->win_cats, s_idle, gap);
     r->depth = 1;
-    r->mark = now;
+    r->mark = r->start = now;
     PyObject *cur;
     if (PyContextVar_Get(r->cat_var, s_other, &cur) < 0) {
         PyErr_WriteUnraisable(self);
@@ -148,41 +245,15 @@ static PyObject *runner_vectorcall(PyObject *self, PyObject *const *args,
     PyObject *exc_type = NULL, *exc_val = NULL, *exc_tb = NULL;
     if (res == NULL)
         PyErr_Fetch(&exc_type, &exc_val, &exc_tb);
-
-    double end = mono_clock();
-    r->depth = 0;
-    double d = end - r->mark;
-    if (d > 0.0)
-        dict_add(r->win_cats, r->cur, d);
-    r->last_end = end;
-    if (end - now > r->top_min) {
-        /* top-K slow-callback record (rare: the bar rises to the K-th
-         * slowest as the window fills).  The third argument is the
-         * callback's start offset WITHIN the open window, so the
-         * Perfetto flame row places the record exactly instead of
-         * laying durations end-to-end from the window start. */
-        PyObject *dur = PyFloat_FromDouble(end - now);
-        PyObject *off = PyFloat_FromDouble(now - r->win_start);
-        if (dur != NULL && off != NULL)
-            call_slow_path(r, s_record_top, cb, dur, off);
-        Py_XDECREF(dur);
-        Py_XDECREF(off);
-    }
-    if (end - r->win_start >= r->window) {
-        PyObject *endf = PyFloat_FromDouble(end);
-        if (endf != NULL) {
-            call_slow_path(r, s_finalize_window, endf, NULL, NULL);
-            Py_DECREF(endf);
-        }
-    }
+    close_callback(r, cb, mono_clock());
     if (res == NULL)
         PyErr_Restore(exc_type, exc_val, exc_tb);
     return res; /* NULL propagates the callback's exception unchanged */
 }
 
 /* set_category(category, label=None): accrue to the current category up
- * to now, then switch — the engine segments one tick callback into
- * staging/transfer/sync slices with this, several times per tick. */
+ * to now, then switch — a stage span opening or closing inside the
+ * callback, or a turn labelling its task. */
 static PyObject *runner_set_category(PyObject *self, PyObject *const *args,
                                      Py_ssize_t nargs) {
     Runner *r = (Runner *)self;
@@ -196,11 +267,28 @@ static PyObject *runner_set_category(PyObject *self, PyObject *const *args,
     double now = mono_clock();
     double d = now - r->mark;
     if (d > 0.0)
-        dict_add(r->win_cats, r->cur, d);
+        seg_add(r, d);
     r->mark = now;
     Py_XSETREF(r->cur, Py_NewRef(args[0]));
     if (nargs == 2 && args[1] != Py_None)
         Py_XSETREF(r->cb_label, Py_NewRef(args[1]));
+    Py_RETURN_NONE;
+}
+
+/* adopt(): the calling callback — the one installing the account — is
+ * open from now on; the next runner entry closes it */
+static PyObject *runner_adopt(PyObject *self, PyObject *unused) {
+    Runner *r = (Runner *)self;
+    if (r->depth || r->closed)
+        Py_RETURN_NONE;
+    PyObject *cur;
+    if (PyContextVar_Get(r->cat_var, s_other, &cur) < 0)
+        return NULL;
+    Py_XSETREF(r->cur, cur);
+    Py_XSETREF(r->cb_label, Py_NewRef(Py_None));
+    r->mark = r->start = mono_clock();
+    r->depth = 1;
+    r->adopted = 1;
     Py_RETURN_NONE;
 }
 
@@ -214,8 +302,13 @@ static int runner_init(PyObject *self, PyObject *args, PyObject *kw) {
     r->mark = r->last_end = r->win_start = now;
     r->top_min = 0.0;
     r->window = 1.0;
+    r->next_slow = now + 1.0;
+    r->stop_s = 0.005;
+    r->start = now;
     r->depth = 0;
     r->closed = 0;
+    r->adopted = 0;
+    seg_clear(r);
     Py_XSETREF(r->cur, Py_NewRef(s_other));
     Py_XSETREF(r->cb_label, Py_NewRef(Py_None));
     PyObject *d = PyDict_New();
@@ -234,6 +327,8 @@ static int runner_traverse(PyObject *self, visitproc visit, void *arg) {
     Py_VISIT(r->win_cats);
     Py_VISIT(r->cat_var);
     Py_VISIT(r->profiler);
+    for (int i = 0; i < r->nseg; i++)
+        Py_VISIT(r->seg_cat[i]);
     return 0;
 }
 
@@ -244,6 +339,7 @@ static int runner_clear(PyObject *self) {
     Py_CLEAR(r->win_cats);
     Py_CLEAR(r->cat_var);
     Py_CLEAR(r->profiler);
+    seg_clear(r);
     return 0;
 }
 
@@ -264,6 +360,14 @@ static PyMemberDef runner_members[] = {
      "top-K admission bar"},
     {"window", T_DOUBLE, offsetof(Runner, window), 0,
      "seconds per occupancy slice"},
+    {"next_slow", T_DOUBLE, offsetof(Runner, next_slow), 0,
+     "the periodic slow path is due at this time"},
+    {"stop_s", T_DOUBLE, offsetof(Runner, stop_s), 0,
+     "a callback longer than this is a stop"},
+    {"start", T_DOUBLE, offsetof(Runner, start), 0,
+     "start of the open callback (tells one callback from the next)"},
+    {"adopted", T_INT, offsetof(Runner, adopted), 0,
+     "the open callback is the one that installed the account"},
     {"depth", T_INT, offsetof(Runner, depth), 0,
      ">0 while inside a wrapped callback"},
     {"closed", T_INT, offsetof(Runner, closed), 0,
@@ -284,6 +388,9 @@ static PyMethodDef runner_methods[] = {
      METH_FASTCALL,
      "set_category(category, label=None): accrue and switch the "
      "attribution category within the current callback."},
+    {"adopt", (PyCFunction)runner_adopt, METH_NOARGS,
+     "adopt(): book the calling (unwrapped) callback from now on; the "
+     "next runner entry closes it."},
     {NULL, NULL, 0, NULL},
 };
 
@@ -314,8 +421,8 @@ PyMODINIT_FUNC PyInit__hotloop(void) {
     s_idle = PyUnicode_InternFromString("idle");
     s_other = PyUnicode_InternFromString("other");
     s_record_top = PyUnicode_InternFromString("_record_top");
-    s_finalize_window = PyUnicode_InternFromString("_finalize_window");
-    if (!s_idle || !s_other || !s_record_top || !s_finalize_window)
+    s_slow = PyUnicode_InternFromString("_slow");
+    if (!s_idle || !s_other || !s_record_top || !s_slow)
         return NULL;
     if (PyType_Ready(&RunnerType) < 0)
         return NULL;

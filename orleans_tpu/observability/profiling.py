@@ -28,8 +28,18 @@ actually goes, continuously and cheaply enough to leave on:
   :data:`LOOP_CATEGORY` contextvar (task steps run in the task's context,
   so one ``enter``/``mark_loop_category`` at the top of a turn/pump task
   labels every later step of that task); instrumented sites segment
-  finer with :meth:`LoopProfiler.set_category` (the engine books its
-  claim and completion callbacks to ``tick_schedule``).
+  finer with :meth:`LoopProfiler.set_category`. A stage span
+  (``observability.stats.StageSpan``) opened on the loop's thread keys
+  loop time on itself: ``stage:<name>``, innermost open stage first, so
+  a stage books its self time.
+* **The loop account** (a metrics-enabled silo installs it; the
+  profiler's ``stats``): at a callback boundary at least every
+  :data:`FOLD_S` of loop time the seconds are folded into the silo's
+  ``StatsRegistry`` — ``loop.idle.seconds`` (in ``select()``),
+  ``loop.stage.<stage>.seconds``, ``loop.other.<category>.seconds`` —
+  which sum to the wall clock over any interval; a callback longer than
+  the interpreter's switch interval is a stop: ``loop.stop.seconds`` and
+  the counter ``loop.stops.<stage or category>`` of what held most of it.
 * **Flight recorder**: per-window occupancy slices plus the top-K
   slowest callbacks (category + grain class/method label when the turn
   declared one) land in a bounded ring; :meth:`LoopProfiler.trigger`
@@ -37,9 +47,12 @@ actually goes, continuously and cheaply enough to leave on:
   queue-wait-trend breach, tail-retained traces — rate-limited per
   reason, into a bounded snapshot deque the management surface serves.
 
-Disabled (``SiloConfig.profiling_enabled=False``, the default) nothing is
-installed: the loop keeps its class methods, hot paths pay one ``None``
-check per site, and the off path is structurally zero-overhead.
+Disabled (``SiloConfig.profiling_enabled`` and ``metrics_enabled`` both
+False, the default) nothing is installed: the loop keeps its class
+methods, hot paths pay one ``None`` check per site, and the off path is
+structurally zero-overhead. ``metrics_enabled`` alone installs the
+account; ``profiling_enabled`` adds the flight-recorder ring, the
+triggers and the management payload.
 """
 
 from __future__ import annotations
@@ -56,6 +69,8 @@ from collections import deque
 from typing import Iterator
 
 import jax
+
+from .stats import bind_loop_account
 
 log = logging.getLogger("orleans.profiling")
 
@@ -85,15 +100,14 @@ __all__ = ["Profiler", "LoopProfiler", "LOOP_CATEGORIES", "LOOP_CATEGORY",
 # Host-loop occupancy profiler
 # ---------------------------------------------------------------------------
 
-# the named occupancy buckets loop time is attributed into (staging,
-# operand upload, kernel dispatch and the host materialize of a device
-# tick run on the engine's worker thread: they are not loop time; with
-# the ``offloop_tick`` lever off the whole job books to tick_schedule)
+# the named occupancy buckets loop time in no stage span is attributed
+# into (a stage span keys the time it holds on ``stage:<name>``: the
+# engine's claim and completion are engine.claim / engine.complete;
+# staging, operand upload, kernel dispatch and the host materialize of a
+# device tick run on the engine's worker thread and are not loop time)
 LOOP_CATEGORIES = (
     "turns",          # host grain turns (dispatcher._run_turn)
     "timers",         # __timer__ tick turns + timer machinery
-    "tick_schedule",  # engine tick: claiming, conflict defer, the worker
-                      # hand-off, and future resolution at completion
     "pump",           # socket pump + wire decode + batched routing
     "egress",         # outbound wire: response/request encode + sender
                       # writes (per-endpoint sender tasks, gateway
@@ -128,6 +142,27 @@ LOOP_CATEGORY: contextvars.ContextVar[str] = contextvars.ContextVar(
 # unchanged.
 _EAGER_TASKS = getattr(getattr(asyncio, "tasks", None), "_eager_tasks", None)
 
+# the account folds into its stats registry at a callback boundary at
+# least this often (seconds of loop time): a 20 s window's delta is then
+# exact to a quarter of a per cent with no read-side flush
+FOLD_S = 0.05
+LOOP_STOP = "loop.stop.seconds"
+
+
+def _stat_name(category: str) -> str:
+    """The registry histogram a loop category folds into."""
+    if category == "idle":
+        return "loop.idle.seconds"
+    if category.startswith("stage:"):
+        return f"loop.stage.{category[6:]}.seconds"
+    return f"loop.other.{category}.seconds"
+
+
+def _stop_name(category: str) -> str:
+    """The counter of the stops a stage or category held most of."""
+    return "loop.stops." + (category[6:] if category.startswith("stage:")
+                            else category)
+
 
 def mark_loop_category(category: str) -> None:
     """Tag the current task so its future steps default to ``category``
@@ -161,14 +196,16 @@ class LoopProfiler:
     ``window`` seconds of attribution roll into one slice dict appended
     to ``ring`` (the flight-recorder substrate); ``snapshots`` holds
     anomaly-triggered copies of the ring. ``totals`` accumulates per
-    category since install — the benchmark/management read."""
+    category since install — the management read. ``stats`` (set by
+    :meth:`attach`) is the loop account's registry: see the module doc."""
 
     __slots__ = ("window", "top_k", "trigger_interval", "ring",
                  "snapshots", "trigger_counts", "trigger_hooks", "totals",
-                 "last_shares", "closed", "started", "_win_start",
-                 "_win_cats", "_win_top", "_top_min", "_last_end",
-                 "_depth", "_mark", "_cur", "_cb_label",
-                 "_last_trigger")
+                 "last_shares", "closed", "started", "stats", "stop_s",
+                 "_win_start", "_win_cats", "_win_top", "_top_min",
+                 "_last_end", "_depth", "_mark", "_cur", "_cb_label",
+                 "_last_trigger", "_next_slow", "_start", "_adopted",
+                 "_segs", "_folded", "_names")
 
     def __init__(self, window: float = 1.0, ring: int = 120,
                  top_k: int = 8, trigger_interval: float = 1.0,
@@ -196,6 +233,16 @@ class LoopProfiler:
         self._cur = "other"      # category accruing since _mark
         self._cb_label: str | None = None
         self._last_trigger: dict[str, float] = {}
+        self.stats = None
+        # a callback longer than the interpreter's switch interval is a
+        # stop: every other thread (the tick worker) waited for it too
+        self.stop_s = sys.getswitchinterval()
+        self._next_slow = now + window  # the periodic slow path (_slow)
+        self._start = now        # start of the open callback
+        self._adopted = False    # the open callback is the installing one
+        self._segs: dict[str, float] = {}  # open callback's seconds by cat
+        self._folded: dict[str, float] = {}  # window seconds already folded
+        self._names: dict[str, str] = {}     # category -> histogram name
 
     # -- interposition side ------------------------------------------------
     def _entry(self):
@@ -217,14 +264,16 @@ class LoopProfiler:
         on, so the steady state is kept flat and allocation-free: two
         clock reads, one contextvar get, two dict upserts (idle gap +
         category slice — cumulative ``totals`` are folded in once per
-        window, not per callback), zero extra frames. The top-K record
+        window, not per callback), zero extra frames. The slow-callback
         path only engages for callbacks slower than the current window's
-        admission bar (``_top_min``); ``_perf``/``_get_cat`` are
-        default-arg locals. A closed profiler passes straight through
-        (callbacks scheduled before uninstall may still run after)."""
-        if self.closed or self._depth:
-            if self.closed:
-                return cb(*args)
+        admission bar (``_top_min``) or the stop threshold, and the fold
+        and the window's finalize only when ``_next_slow`` is due;
+        ``_perf``/``_get_cat`` are default-arg locals. A closed profiler
+        passes straight through (callbacks scheduled before uninstall may
+        still run after)."""
+        if self.closed:
+            return cb(*args)
+        if self._depth and not self._adopted:
             # nested invocation (a wrapped fn called synchronously from
             # inside another): inner boundaries are a no-op
             self._depth += 1
@@ -233,6 +282,11 @@ class LoopProfiler:
             finally:
                 self._depth -= 1
         now = _perf()
+        if self._depth:
+            # the loop runs a callback only after the one before
+            # returned: the callback that installed the account ended
+            self._adopted = False
+            self._close(None, now)
         gap = now - self._last_end
         wc = self._win_cats
         if gap > 0.0:
@@ -243,16 +297,27 @@ class LoopProfiler:
             except KeyError:
                 wc["idle"] = gap
         self._depth = 1
-        self._mark = now
+        self._mark = self._start = now
         self._cur = _get_cat()
         self._cb_label = None
         try:
             return cb(*args)
         finally:
-            end = _perf()
-            self._depth = 0
-            d = end - self._mark
-            if d > 0.0:
+            self._close(cb, _perf())
+
+    def _close(self, cb, end: float) -> None:
+        """One callback's epilogue (the C runner's ``close_callback``):
+        its last segment, the slow-callback record, the periodic slow
+        path."""
+        self._depth = 0
+        d = end - self._mark
+        dur = end - self._start
+        slow = dur > self._top_min or dur > self.stop_s
+        segs = self._segs
+        if d > 0.0:
+            if segs and slow:
+                self._seg_add(d)
+            else:
                 # re-read the dict slot: robust against anything inside
                 # cb ever rebinding the open window
                 wc = self._win_cats
@@ -261,29 +326,49 @@ class LoopProfiler:
                     wc[cat] += d
                 except KeyError:
                     wc[cat] = d
-            self._last_end = end
-            if end - now > self._top_min:
-                # top-K slow-callback record (rare by construction: the
-                # bar rises to the K-th slowest as the window fills)
-                self._record_top(cb, end - now, now - self._win_start)
-            if end - self._win_start >= self.window:
-                self._finalize_window(end)
+        self._last_end = end
+        if slow:
+            # recorded under what held most of it (rare by construction:
+            # the top-K bar rises to the K-th slowest as the window
+            # fills; a stop is longer than the switch interval)
+            owner = max(segs, key=segs.get) if segs else self._cur
+            self._record_top(cb, dur, self._start - self._win_start, owner)
+        if segs:
+            segs.clear()
+        if end >= self._next_slow:
+            self._slow(end)
 
-    def _record_top(self, cb, dur: float,
-                    offset: float | None = None) -> None:
-        """``offset`` = the callback's START relative to the open
-        window's start (stamped by the hot path — C runner or the
-        Python reference — so the Perfetto flame row places each record
+    def _seg_add(self, d: float) -> None:
+        cat = self._cur
+        wc = self._win_cats
+        wc[cat] = wc.get(cat, 0.0) + d
+        self._segs[cat] = self._segs.get(cat, 0.0) + d
+
+    def _record_top(self, cb, dur: float, offset: float | None = None,
+                    owner: str | None = None) -> None:
+        """A slow callback: into the window's top-K if it passes the
+        bar, and — longer than ``stop_s``, with the account on — a stop
+        (``loop.stop.seconds`` and ``loop.stops.<owner>``). ``owner`` is
+        the category that held most of it (the current one by default).
+        ``offset`` = the callback's START relative to the open window's
+        start (stamped by the hot path — C runner or the Python
+        reference — so the Perfetto flame row places each record
         exactly instead of laying durations end-to-end from the window
         start). None only from legacy callers; the exporter falls back
         to cursor placement then."""
-        top = self._win_top
-        top.append((dur, self._cur,
-                    self._cb_label or _describe_callback(cb), offset))
-        if len(top) > self.top_k:
-            top.sort(key=lambda t: t[0], reverse=True)
-            del top[self.top_k:]
-            self._top_min = top[-1][0]
+        cat = self._cur if owner is None else owner
+        if dur > self._top_min:
+            top = self._win_top
+            top.append((dur, cat,
+                        self._cb_label or _describe_callback(cb), offset))
+            if len(top) > self.top_k:
+                top.sort(key=lambda t: t[0], reverse=True)
+                del top[self.top_k:]
+                self._top_min = top[-1][0]
+        st = self.stats
+        if dur > self.stop_s and st is not None:
+            st.observe(LOOP_STOP, dur)
+            st.increment(_stop_name(cat))
 
     def _accrue(self, now: float) -> None:
         d = now - self._mark
@@ -308,16 +393,19 @@ class LoopProfiler:
         now = _perf()
         d = now - self._mark
         if d > 0.0:
-            wc = self._win_cats
-            cat = self._cur
-            try:
-                wc[cat] += d
-            except KeyError:
-                wc[cat] = d
+            self._seg_add(d)
         self._mark = now
         self._cur = category
         if label is not None:
             self._cb_label = label
+
+    def switch(self, category: str) -> str:
+        """Switch the current callback's category and return the one it
+        had, for the caller to switch back to before the callback ends
+        (a stage span that opens and closes in one callback)."""
+        prev = self._cur
+        self.set_category(category)
+        return prev
 
     def enter(self, category: str, label: str | None = None):
         """Category for the current slice AND the current task's future
@@ -339,7 +427,9 @@ class LoopProfiler:
         which is where it physically ran. On interpreters without eager
         factories (the py3.10 reference environment) the registry does
         not exist, the guard is a single module-constant None test, and
-        the switch is exact as before."""
+        the switch is exact as before. The label still names the
+        creator's callback when nothing has (a slow callback that ran a
+        turn inline is that turn's, for the top-K)."""
         token = LOOP_CATEGORY.set(category)
         if _EAGER_TASKS is not None and self._depth:
             try:
@@ -347,16 +437,88 @@ class LoopProfiler:
             except RuntimeError:
                 t = None
             if t is not None and t in _EAGER_TASKS:
-                return token  # deferred: guarded eager boundary
+                # deferred: guarded eager boundary
+                if label is not None and self._cb_label is None:
+                    self._cb_label = label
+                return token
         self.set_category(category, label)
         return token
 
     def exit(self, token) -> None:
-        LOOP_CATEGORY.reset(token)
+        try:
+            LOOP_CATEGORY.reset(token)
+        except ValueError:
+            # closed in another context than it opened in (a span whose
+            # unit of work ends in a done-callback): that one never saw it
+            pass
         self.set_category(LOOP_CATEGORY.get())
+
+    def hold(self, category: str) -> tuple:
+        """:meth:`enter` for a stage span held across an ``await``;
+        :meth:`release` undoes it."""
+        prev, start = self._cur, self._start
+        return self.enter(category), prev, start
+
+    def release(self, held: tuple) -> None:
+        """Undo :meth:`hold`: a later step of the task books what its
+        context says; the callback that held goes back to what it was
+        booking (the nesting stage the held one closed inside)."""
+        token, prev, start = held
+        self.exit(token)
+        if start == self._start:
+            self.set_category(prev)
+
+    def adopt(self, _perf=time.perf_counter) -> None:
+        """Book the calling callback — the one installing the account,
+        which no runner opened — from now on: the next runner entry,
+        which the loop makes only once it has returned, closes it (its
+        time is busy, not idle)."""
+        if self._depth or self.closed:
+            return
+        self._cur = LOOP_CATEGORY.get()
+        self._cb_label = None
+        self._mark = self._start = _perf()
+        self._depth = 1
+        self._adopted = True
+
+    # -- the loop account (the stats registry) -----------------------------
+    def attach(self, stats) -> None:
+        """Fold this loop's seconds into ``stats`` from now on (every
+        :data:`FOLD_S` at the latest) and record its stops there."""
+        self.stats = stats
+        stats.histogram(LOOP_STOP)  # 0 and not absent without a stop
+        self._next_slow = min(self._next_slow,
+                              time.perf_counter() + FOLD_S)
+
+    def _slow(self, now: float) -> None:
+        """Due at ``_next_slow`` (a callback boundary): the fold, and
+        the window's finalize once it is full."""
+        if self.stats is not None:
+            self._fold()
+        if now - self._win_start >= self.window:
+            self._finalize_window(now)
+        nxt = self._win_start + self.window
+        self._next_slow = nxt if self.stats is None \
+            else min(nxt, now + FOLD_S)
+
+    def _fold(self) -> None:
+        """Observe what the open window accrued since the last fold."""
+        st = self.stats
+        folded = self._folded
+        names = self._names
+        for k, v in self._win_cats.items():
+            d = v - folded.get(k, 0.0)
+            if d > 0.0:
+                name = names.get(k)
+                if name is None:
+                    name = names[k] = _stat_name(k)
+                st.observe(name, d)
+                folded[k] = v
 
     # -- windows / flight recorder ----------------------------------------
     def _finalize_window(self, now: float) -> None:
+        if self.stats is not None:
+            self._fold()
         wall = now - self._win_start
         shares = ({k: round(v / wall, 4) for k, v in self._win_cats.items()}
                   if wall > 0 else {})
@@ -381,6 +543,7 @@ class LoopProfiler:
         })
         self.last_shares = shares
         self._win_cats = {}
+        self._folded = {}
         self._win_top = []
         self._top_min = 0.0
         self._win_start = now
@@ -487,6 +650,9 @@ class _NativeLoopProfiler(LoopProfiler):
     def set_category(self, category: str, label=None) -> None:
         self._c.set_category(category, label)
 
+    def adopt(self) -> None:
+        self._c.adopt()
+
 
 def _delegate(cname: str) -> property:
     return property(lambda self, _n=cname: getattr(self._c, _n),
@@ -497,7 +663,9 @@ for _name, _cname in (("window", "window"), ("closed", "closed"),
                       ("_win_start", "win_start"), ("_win_cats", "win_cats"),
                       ("_top_min", "top_min"), ("_last_end", "last_end"),
                       ("_depth", "depth"), ("_mark", "mark"),
-                      ("_cur", "cur"), ("_cb_label", "cb_label")):
+                      ("_cur", "cur"), ("_cb_label", "cb_label"),
+                      ("_next_slow", "next_slow"), ("stop_s", "stop_s"),
+                      ("_adopted", "adopted"), ("_start", "start")):
     setattr(_NativeLoopProfiler, _name, _delegate(_cname))
 del _name, _cname
 
@@ -521,14 +689,19 @@ def loop_profiler(loop) -> LoopProfiler | None:
 
 
 def install_loop_profiler(loop, *, window: float = 1.0, ring: int = 120,
-                          top_k: int = 8,
-                          trigger_interval: float = 1.0) -> LoopProfiler:
+                          top_k: int = 8, trigger_interval: float = 1.0,
+                          stats=None) -> LoopProfiler:
     """Interpose occupancy accounting on ``loop`` (idempotent +
     refcounted: silos sharing a loop share ONE profiler — occupancy is a
     loop property — and the last :func:`uninstall_loop_profiler` removes
-    the hooks). Instance-attribute shadowing keeps this py3.10-safe: no
-    loop subclass, no task factory, works on a loop that is already
-    running. ``call_later`` is covered through the patched ``call_at``;
+    the hooks). ``stats``: the registry the loop account folds into (the
+    first silo that brings one). Installed from the loop's own thread,
+    the stage spans of that thread key on it, callbacks scheduled or
+    registered before the install are wrapped where they wait, and the
+    callback running the install is booked from here on
+    (:meth:`LoopProfiler.adopt`). Instance-attribute shadowing keeps
+    this py3.10-safe: no loop subclass, no task factory, works on a loop
+    that is already running. ``call_later`` is covered through the patched ``call_at``;
     executor completions arrive via the patched ``call_soon_threadsafe``;
     selector IO-ready callbacks (transport ``_read_ready`` — the recv
     syscall + buffer feed that would otherwise land in the inter-callback
@@ -547,9 +720,16 @@ def install_loop_profiler(loop, *, window: float = 1.0, ring: int = 120,
     ent = _loop_profilers.get(loop)
     if ent is not None:
         ent[0] += 1
-        return ent[1]
+        prof = ent[1]
+        if stats is not None and prof.stats is None:
+            prof.attach(stats)
+        if ring > (prof.ring.maxlen or 0):
+            prof.ring = deque(prof.ring, maxlen=ring)
+        return prof
     prof = _profiler_class()(window=window, ring=ring, top_k=top_k,
                              trigger_interval=trigger_interval)
+    if stats is not None:
+        prof.attach(stats)
     # the ONE entry callable every schedule reuses (the C Runner when
     # native, the bound _run_cb otherwise): scheduling it with the real
     # callback as its first argument costs no closure/partial allocation
@@ -592,15 +772,36 @@ def install_loop_profiler(loop, *, window: float = 1.0, ring: int = 120,
         loop._add_reader = _add_reader
         loop._add_writer = _add_writer
         names += ["_add_reader", "_add_writer"]
+    _wrap_waiting(loop, run_cb)
     _loop_profilers[loop] = [1, prof, tuple(names)]
+    if asyncio._get_running_loop() is loop:
+        bind_loop_account(prof)
+        prof.adopt()
     log.info("loop profiler installed (window=%.2fs, ring=%d)", window, ring)
     return prof
 
 
+def _wrap_waiting(loop, run_cb) -> None:
+    """Wrap what was scheduled or registered before the hooks went in:
+    the handles waiting in the ready queue and among the timers, and the
+    selector's reader and writer handles (the self-pipe that wakes the
+    loop for ``call_soon_threadsafe``, a server's accept) — each would
+    otherwise run unseen and its time be booked as idle."""
+    handles = [*getattr(loop, "_ready", ()), *getattr(loop, "_scheduled", ())]
+    selector = getattr(loop, "_selector", None)
+    if selector is not None:
+        for key in selector.get_map().values():
+            handles.extend(h for h in key.data if h is not None)
+    for h in handles:
+        if not h._cancelled and h._callback is not run_cb:
+            h._callback, h._args = run_cb, (h._callback, *h._args)
+
+
 def uninstall_loop_profiler(loop) -> None:
     """Drop one reference; the last removes the instance-attribute hooks
-    (class methods take over again) and closes the profiler so
-    already-wrapped callbacks pass straight through."""
+    (class methods take over again), folds what the account still holds
+    and closes the profiler so already-wrapped callbacks pass straight
+    through."""
     ent = _loop_profilers.get(loop)
     if ent is None:
         return
@@ -609,6 +810,11 @@ def uninstall_loop_profiler(loop) -> None:
         return
     del _loop_profilers[loop]
     _, prof, names = ent
+    if prof.stats is not None:
+        prof._flush()
+        prof._fold()
+    if asyncio._get_running_loop() is loop:
+        bind_loop_account(None, prof)
     prof.closed = True
     for name in names:
         try:

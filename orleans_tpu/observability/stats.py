@@ -20,6 +20,7 @@ from jax.profiler import TraceAnnotation
 
 __all__ = ["StatsRegistry", "Histogram", "QueueWaitTrend", "CallSiteStats",
            "StageSpan", "NO_SPAN", "observe_or_defer", "open_stage_registry",
+           "bind_loop_account",
            "close_stage_registry", "STAGES", "FLUSH_STATS", "RECOVER_STATS",
            "DISPATCH_STATS", "REBALANCE_STATS", "INGEST_STATS",
            "INGEST_STAGES", "MESH_STATS", "EXCHANGE_STATS", "EGRESS_STATS", "EGRESS_STAGES",
@@ -67,10 +68,8 @@ REBALANCE_STATS = {
 #
 #   decode      wire.decode_message (native hotwire or pickle fallback);
 #               stamps received_at at decode end
-#   enqueue     arrival -> leaving the MessageCenter inbound queue
-#               (inline routing makes this ~0; a backlogged QoS category
-#               shows its queue dwell here); re-stamps before routing
-#   queue_wait  hand-off -> work start. Host tier: routing + mailbox +
+#   queue_wait  hand-off (re-stamped as it leaves the MessageCenter's
+#               routing) -> work start. Host tier: routing + mailbox +
 #               task scheduling, observed at turn start. Device tier:
 #               engine enqueue -> batch start (tick scheduling +
 #               conflict-deferred ticks), observed per item by the
@@ -83,15 +82,13 @@ REBALANCE_STATS = {
 # Host-tier turns end at queue_wait (execution is scheduler.turn_length);
 # device-tier requests continue through staging/transfer/tick. Everything
 # is gated on SiloConfig.metrics_enabled — one attr check when off.
-INGEST_STAGES = ("decode", "enqueue", "queue_wait", "staging", "transfer",
-                 "tick")
+INGEST_STAGES = ("decode", "queue_wait", "staging", "transfer", "tick")
 
 INGEST_STATS = {
     "decode": "ingest.decode.seconds",
     "decode_bytes": "ingest.decode.bytes",       # SIZE_BOUNDS histogram
     "frames": "ingest.frames",                   # counter: frames decoded
     "frame_batch": "ingest.frame_batch.size",    # COUNT_BOUNDS histogram
-    "enqueue": "ingest.enqueue.seconds",
     "queue_wait": "ingest.queue_wait.seconds",
     "turns": "ingest.turns",                     # counter: host turns timed
     "staging": "ingest.staging.seconds",
@@ -264,6 +261,13 @@ SLO_STATS = {
 #   ingest.tick.sync      dispatch return -> host materialize done
 #   engine.complete_hop   worker's call_soon_threadsafe -> _complete_job
 #                         entered (histogram only, crosses threads)
+#   engine.complete       _complete_job (loop): the replay of the worker's
+#                         observations, the outbox hand-off of a sending
+#                         job, and engine.resolve inside it
+#   engine.worker_idle    the tick worker's wait: its previous job's end
+#                         -> this job's dequeue (histogram only, stamped
+#                         on the worker, replayed through the job's sink;
+#                         a long wait is posted to the loop in pieces)
 #   engine.resolve        _resolve_batch (loop)
 #   egress.flush          EgressBatcher.flush (loop)
 #   flush                 one write-behind pass of hosting.flush_all
@@ -284,18 +288,23 @@ SLO_STATS = {
 #                         (beside it RECOVER_STATS: the messages that met
 #                         a fresh key, and the keys a pass read)
 #
-# Spans that stay open across an ``await`` (flush, flush.write, recover)
-# interleave with other work on the loop: their seconds are wall time of
-# the unit of work, not loop time, and they never become the thread's
-# current stage. Worker-thread spans take the job's ``sink`` and replay
-# loop-side. All of it is gated on SiloConfig.metrics_enabled: sites
-# guard on the registry being None and construct nothing when it is.
+# Spans that stay open across an ``await`` (flush, flush.write, recover,
+# exchange.activate) interleave with other work on the loop: their seconds
+# are wall time of the unit of work, not loop time, and they never become
+# the thread's current stage. Worker-thread spans take the job's ``sink``
+# and replay loop-side. The loop's own time is keyed on the loop-side
+# stages by the loop account (observability.profiling: loop.stage.<stage>
+# .seconds is a stage's self time on the loop, beside loop.idle.seconds
+# and loop.other.<category>.seconds). All of it is gated on
+# SiloConfig.metrics_enabled: sites guard on the registry being None and
+# construct nothing when it is.
 STAGES = ("pump.batch", "engine.claim", "engine.worker_queue",
           "engine.fence_wait", "ingest.staging", "ingest.transfer",
           "ingest.tick.dispatch", "ingest.tick.sync", "engine.complete_hop",
-          "engine.resolve", "egress.flush", "flush", "flush.locate",
-          "flush.gather", "flush.write", "recover", "exchange",
-          "exchange.route", "exchange.apply", "exchange.activate")
+          "engine.complete", "engine.resolve", "egress.flush", "flush",
+          "flush.locate", "flush.gather", "flush.write", "recover",
+          "exchange", "exchange.route", "exchange.apply",
+          "exchange.activate")
 
 FLUSH_STATS = {
     "rows": "vector.storage.flush.rows",   # COUNT_BOUNDS: rows per flush
@@ -324,9 +333,22 @@ _listening = False
 class _PerThread(threading.local):
     stage = None   # the innermost open (nesting) StageSpan of this thread
     home = None    # the registry open_stage_registry() gave this thread
+    account = None  # the loop account of this thread's loop, if installed
 
 
 _thread = _PerThread()
+
+
+# stage name -> the loop account's category for it (one string a stage)
+_STAGE_KEYS: dict[str, str] = {}
+
+
+def bind_loop_account(account, only=None) -> None:
+    """Key the calling thread's stage spans on ``account`` — the loop
+    profiler installed on this thread's running loop — or on nothing
+    (None; with ``only``, just where that one is bound)."""
+    if only is None or _thread.account is only:
+        _thread.account = account
 
 
 def observe_or_defer(sink, stats, key: str, value: float) -> None:
@@ -350,9 +372,19 @@ class StageSpan:
     loop-side, because the registry is loop-confined. ``nest=False`` for a
     span held across an ``await``: it does not become the thread's
     current stage (compiles are booked to the innermost *synchronous*
-    stage). ``unit`` names the unit of work on the annotation."""
+    stage). ``unit`` names the unit of work on the annotation.
 
-    __slots__ = ("name", "stats", "sink", "t0", "_ann", "_prev", "_nest")
+    On a thread whose loop has the loop account installed the span also
+    keys the loop's time on itself, ``stage:<name>``, so the innermost
+    open stage books its self time: a nesting span opens and closes in
+    one callback and switches the callback's category there and back; a
+    span held across an ``await`` is its task's category until it closes
+    (``LoopProfiler.hold``: task steps run in their task's context), so
+    it books only its own task's steps — and what the task schedules
+    while it is open, which copies that context: its own work."""
+
+    __slots__ = ("name", "stats", "sink", "t0", "_ann", "_prev", "_nest",
+                 "_acct", "_tok")
 
     def __init__(self, stats, name: str, sink: list | None = None,
                  nest: bool = True, **unit):
@@ -365,10 +397,22 @@ class StageSpan:
             _thread.stage = self
         self._ann = TraceAnnotation("otpu:" + name, **unit)
         self._ann.__enter__()
+        acct = self._acct = _thread.account
+        if acct is not None:
+            # the loop's time is keyed from here to the close's clock read
+            key = _STAGE_KEYS.get(name)
+            if key is None:
+                key = _STAGE_KEYS[name] = "stage:" + name
+            self._tok = acct.switch(key) if nest else acct.hold(key)
         self.t0 = time.perf_counter()
 
     def close(self) -> float:
         dt = time.perf_counter() - self.t0
+        if self._acct is not None:
+            if self._nest:
+                self._acct.set_category(self._tok)
+            else:
+                self._acct.release(self._tok)
         self._ann.__exit__(None, None, None)
         if self._nest:
             _thread.stage = self._prev

@@ -25,10 +25,7 @@ from ..core.ids import GrainId, SiloAddress
 from ..core.message import Category, Direction, Message
 from ..core.serialization import copy_call_body, copy_result
 from ..observability.stats import DISPATCH_STATS, StatsRegistry
-from ..observability.stats import INGEST_STATS as _INGEST
 from ..observability.tracing import mark_remote_if_traced
-
-_INGEST_ENQUEUE = _INGEST["enqueue"]
 from .activation import ActivationState
 from ..storage.core import StorageManager
 from .cancellation import TokenInterner
@@ -483,10 +480,9 @@ class MessageCenter:
         responses: list = []
         for m in msgs:
             if ist is not None and m.received_at is not None:
-                # ingest enqueue stage (~0 inline) — one clock read for
-                # the whole batch; re-stamped BEFORE routing, the last
-                # safe touch (routing may consume the envelope)
-                ist.observe(_INGEST_ENQUEUE, now - m.received_at)
+                # queue_wait is timed from here — one clock read for the
+                # whole batch; re-stamped BEFORE routing, the last safe
+                # touch (routing may consume the envelope)
                 m.received_at = now
             cat_counts[m.category] = cat_counts.get(m.category, 0) + 1
             if m.direction == Direction.RESPONSE:
@@ -554,16 +550,12 @@ class MessageCenter:
                       for c in Category}
 
     def _route(self, msg: Message) -> None:
-        ist = self._istats
-        if ist is not None and msg.received_at is not None:
-            # ingest enqueue stage: decode/arrival -> leaving the inbound
-            # queue (inline routing makes this ~0; a backlogged category
-            # shows its queue dwell here). Observed and re-stamped BEFORE
-            # routing — the dispatcher may consume (and even recycle) the
-            # envelope synchronously, so this is the last safe touch.
-            now = time.monotonic()
-            ist.observe(_INGEST_ENQUEUE, now - msg.received_at)
-            msg.received_at = now
+        if self._istats is not None and msg.received_at is not None:
+            # queue_wait is timed from leaving the inbound queue:
+            # re-stamped BEFORE routing — the dispatcher may consume (and
+            # even recycle) the envelope synchronously, so this is the
+            # last safe touch
+            msg.received_at = time.monotonic()
         self.silo.stats.increment(self._RECEIVED_STAT[msg.category])
         if msg.direction != Direction.RESPONSE and (
                 msg.target_silo is None
@@ -873,9 +865,13 @@ class Silo:
         self.metrics_server = None   # observability.metrics.MetricsHttpServer
         self.metrics_sink = None     # observability.export.OtlpMetricsSink
         # host-loop occupancy profiler (observability.profiling.
-        # LoopProfiler): installed at start when profiling_enabled — every
-        # hot-path site guards on this None, so the off path costs one
-        # attribute check
+        # LoopProfiler): installed at start when metrics_enabled (the loop
+        # account: loop.* seconds in the stats registry) or
+        # profiling_enabled; ``loop_prof`` is the same profiler where
+        # profiling_enabled adds the flight recorder, its triggers and the
+        # management payload — every hot-path site guards on these None,
+        # so the off path costs one attribute check
+        self.loop_account = None
         self.loop_prof = None
         # multi-loop ingress pool (runtime.multiloop.IngressLoopPool):
         # created by SocketFabric.register_silo when ingress_loops >= 2,
@@ -997,7 +993,7 @@ class Silo:
         if self.config.eager_turns:
             _install_eager_factory(asyncio.get_running_loop())
             self._eager_installed = True
-        if self.config.profiling_enabled:
+        if self.config.profiling_enabled or self.config.metrics_enabled:
             self._install_loop_profiler(asyncio.get_running_loop())
         self.message_center.start()          # RuntimeServices
         self.catalog.start()
@@ -1184,17 +1180,17 @@ class Silo:
             # control returns to the loop below). Before the client
             # close so resolved ticks still reach their callers.
             self.vector.shutdown_worker()
-        if self.loop_prof is not None:
+        if self.loop_account is not None:
             from ..observability.profiling import (loop_profiler,
                                                    uninstall_loop_profiler)
             if self._flight_hook is not None:
                 try:
-                    self.loop_prof.trigger_hooks.remove(self._flight_hook)
+                    self.loop_account.trigger_hooks.remove(self._flight_hook)
                 except ValueError:
                     pass
                 self._flight_hook = None
             uninstall_loop_profiler(asyncio.get_running_loop())
-            self.loop_prof = None
+            self.loop_account = self.loop_prof = None
             self.dispatcher._loop_prof = None
             self.storage_manager.loop_prof = None
             if hasattr(self.fabric, "loop_prof"):
@@ -1204,8 +1200,6 @@ class Silo:
                 # hook a surviving silo's egress attribution still needs
                 self.fabric.loop_prof = loop_profiler(
                     asyncio.get_running_loop())
-            if self.vector is not None:
-                self.vector.loop_prof = None
         self.message_center.stop()
         self.runtime_client.close()
         self.fabric.unregister_silo(self, dead=not graceful)
@@ -1253,28 +1247,38 @@ class Silo:
 
     def _install_loop_profiler(self, loop) -> None:
         """Install (or join) the loop's occupancy profiler and wire this
-        silo's consumers: per-category occupancy gauges, the dispatcher/
-        engine/storage category hooks, the tail-retention flight trigger,
-        and the telemetry sink hook. Co-hosted silos on one loop share
-        one profiler (occupancy is a loop property); install is
-        refcounted, so the last silo to stop removes the interposition."""
+        silo's consumers: the loop account's registry (metrics_enabled),
+        and — with profiling_enabled — the dispatcher/storage/fabric
+        category hooks, per-category occupancy gauges, the
+        tail-retention flight trigger and the telemetry sink hook.
+        Co-hosted silos on one loop share one profiler (occupancy is a
+        loop property); install is refcounted, so the last silo to stop
+        removes the interposition."""
         from ..observability.profiling import (LOOP_CATEGORIES,
                                                install_loop_profiler)
         cfg = self.config
         lp = install_loop_profiler(
-            loop, window=cfg.profiling_window, ring=cfg.profiling_ring,
+            loop, window=cfg.profiling_window,
+            ring=cfg.profiling_ring if cfg.profiling_enabled else 0,
             top_k=cfg.profiling_top_k,
-            trigger_interval=cfg.profiling_trigger_interval)
+            trigger_interval=cfg.profiling_trigger_interval,
+            stats=self.stats if cfg.metrics_enabled else None)
+        self.loop_account = lp
+        if not cfg.profiling_enabled:
+            # the account alone: stage spans key the loop's time, and
+            # the time in no stage keeps the category of the task or
+            # callback it runs in (a named slice inside a stage would
+            # take the stage's self time)
+            return
         self.loop_prof = lp
-        # cached refs so the hot paths pay one attribute load
+        # cached refs so the hot paths pay one attribute load: host turns
+        # label themselves "turns"/"timers", storage IO "storage"
         self.dispatcher._loop_prof = lp
         self.storage_manager.loop_prof = lp
         if hasattr(self.fabric, "loop_prof"):
             # socket fabric: the inline client-route encode+write books
             # its slice under "egress" (the sharded-egress A/B signal)
             self.fabric.loop_prof = lp
-        if self.vector is not None:
-            self.vector.loop_prof = lp
         for cat in LOOP_CATEGORIES:
             # live per-category occupancy of the LAST completed window
             # (the Prometheus gauges; cumulative shares ride ctl_loop_profile)

@@ -1078,13 +1078,13 @@ class SocketFabric:
             # ingest stage metrics (observability.stats.INGEST_STATS):
             # decode is timed inside decode_frames (which
             # also stamps the envelope's received_at) and frames-per-read
-            # lands in the batch histogram. The later stages (enqueue/
-            # queue_wait) are observed downstream where the envelope is
+            # lands in the batch histogram. The later stage (queue_wait)
+            # is observed downstream where the envelope is
             # provably still live — routing can consume a message
             # synchronously (inline turns, response correlation +
             # recycle), so NOTHING here may touch msg after routing.
             ist = silo.ingest_stats
-            if silo.loop_prof is not None:
+            if silo.loop_account is not None:
                 # loop-occupancy attribution: this handler task's steps —
                 # socket reads, wire decode, batched routing (including
                 # inline turns' first synchronous stretch until the turn

@@ -338,7 +338,7 @@ def decode_message(headers: bytes, body: bytes, stats=None) -> Message:
         stats.histogram_with(_DECODE_BYTES, _SIZE_BOUNDS).observe(
             len(headers) + len(body))
         stats.increment(_FRAMES)
-        msg.received_at = now  # ingest stage stamp (enqueue measures next)
+        msg.received_at = now  # ingest stage stamp (queue_wait measures next)
     return msg
 
 

@@ -138,8 +138,9 @@ def test_the_tick_lever_has_one_field_and_one_default():
 
 def test_dispatch_imports_nothing_from_the_loop_profiler():
     """Layering: the device tier is observed, it does not reach up into
-    an observer. ``VectorRuntime.loop_prof`` is injected by the silo;
-    no module under ``orleans_tpu/dispatch`` imports
+    an observer. The engine's loop time is keyed by its stage spans (the
+    loop account), and it holds no profiler: no ``loop_prof`` attribute,
+    and no module under ``orleans_tpu/dispatch`` imports
     ``observability.profiling``."""
     import ast
     import pathlib
@@ -161,6 +162,9 @@ def test_dispatch_imports_nothing_from_the_loop_profiler():
             if any(n.endswith("observability.profiling") for n in names):
                 bad.append((f.name, node.lineno))
     assert not bad, bad
+    from orleans_tpu.dispatch import VectorRuntime
+    from orleans_tpu.parallel import make_mesh
+    assert not hasattr(VectorRuntime(mesh=make_mesh(1)), "loop_prof")
 
 
 def test_log_options_dumps_every_field(caplog):

@@ -224,17 +224,18 @@ def test_install_refcount_and_uninstall():
 async def test_occupancy_under_concurrent_turns_and_ticks(offloop):
     """Concurrent host turns + device ticks attribute into their own
     categories and shares sum to ~1.0 of loop wall (incl. idle). The
-    tick's loop side — the claim and the completion — books to
-    ``tick_schedule``; staging, transfer, dispatch and sync run on the
-    tick worker and are no loop time, so no other ``tick_*`` category
-    exists (with ``offloop_tick=False`` the job runs inside the claim's
-    callback and books there too)."""
+    tick's loop side — the claim and the completion — books to its stage
+    spans, ``stage:engine.claim`` and ``stage:engine.complete`` (the
+    engine sets no category of its own: there is no ``tick_*``);
+    staging, transfer, dispatch and sync run on the tick worker and are
+    no loop time (with ``offloop_tick=False`` the job runs on the loop
+    and books to its own stages)."""
     from orleans_tpu.dispatch import add_vector_grains
     from orleans_tpu.parallel import make_mesh
 
     EchoVec = _make_vector_grain()
     b = (SiloBuilder().with_name("prof-silo").add_grains(EchoGrain)
-         .with_config(offloop_tick=offloop)
+         .with_config(offloop_tick=offloop, metrics_enabled=True)
          .with_options(ProfilingOptions(enabled=True, window=0.05)))
     add_vector_grains(b, EchoVec, mesh=make_mesh(1), dense={EchoVec: 32})
     silo = b.build()
@@ -259,11 +260,12 @@ async def test_occupancy_under_concurrent_turns_and_ticks(offloop):
         shares = prof["shares"]
         assert abs(sum(shares.values()) - 1.0) < 0.02, shares
         assert prof["seconds"].get("turns", 0.0) > 0.0
-        assert prof["seconds"].get("tick_schedule", 0.0) > 0.0, prof
-        assert [c for c in prof["seconds"] if c.startswith("tick_")] \
-            == ["tick_schedule"], prof["seconds"]
-        assert [c for c in LOOP_CATEGORIES if c.startswith("tick_")] \
-            == ["tick_schedule"]
+        for stage in ("engine.claim", "engine.complete"):
+            assert prof["seconds"].get("stage:" + stage, 0.0) > 0.0, prof
+        staged = {c for c in prof["seconds"] if c.startswith("stage:ingest.")}
+        assert bool(staged) == (not offloop), prof["seconds"]
+        assert not [c for c in prof["seconds"] if c.startswith("tick_")]
+        assert not [c for c in LOOP_CATEGORIES if c.startswith("tick_")]
         assert prof["windows"], "no occupancy slices collected"
         # per-category occupancy gauges registered and live
         snap = silo.stats.snapshot()

@@ -367,9 +367,11 @@ async def test_silo_pushes_snapshots_to_collector():
 # Ingest stage attribution over the real socket path
 # ----------------------------------------------------------------------
 async def test_socket_ingest_stages_observed():
-    """Gateway traffic over real TCP populates the decode / enqueue /
-    queue_wait stage histograms and the frame-batch size series, and the
-    per-stage counts line up with the frames counter."""
+    """Gateway traffic over real TCP populates the decode / queue_wait
+    stage histograms and the frame-batch size series, and the per-stage
+    counts line up with the frames counter. Routing observes nothing a
+    message (no ``ingest.enqueue``: the loop account keys the pump's loop
+    time on its stage span)."""
     from orleans_tpu.runtime import SiloBuilder
     from orleans_tpu.runtime.socket_fabric import GatewayClient, SocketFabric
 
@@ -388,10 +390,10 @@ async def test_socket_ingest_stages_observed():
         snap = silo.stats.snapshot()
         hists = snap["histograms"]
         decode = hists[INGEST_STATS["decode"]]
-        enqueue = hists[INGEST_STATS["enqueue"]]
         qwait = hists[INGEST_STATS["queue_wait"]]
         assert decode["count"] >= 40
-        assert enqueue["count"] == decode["count"]
+        assert "enqueue" not in INGEST_STATS
+        assert not [n for n in hists if n.startswith("ingest.enqueue")]
         assert qwait["count"] >= 40
         assert snap["counters"][INGEST_STATS["frames"]] == decode["count"]
         # size + batch histograms carry their custom bounds
@@ -401,7 +403,7 @@ async def test_socket_ingest_stages_observed():
         batch = hists[INGEST_STATS["frame_batch"]]
         assert batch["count"] >= 1 and batch["sum"] == decode["count"]
         # stages are real time: every sum is positive and finite
-        for h in (decode, enqueue, qwait):
+        for h in (decode, qwait):
             assert 0 < h["sum"] < 60
     finally:
         if client is not None:

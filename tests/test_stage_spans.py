@@ -46,7 +46,8 @@ N_KEYS = 16
 ROUNDS = 4
 PER_TICK = ("engine.claim", "engine.worker_queue", "engine.fence_wait",
             "ingest.staging", "ingest.transfer", "ingest.tick.dispatch",
-            "ingest.tick.sync", "engine.complete_hop", "engine.resolve")
+            "ingest.tick.sync", "engine.complete_hop", "engine.complete",
+            "engine.resolve")
 PER_FLUSH = ("flush", "flush.locate", "flush.gather", "flush.write")
 
 
