@@ -221,6 +221,28 @@ def serialize(obj: Any) -> bytes:
     return _pickle_dumps(obj)
 
 
+def serialize_rows(columns: dict, n: int, list_max: int):
+    """``serialize(row)`` for each of the first ``n`` rows of a table's
+    host ``columns`` (field → numpy array, rows on the first axis), from
+    one call of the native codec: the row being ``{field: value}`` with a
+    field of one value a row as its Python value (``tolist()``), a field
+    of up to ``list_max`` values a row as its row's ``tolist()``, and a
+    wider field as its numpy row. An iterator: each row's bytes object is
+    written once, in C, when it is asked for — no row dict, no buffer to
+    grow and copy — so a consumer that lets a blob go before it takes
+    the next keeps one row in flight.
+
+    None when the native codec is not built (``serialize`` is pickle
+    then) or a column is not one it carries — a dtype other than bool /
+    int / uint / float of 1, 2, 4 or 8 bytes, a byte order not native, a
+    uint64 field of up to ``list_max`` values: the caller then serialises
+    row by row. Any strides are carried."""
+    if _hotwire is None:
+        return None
+    return _hotwire.dumps_rows(tuple(columns), tuple(columns.values()), n,
+                               list_max)
+
+
 # Module roots the wire-tier decoder will instantiate. Anything else is
 # rejected — the analog of the reference's serializer registration gate
 # (``SerializationManager.Register``): only known types cross the wire.

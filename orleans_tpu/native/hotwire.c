@@ -777,6 +777,336 @@ static PyObject *hw_dumps(PyObject *self, PyObject *obj) {
     return out;
 }
 
+/* ------------------------------------------------------------------ */
+/* dumps_rows: a chunk of table rows, encoded from its columns         */
+/*
+ * dumps_rows(fields, columns, n, list_max) gives, for each of the first
+ * n rows of the columns in turn, the bytes dumps() writes for the row dict
+ * {field: value} in which a column of one value a row gives the Python
+ * value tolist() gives (bool, int, float), a column of 1..list_max
+ * values a row gives its row's tolist() (nested lists), and a wider one
+ * its row as a numpy array.  Nothing Python is built on the way: each
+ * row's size is computed first and its bytes object written once.
+ *
+ * Carried: exactly np.ndarray columns of native-endian bool / int / uint
+ * / float items of 1, 2, 4 or 8 bytes, any strides (a wide row that lies
+ * as one block is copied as one, any other is read through its strides
+ * into C order, as dumps() packs it).  A uint64 column of few values is
+ * not carried (a value past int64 would take
+ * the pickle escape).  Returns None, having encoded nothing, when any
+ * column is not carried; the columns are checked before the first row. */
+
+enum { COL_SCALAR, COL_LIST, COL_ND };
+
+typedef struct {
+    Py_buffer v;
+    const char *name;
+    Py_ssize_t name_len;
+    char kind;          /* 'b' bool, 'i', 'u', 'f' */
+    int isz;
+    int mode;
+    Py_ssize_t fixed;   /* bytes of a row's value that no value changes */
+    Py_ssize_t nbytes;  /* COL_ND: the row's data bytes */
+    int block;          /* COL_ND: the row's bytes lie as one C-order block */
+} RowCol;
+
+static inline int varint_len(uint64_t v) {
+    int n = 1;
+    while (v >= 0x80) { v >>= 7; n++; }
+    return n;
+}
+
+static inline char *put_varint(char *p, uint64_t v) {
+    do { uint8_t b = v & 0x7F; v >>= 7; if (v) b |= 0x80; *p++ = (char)b; }
+    while (v);
+    return p;
+}
+
+/* numpy's own half -> double (npy_halfbits_to_doublebits): exact, and a
+   signalling NaN stays signalling, as tolist() gives it */
+static uint64_t half_to_double_bits(uint16_t h) {
+    uint64_t sgn = ((uint64_t)h & 0x8000u) << 48;
+    uint16_t exp = h & 0x7c00u, sig = h & 0x03ffu;
+    if (exp == 0x7c00u)
+        return sgn + 0x7ff0000000000000ULL + ((uint64_t)sig << 42);
+    if (exp)
+        return sgn + (((uint64_t)(h & 0x7fffu) + 0xfc000u) << 42);
+    if (!sig)
+        return sgn;
+    int e = 0;
+    sig <<= 1;
+    while (!(sig & 0x0400u)) { sig <<= 1; e++; }
+    return sgn + ((uint64_t)(1023 - 15 - e) << 52) +
+           ((uint64_t)(sig & 0x03ffu) << 42);
+}
+
+static inline int64_t read_int(const char *p, char kind, int isz) {
+    switch (isz) {
+    case 1: return kind == 'i' ? (int64_t)*(const int8_t *)p
+                               : (int64_t)*(const uint8_t *)p;
+    case 2: { uint16_t u; memcpy(&u, p, 2);
+              return kind == 'i' ? (int64_t)(int16_t)u : (int64_t)u; }
+    case 4: { uint32_t u; memcpy(&u, p, 4);
+              return kind == 'i' ? (int64_t)(int32_t)u : (int64_t)u; }
+    default: { int64_t v; memcpy(&v, p, 8); return v; }
+    }
+}
+
+/* one value of a scalar or list column, written at q; returns the end */
+static char *put_scalar(const RowCol *c, const char *p, char *q) {
+    if (c->kind == 'b') {
+        *q++ = *p ? T_TRUE : T_FALSE;
+        return q;
+    }
+    if (c->kind == 'f') {
+        uint64_t bits;
+        if (c->isz == 2) {
+            uint16_t h; memcpy(&h, p, 2);
+            bits = half_to_double_bits(h);
+        } else {
+            double d;
+            if (c->isz == 4) { float f; memcpy(&f, p, 4); d = f; }
+            else memcpy(&d, p, 8);
+            memcpy(&bits, &d, 8);
+        }
+        *q++ = T_FLOAT;
+        memcpy(q, &bits, 8);
+        return q + 8;
+    }
+    *q++ = T_INT;
+    return put_varint(q, zigzag(read_int(p, c->kind, c->isz)));
+}
+
+/* the nested lists of one row of a list column, from dimension dim on */
+static char *put_list(const RowCol *c, int dim, const char *p, char *q) {
+    Py_ssize_t d = c->v.shape[dim], stride = c->v.strides[dim];
+    *q++ = T_LIST;
+    q = put_varint(q, (uint64_t)d);
+    for (Py_ssize_t i = 0; i < d; i++, p += stride)
+        q = dim + 1 < c->v.ndim ? put_list(c, dim + 1, p, q)
+                                : put_scalar(c, p, q);
+    return q;
+}
+
+/* a wide row's items in C order, read through the column's strides */
+static char *put_strided(const RowCol *c, int dim, const char *p, char *q) {
+    Py_ssize_t d = c->v.shape[dim], stride = c->v.strides[dim];
+    if (dim + 1 < c->v.ndim) {
+        for (Py_ssize_t i = 0; i < d; i++, p += stride)
+            q = put_strided(c, dim + 1, p, q);
+        return q;
+    }
+    switch (c->isz) {
+    case 1: for (Py_ssize_t i = 0; i < d; i++, p += stride) *q++ = *p;
+            return q;
+    case 2: for (Py_ssize_t i = 0; i < d; i++, p += stride, q += 2)
+                memcpy(q, p, 2);
+            return q;
+    case 4: for (Py_ssize_t i = 0; i < d; i++, p += stride, q += 4)
+                memcpy(q, p, 4);
+            return q;
+    default: for (Py_ssize_t i = 0; i < d; i++, p += stride, q += 8)
+                memcpy(q, p, 8);
+            return q;
+    }
+}
+
+/* the variable part of one row's value: the bytes of its int values
+   (every other part of a row's size is the column's `fixed`) */
+static Py_ssize_t row_ints_len(const RowCol *c, int dim, const char *p) {
+    if (dim == c->v.ndim)
+        return varint_len(zigzag(read_int(p, c->kind, c->isz)));
+    Py_ssize_t len = 0, stride = c->v.strides[dim];
+    for (Py_ssize_t i = 0; i < c->v.shape[dim]; i++, p += stride)
+        len += row_ints_len(c, dim + 1, p);
+    return len;
+}
+
+/* Fill one column's description; 1 = carried, 0 = not (no error set),
+   -1 = error.  The column's buffer is held from here on (when 1). */
+static int row_col(RowCol *c, PyObject *name, PyObject *col, Py_ssize_t n,
+                   Py_ssize_t list_max) {
+    if (!PyUnicode_CheckExact(name) ||
+        (PyObject *)Py_TYPE(col) != g_state.nd_array_cls)
+        return 0;
+    c->name = PyUnicode_AsUTF8AndSize(name, &c->name_len);
+    if (!c->name) { PyErr_Clear(); return 0; }
+    if (PyObject_GetBuffer(col, &c->v, PyBUF_RECORDS_RO) < 0) {
+        PyErr_Clear();  /* a dtype with no buffer form */
+        return 0;
+    }
+    const char *f = c->v.format ? c->v.format : "B";
+    if (*f == '<' || *f == '=' || *f == '@' || *f == '|') f++;
+    char kind = 0;
+    if (f[0] && !f[1]) {
+        if (f[0] == '?') kind = 'b';
+        else if (strchr("bhilq", f[0])) kind = 'i';
+        else if (strchr("BHILQ", f[0])) kind = 'u';
+        else if (strchr("efd", f[0])) kind = 'f';
+    }
+    int isz = (int)c->v.itemsize;
+    if (!kind || !(isz == 1 || isz == 2 || isz == 4 || isz == 8) ||
+        c->v.ndim < 1 || c->v.ndim > 33 || c->v.shape[0] < n) {
+        PyBuffer_Release(&c->v);
+        return 0;
+    }
+    c->kind = kind;
+    c->isz = isz;
+    Py_ssize_t count = 1;
+    for (int d = 1; d < c->v.ndim; d++) count *= c->v.shape[d];
+    if (count > list_max) {
+        /* the row as dumps() writes a numpy row: T_NDARRAY's header, then
+           its bytes in C order */
+        Py_ssize_t expect = isz;
+        c->block = 1;
+        for (int d = c->v.ndim - 1; d >= 1; d--) {
+            if (c->v.shape[d] != 1 && c->v.strides[d] != expect)
+                c->block = 0;
+            expect *= c->v.shape[d];
+        }
+        c->mode = COL_ND;
+        c->nbytes = count * isz;
+        c->fixed = 4 + varint_len((uint64_t)(c->v.ndim - 1)) + c->nbytes;
+        for (int d = 1; d < c->v.ndim; d++)
+            c->fixed += varint_len((uint64_t)c->v.shape[d]);
+        return 1;
+    }
+    if (kind == 'u' && isz == 8) {
+        PyBuffer_Release(&c->v);
+        return 0;
+    }
+    c->mode = c->v.ndim == 1 ? COL_SCALAR : COL_LIST;
+    /* each list's tag and count, level by level; a bool or float value is
+       all fixed, an int value its tag here and its varint per row */
+    Py_ssize_t lists = 1, fixed = 0;
+    for (int d = 1; d < c->v.ndim; d++) {
+        fixed += lists * (1 + varint_len((uint64_t)c->v.shape[d]));
+        lists *= c->v.shape[d];
+    }
+    c->fixed = fixed + count * (kind == 'f' ? 9 : 1);
+    return 1;
+}
+
+/* The encoder: an iterator whose next() is the next row's bytes.  Each
+   row is written when it is asked for, so a consumer that stores a blob
+   and lets an older one go before it asks for the next keeps one row's
+   bytes in flight: the freed block is the next row's, still in cache. */
+typedef struct {
+    PyObject_HEAD
+    PyObject *fields;   /* keeps the names' UTF-8 alive */
+    RowCol *cols;
+    Py_ssize_t nf, held, n, next;
+    Py_ssize_t base;    /* a row's bytes that no value changes */
+    int ints;           /* a scalar or list column of ints: sizes vary */
+} RowIter;
+
+static void rowiter_dealloc(RowIter *it) {
+    for (Py_ssize_t j = 0; j < it->held; j++)
+        PyBuffer_Release(&it->cols[j].v);
+    PyMem_Free(it->cols);
+    Py_XDECREF(it->fields);
+    Py_TYPE(it)->tp_free((PyObject *)it);
+}
+
+static PyObject *rowiter_next(RowIter *it) {
+    if (it->next >= it->n) return NULL;
+    Py_ssize_t r = it->next++, nf = it->nf, size = it->base;
+    if (it->ints)
+        for (Py_ssize_t j = 0; j < nf; j++) {
+            RowCol *c = &it->cols[j];
+            if (c->mode != COL_ND && (c->kind == 'i' || c->kind == 'u'))
+                size += row_ints_len(c, 1, (const char *)c->v.buf +
+                                           r * c->v.strides[0]);
+        }
+    PyObject *blob = PyBytes_FromStringAndSize(NULL, size);
+    if (!blob) return NULL;
+    char *q = PyBytes_AS_STRING(blob);
+    *q++ = (char)(uint8_t)HW_MAGIC;
+    *q++ = (char)HW_VERSION;
+    *q++ = T_DICT;
+    q = put_varint(q, (uint64_t)nf);
+    for (Py_ssize_t j = 0; j < nf; j++) {
+        RowCol *c = &it->cols[j];
+        const char *p = (const char *)c->v.buf + r * c->v.strides[0];
+        *q++ = T_STR;
+        q = put_varint(q, (uint64_t)c->name_len);
+        memcpy(q, c->name, c->name_len);
+        q += c->name_len;
+        if (c->mode == COL_SCALAR) {
+            q = put_scalar(c, p, q);
+        } else if (c->mode == COL_LIST) {
+            q = put_list(c, 1, p, q);
+        } else {
+            *q++ = T_NDARRAY;
+            *q++ = c->kind;
+            *q++ = (char)c->isz;
+            *q++ = 0;
+            q = put_varint(q, (uint64_t)(c->v.ndim - 1));
+            for (int d = 1; d < c->v.ndim; d++)
+                q = put_varint(q, (uint64_t)c->v.shape[d]);
+            if (c->block) {
+                memcpy(q, p, c->nbytes);
+                q += c->nbytes;
+            } else {
+                q = put_strided(c, 1, p, q);
+            }
+        }
+    }
+    return blob;
+}
+
+static PyTypeObject RowIter_Type = {
+    PyVarObject_HEAD_INIT(NULL, 0)
+    .tp_name = "_hotwire.RowIter",
+    .tp_basicsize = sizeof(RowIter),
+    .tp_dealloc = (destructor)rowiter_dealloc,
+    .tp_flags = Py_TPFLAGS_DEFAULT,
+    .tp_doc = "dumps_rows' rows, each encoded when it is asked for",
+    .tp_iter = PyObject_SelfIter,
+    .tp_iternext = (iternextfunc)rowiter_next,
+};
+
+static PyObject *hw_dumps_rows(PyObject *self, PyObject *args) {
+    PyObject *fields, *columns;
+    Py_ssize_t n, list_max;
+    if (!PyArg_ParseTuple(args, "O!O!nn", &PyTuple_Type, &fields,
+                          &PyTuple_Type, &columns, &n, &list_max))
+        return NULL;
+    Py_ssize_t nf = PyTuple_GET_SIZE(fields);
+    if (PyTuple_GET_SIZE(columns) != nf || n < 0) {
+        PyErr_SetString(PyExc_ValueError,
+                        "dumps_rows: one column a field, n >= 0");
+        return NULL;
+    }
+#if PY_BIG_ENDIAN
+    Py_RETURN_NONE;
+#endif
+    if (!g_state.nd_restore) Py_RETURN_NONE;
+    RowIter *it = PyObject_New(RowIter, &RowIter_Type);
+    if (!it) return NULL;
+    it->cols = PyMem_Calloc(nf ? nf : 1, sizeof(RowCol));
+    it->fields = Py_NewRef(fields);
+    it->nf = nf; it->held = 0; it->n = n; it->next = 0; it->ints = 0;
+    /* magic, version, the dict's tag and count; then each key and each
+       value's fixed part */
+    it->base = 3 + varint_len((uint64_t)nf);
+    if (!it->cols) { Py_DECREF(it); return PyErr_NoMemory(); }
+    for (; it->held < nf; it->held++) {
+        RowCol *c = &it->cols[it->held];
+        int rc = row_col(c, PyTuple_GET_ITEM(fields, it->held),
+                         PyTuple_GET_ITEM(columns, it->held), n, list_max);
+        if (rc <= 0) {
+            Py_DECREF(it);
+            if (rc < 0) return NULL;
+            Py_RETURN_NONE;
+        }
+        it->base += 1 + varint_len((uint64_t)c->name_len) + c->name_len +
+                    c->fixed;
+        it->ints |= c->mode != COL_ND && (c->kind == 'i' || c->kind == 'u');
+    }
+    return (PyObject *)it;
+}
+
 static PyObject *hw_loads(PyObject *self, PyObject *arg) {
     Py_buffer view;
     if (PyObject_GetBuffer(arg, &view, PyBUF_SIMPLE) < 0) return NULL;
@@ -1845,6 +2175,10 @@ static PyMethodDef hw_methods[] = {
      "Encode a value to hotwire bytes (magic-prefixed)."},
     {"loads", hw_loads, METH_O,
      "Decode hotwire bytes back to a value."},
+    {"dumps_rows", hw_dumps_rows, METH_VARARGS,
+     "dumps_rows(fields, columns, n, list_max) -> iterator | None: "
+     "dumps() of each of n table rows, encoded from the columns as it is "
+     "asked for; None when a column is not one it carries."},
     {"pack_attrs", hw_pack_attrs, METH_VARARGS,
      "pack_attrs(obj, names, extra) -> bytes: encode getattr'd fields."},
     {"unpack_attrs", hw_unpack_attrs, METH_VARARGS,
@@ -1905,6 +2239,7 @@ static struct PyModuleDef hw_module = {
 
 PyMODINIT_FUNC PyInit__hotwire(void) {
     memset(&g_state, 0, sizeof(g_state));
+    if (PyType_Ready(&RowIter_Type) < 0) return NULL;
     empty_args = PyTuple_New(0);
     if (!empty_args) return NULL;
     return PyModule_Create(&hw_module);

@@ -316,6 +316,9 @@ FLUSH_STATS = {
     # counter: of those, rows written while a later chunk of their pass
     # was still to come down (0 for a pass of one chunk)
     "pipelined": "vector.storage.flush.pipelined",
+    # counter: of those, rows whose stored blob came from the chunk's one
+    # codec call on its columns (not a row serialised by itself)
+    "encoded": "vector.storage.flush.encoded",
 }
 
 RECOVER_STATS = {

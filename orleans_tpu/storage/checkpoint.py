@@ -32,6 +32,7 @@ import numpy as np
 
 from ..core.errors import InconsistentStateError
 from ..core.ids import GrainId, GrainType
+from ..core.serialization import serialize_rows
 from ..dispatch.engine import MIN_BUCKET, _bucket
 from ..observability.stats import NO_SPAN, StageSpan
 from .core import ADOPT_ETAG, GrainStorage
@@ -204,6 +205,35 @@ def _observe_laps(stats, laps: list) -> None:
         stats.observe(key, secs)
 
 
+class _ChunkEntries:
+    """``write_many``'s entries for one landed chunk of a pass. Iterated,
+    they are the (grain id, row, etag) triples every provider takes, each
+    row built from the chunk's columns as it is asked for
+    (``VectorStorageBridge._rows``). ``serialized()`` offers the same
+    entries with each row as ``serialize`` encodes it — the chunk's blobs
+    from one codec call on its columns (``serialize_rows``) — or None
+    where the codec does not carry them; ``encoded`` says whether a
+    provider took them."""
+
+    __slots__ = ("ids", "etags", "host", "encoded")
+
+    def __init__(self, ids: list, etags: list, host: dict[str, np.ndarray]):
+        self.ids, self.etags, self.host = ids, etags, host
+        self.encoded = False
+
+    def __iter__(self):
+        return zip(self.ids, VectorStorageBridge._rows(self.host,
+                                                      len(self.ids)),
+                   self.etags)
+
+    def serialized(self):
+        blobs = serialize_rows(self.host, len(self.ids), _LIST_MAX)
+        if blobs is None:
+            return None
+        self.encoded = True
+        return zip(self.ids, blobs, self.etags)
+
+
 class VectorStorageBridge:
     """Write-behind per-actor persistence for one VectorGrain class: rows
     flushed to / loaded from an ordinary ``GrainStorage`` provider, with
@@ -224,6 +254,9 @@ class VectorStorageBridge:
         # come down (every chunk's but the last's): how far flush() runs
         # download and write at the same time
         self.pipelined = 0
+        # rows written whose stored blob the provider took from the
+        # chunk's one codec call (``_ChunkEntries.serialized``)
+        self.encoded = 0
         # observed, not configured: does the provider bring its own
         # write_many, or does a flush go through the per-key default?
         self.batched = (type(storage).write_many
@@ -343,13 +376,15 @@ class VectorStorageBridge:
                 for c in host.values()]
         return (dict(zip(fields, vals)) for vals in zip(*cols))
 
-    def _entries(self, kept: list[int], host: dict[str, np.ndarray]):
-        """``write_many``'s entries for the located keys, one at a time:
+    def _entries(self, kept: list[int], host: dict[str, np.ndarray]
+                 ) -> _ChunkEntries:
+        """``write_many``'s entries for the located keys of one chunk:
         (grain id, row, the etag this bridge remembers or ADOPT_ETAG)."""
         etag_of, gid = self._etags.get, self._grain_id
-        for key, row in zip(kept, self._rows(host, len(kept))):
-            etag = etag_of(key)
-            yield gid(key), row, ADOPT_ETAG if etag is None else etag
+        return _ChunkEntries(
+            [gid(k) for k in kept],
+            [ADOPT_ETAG if (e := etag_of(k)) is None else e for k in kept],
+            host)
 
     def _release_conflicted(self, tbl, key: int) -> None:
         # another silo flushed this key since our last write: an
@@ -381,9 +416,11 @@ class VectorStorageBridge:
         rows at a time, with every chunk's host copy asked for
         (``_launch``): that is the snapshot, and the fence goes back
         there. Then, in chunk order: wait for a chunk's columns
-        (``_land``), turn them into rows as the provider consumes them
-        (``_rows``), and ``storage.write_many`` them with each key's
-        remembered etag (or ``ADOPT_ETAG`` where this bridge has none: a
+        (``_land``), hand them to ``storage.write_many`` as entries that
+        are rows built as the provider consumes them (``_rows``) or, for
+        a provider that stores ``serialize``'s bytes (``MemoryStorage``),
+        the rows' blobs from one codec call (``_ChunkEntries``), with each
+        key's remembered etag (or ``ADOPT_ETAG`` where this bridge has none: a
         fresh bridge after a checkpoint restore has no etag memory but IS
         the legitimate writer — the device row is the truth being
         flushed) — while the runtime's own threads bring the later chunks
@@ -420,8 +457,8 @@ class VectorStorageBridge:
         ``flush=<n>`` a pass: flush.locate (under the fence),
         flush.gather (loop time obtaining rows: the launches under the
         fence and the waits for the chunks, summed) and flush.write
-        (building the rows and the provider's ``write_many``, summed over
-        the chunks)."""
+        (building the rows or their blobs and the provider's
+        ``write_many``, summed over the chunks)."""
         keys = np.asarray(keys if isinstance(keys, np.ndarray)
                           else list(keys), np.int64)
         if not keys.size:
@@ -458,8 +495,9 @@ class VectorStorageBridge:
                 part = kept[lo:lo + m]
                 lo += m
                 with span("flush.write", nest=False):
+                    entries = self._entries(part, host)
                     results = await self.storage.write_many(
-                        self.grain_type, self._entries(part, host))
+                        self.grain_type, entries)
                 wrote, bad = 0, []
                 for key, r in zip(part, results):
                     if not isinstance(r, BaseException):
@@ -475,6 +513,8 @@ class VectorStorageBridge:
                     self.runtime._mark_dirty(self.grain_class, bad)
                     failed += bad
                 written += wrote
+                if entries.encoded:
+                    self.encoded += wrote
                 if chunks:  # a later chunk was still to come down
                     self.pipelined += wrote
         finally:

@@ -19,7 +19,11 @@ through it. Its default in the base class is one concurrent ``read``/``write``
 per key, so a provider that knows only per-key operations (``FileStorage``,
 the fault- and latency-injecting wrappers, a user's own) keeps its per-key
 latency, faults and interleaving; ``MemoryStorage`` overrides it with a single
-synchronous pass over its dict.
+synchronous pass over its dict. Entries may also offer ``serialized()``:
+the same entries with each state as ``serialize`` encodes it, or None —
+the write-behind flush's chunks do, from one codec call on their columns.
+A provider that stores those bytes may take them (``MemoryStorage`` does);
+every other provider iterates the entries as ever.
 
 Batched read: ``GrainStorage.read_many(grain_type, grain_ids)`` is the same
 contract turned round — one item per id, in order, ``(state, etag)`` or the
@@ -172,10 +176,15 @@ class MemoryStorage(GrainStorage):
     async def write_many(self, grain_type, entries):
         """The whole batch in one synchronous pass over the dict: no
         coroutine per key, an adopting entry needs no read, and each
-        state is serialised and let go before the next is taken."""
+        state is serialised and let go before the next is taken — or,
+        where the entries offer their states already serialised (module
+        docstring), their blobs are stored as given."""
         data, seq = self._data, self._etag_seq
+        offered = getattr(entries, "serialized", None)
+        items = offered() if offered is not None else None
+        encoded = items is not None
         out = []
-        for grain_id, state, etag in entries:
+        for grain_id, state, etag in items if encoded else entries:
             k = _key(grain_type, grain_id)
             cur = data.get(k)
             cur_etag = cur[1] if cur else None
@@ -184,11 +193,14 @@ class MemoryStorage(GrainStorage):
                     f"etag mismatch for {grain_id}", stored_etag=cur_etag,
                     current_etag=etag))
                 continue
-            try:
-                blob = serialize(state)
-            except Exception as e:  # noqa: BLE001 — this entry's result
-                out.append(e)
-                continue
+            if encoded:
+                blob = state
+            else:
+                try:
+                    blob = serialize(state)
+                except Exception as e:  # noqa: BLE001 — this entry's result
+                    out.append(e)
+                    continue
             new_etag = f"e{next(seq)}"
             data[k] = (blob, new_etag)
             out.append(new_etag)

@@ -6,12 +6,16 @@ of which reads a clock."""
 
 import asyncio
 import itertools
+import math
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import orleans_tpu.core.serialization as ser
 from orleans_tpu.core.errors import InconsistentStateError
+from orleans_tpu.core.ids import GrainId, GrainType
+from orleans_tpu.core.serialization import serialize
 from orleans_tpu.dispatch import (VectorGrain, VectorRuntime, actor_method,
                                   add_vector_grains)
 from orleans_tpu.observability.stats import FLUSH_STATS
@@ -21,6 +25,7 @@ from orleans_tpu.storage import (ADOPT_ETAG, ErrorInjectionStorage,
                                  GrainStorage, LatencyStorage, MemoryStorage,
                                  VectorStorageBridge)
 from orleans_tpu.storage import checkpoint
+from orleans_tpu.storage import core as storage_core
 from orleans_tpu.storage.checkpoint import _gather_rows
 
 
@@ -780,3 +785,232 @@ async def test_a_pass_cancelled_between_chunks_is_persisted_by_the_stop_drain(
         state, etag = await storage.read("HostedCounter", bridge._grain_id(k))
         assert state == {"total": 2 * (k + 1)}
         assert bridge._etags[k] == etag
+
+
+# -- a chunk's blobs from one codec call on its columns ----------------------
+
+HW = ser._hotwire
+
+
+@pytest.fixture(params=["native", "pickle"])
+def codec(request, monkeypatch) -> bool:
+    """Each case runs on the native codec and on the build without it
+    (``ORLEANS_TPU_NATIVE=0``: ``serialize`` is pickle); the value says
+    whether the chunk's one codec call is there to engage."""
+    native = request.param == "native" and HW is not None
+    monkeypatch.setattr(ser, "_hotwire", HW if native else None)
+    return native
+
+
+def _edge(dtype) -> np.ndarray:
+    """The values of ``dtype`` a Python round trip or a varint could get
+    wrong: extremes, -0.0, NaN, infinities, a subnormal."""
+    dt = np.dtype(dtype)
+    if dt.kind == "b":
+        return np.array([True, False, False, True], dt)
+    if dt.kind in "iu":
+        info = np.iinfo(dt)  # and a zigzag varint's byte edges
+        return np.array([v for v in (0, 1, -1, info.max, info.min,
+                                     info.max // 3, info.min // 5, 63, 64,
+                                     -64, -65, 8191, 8192)
+                         if info.min <= v <= info.max], dt)
+    info = np.finfo(dt)  # and a signalling NaN: tolist()'s own rounding
+    snan = np.array([(0x7c01, 0x7f800001, 0x7ff0000000000001)[
+        dt.itemsize // 4]], f"u{dt.itemsize}").view(dt)
+    return np.concatenate([np.array(
+        [-0.0, np.nan, np.inf, -np.inf, info.max, -info.max,
+         info.smallest_subnormal, info.tiny, 1 / 3, 0.1], dt), snan])
+
+
+def _column(dtype, shape, n: int, salt: int = 0) -> np.ndarray:
+    """``n`` rows of ``shape``, every value one of ``_edge``'s, rows that
+    differ from each other."""
+    edge = _edge(dtype)
+    count = n * math.prod(shape)
+    pick = (np.arange(count) * 7 + salt) % len(edge)
+    return edge[pick].reshape((n, *shape))
+
+
+SCALARS = {f"s_{np.dtype(d).name}": (d, ()) for d in (
+    np.bool_, np.int8, np.int16, np.int32, np.int64, np.uint8, np.uint16,
+    np.uint32, np.float16, np.float32, np.float64)}
+CHUNKS = {
+    "scalars": SCALARS,
+    "vectors": {"v1": (np.float32, (1,)), "v16": (np.int32, (16,)),
+                "v17": (np.int32, (17,)), "b3": (np.bool_, (3,)),
+                "h4": (np.float16, (4,)), "q16": (np.int64, (16,)),
+                "u17": (np.uint64, (17,)), "d17": (np.float64, (17,)),
+                "n": (np.int32, ())},
+    "nested": {"m2x3": (np.int16, (2, 3)), "m4x4": (np.uint8, (4, 4)),
+               "m4x5": (np.float32, (4, 5)), "e0": (np.int32, (0,)),
+               "e2x0": (np.int32, (2, 0))},
+    "wide": {"kb": (np.uint8, (1024,)), "timeline": (np.uint8, (32768,)),
+             "followers": (np.int32, (128,)), "seq": (np.int32, ())},
+}
+
+
+def _chunk(fields: dict, n: int, salt: int = 0) -> dict:
+    return {f: _column(d, s, n, salt + i)
+            for i, (f, (d, s)) in enumerate(fields.items())}
+
+
+def _gids(n: int) -> list:
+    return [GrainId.for_grain(GrainType.of("T"), k) for k in range(n)]
+
+
+def _stored(storage, gids) -> list:
+    return [storage._data[storage_core._key("T", g)][0] for g in gids]
+
+
+async def _write_chunk(storage, host: dict, n: int, etags=None):
+    gids = _gids(n)
+    entries = checkpoint._ChunkEntries(
+        gids, etags or [ADOPT_ETAG] * n, host)
+    return gids, entries, await storage.write_many("T", entries)
+
+
+@pytest.mark.parametrize("n", [1, 12])
+@pytest.mark.parametrize("chunk", list(CHUNKS))
+async def test_a_chunks_blobs_are_serialize_of_each_row(codec, chunk, n):
+    """Stored byte for byte as ``serialize`` encodes the row dict
+    ``_rows`` builds — lists up to 16 values, numpy rows past that —
+    from one codec call where the native codec is built, row by row
+    where it is not."""
+    host = _chunk(CHUNKS[chunk], n)
+    storage = MemoryStorage()
+    gids, entries, out = await _write_chunk(storage, host, n)
+    assert all(isinstance(r, str) for r in out)
+    assert entries.encoded is codec
+    want = [serialize(row) for row in VectorStorageBridge._rows(host, n)]
+    assert _stored(storage, gids) == want
+
+
+def test_strided_columns_are_read_where_their_rows_are(codec):
+    """A column in any order is read through its strides — a wide row
+    that is not one block too (the TPU may hand a wide result back
+    column-major): the bytes are those of the row packed in C order."""
+    n = 9
+    host = {**_chunk(CHUNKS["vectors"], n), **_chunk(CHUNKS["nested"], n)}
+    strided = {f: np.asfortranarray(c) for f, c in host.items()}
+    strided["every_other"] = _column(np.float32, (6,), 2 * n)[::2]
+    strided["backwards"] = _column(np.uint8, (40,), n)[::-1]
+    strided["f_order_3d"] = np.asfortranarray(_column(np.int16, (3, 7), n))
+    assert not strided["v17"][0].flags.c_contiguous
+    assert not strided["f_order_3d"][0].flags.c_contiguous
+    blobs = ser.serialize_rows(strided, n, checkpoint._LIST_MAX)
+    assert (blobs is not None) is codec
+    if codec:
+        assert list(blobs) == [serialize(r) for r in
+                               VectorStorageBridge._rows(strided, n)]
+
+
+UNCARRIED = {
+    "complex": np.arange(8, dtype=np.complex64).reshape(8, 1),
+    "big-endian": np.arange(8, dtype=">i4"),
+    "uint64-scalar": np.array([0, 1, 2**63, 2**64 - 1] * 2, np.uint64),
+    "longdouble": np.arange(8, dtype=np.longdouble),
+    "datetime": np.arange(8).astype("datetime64[s]"),
+}
+
+
+@pytest.mark.parametrize("kind", list(UNCARRIED))
+async def test_a_column_the_codec_does_not_carry_falls_back_for_the_chunk(
+        codec, kind):
+    n = 8
+    host = {"ok": _column(np.int32, (), n), "odd": UNCARRIED[kind]}
+    storage = MemoryStorage()
+    gids, entries, out = await _write_chunk(storage, host, n)
+    assert all(isinstance(r, str) for r in out)
+    assert entries.encoded is False
+    assert _stored(storage, gids) == [
+        serialize(row) for row in VectorStorageBridge._rows(host, n)]
+
+
+async def test_the_fast_path_keeps_per_entry_etag_checks(codec):
+    """Etag per entry, in order: the right etag, a stale one, none for an
+    absent key, ADOPT_ETAG for a present and for an absent one — one
+    conflict fails that entry only."""
+    n = 6
+    storage = MemoryStorage()
+    gids, _, first = await _write_chunk(storage, _chunk(CHUNKS["wide"], n), n)
+    host = _chunk(CHUNKS["wide"], n + 2, salt=5)
+    etags = [first[0], "stale", first[2], ADOPT_ETAG, None, first[5],
+             None, ADOPT_ETAG]
+    gids, entries, out = await _write_chunk(storage, host, n + 2, etags)
+    assert entries.encoded is codec
+    ok = [True, False, True, True, False, True, True, True]
+    assert [isinstance(r, str) for r in out] == ok
+    assert all(isinstance(r, InconsistentStateError)
+               for r, good in zip(out, ok) if not good)
+    assert len(set(out[i] for i in range(n + 2) if ok[i])) == sum(ok)
+    rows = list(VectorStorageBridge._rows(host, n + 2))
+    old = list(VectorStorageBridge._rows(_chunk(CHUNKS["wide"], n), n))
+    assert _stored(storage, gids) == [
+        serialize(rows[i] if ok[i] else old[i]) for i in range(n + 2)]
+    for i, g in enumerate(gids):
+        assert (await storage.read("T", g))[1] == \
+            (out[i] if ok[i] else first[i])
+
+
+async def test_a_per_key_subclass_sees_row_dicts_and_no_blob(codec):
+    seen = []
+
+    class Counting(MemoryStorage):
+        async def write(self, grain_type, grain_id, state, etag):
+            seen.append(type(state))
+            return await super().write(grain_type, grain_id, state, etag)
+
+    assert Counting.write_many is GrainStorage.write_many
+    n = 5
+    host = _chunk(CHUNKS["wide"], n)
+    storage = Counting()
+    gids, entries, out = await _write_chunk(storage, host, n)
+    assert seen == [dict] * n and entries.encoded is False
+    assert _stored(storage, gids) == [
+        serialize(row) for row in VectorStorageBridge._rows(host, n)]
+
+
+Account = _grain(jnp.uint8, (1024,))
+
+
+@pytest.mark.parametrize("kind", ["batched", "per_key"])
+async def test_a_flush_then_load_gives_back_the_same_rows(codec, kind):
+    """Device rows → one codec call (or row by row) → storage → a fresh
+    table: the same bytes, and the bridge counts the rows whose blob the
+    codec wrote."""
+    n = 40
+    rt = _runtime(Account, n, mesh=1, cap=64)
+    futs = [rt.call(Account, k, "put",
+                    v=((np.arange(1024) * 3 + k) % 256).astype(np.uint8))
+            for k in range(n)]
+    await rt.flush()
+    await asyncio.gather(*futs)
+    storage = PROVIDERS[kind]()
+    bridge = VectorStorageBridge(rt, Account, storage)
+    assert await bridge.flush(range(n)) == n
+    assert bridge.encoded == (n if codec and kind == "batched" else 0)
+    want = _bits(_device_rows(rt, Account, n))
+    rt2 = _runtime(Account, n, mesh=1, cap=64)
+    assert await VectorStorageBridge(rt2, Account, storage).load(
+        range(n)) == list(range(n))
+    got = _bits(_device_rows(rt2, Account, n))
+    assert all(np.array_equal(got[f], want[f]) for f in want)
+
+
+@pytest.mark.parametrize("kind", ["batched", "per_key"])
+async def test_encoded_counter_counts_the_rows_the_codec_wrote(codec, kind):
+    silo = _silo(PROVIDERS[kind](), 0.02)
+    await silo.start()
+    client = await ClusterClient(silo.fabric).connect()
+    try:
+        await asyncio.gather(*(client.get_grain(HostedCounter, k)
+                               .add(x=np.int32(1)) for k in range(20)))
+    finally:
+        await client.close_async()
+        await silo.stop()  # final drain
+    flushed = silo.stats.get(FLUSH_STATS["flushed"])
+    assert flushed >= 20
+    assert FLUSH_STATS["encoded"] in silo.stats.counters  # 0, not absent
+    engaged = codec and kind == "batched"
+    assert silo.stats.get(FLUSH_STATS["encoded"]) == (
+        flushed if engaged else 0)
