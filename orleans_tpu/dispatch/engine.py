@@ -70,6 +70,7 @@ _DEFERRED = "engine.deferred"               # counter: msgs deferred >= once
 _DEFER_WAIT = "engine.defer_wait.seconds"   # first deferral -> claimed
 _HELD = "engine.held"                       # counter: msgs held >= once
 _COMPLETE_HOP = "engine.complete_hop.seconds"
+_REPLIES_BATCHED = "engine.replies_batched"  # counter: served replies
 _WORKER_IDLE = "engine.worker_idle.seconds"  # previous job's end -> dequeue
 _IDLE_PIECE_S = 0.05  # a longer wait for a job is posted in pieces of this
 # worker-side ledger stamp (cost attribution, observability.ledger): the
@@ -353,7 +354,10 @@ class _Pending:
     delay AND conflict-deferred extra ticks are attributed, on the owning
     silo only. ``future`` may be None (one-way batched-ingress calls —
     nothing consumes the per-lane result, so the batch skips the
-    future/callback machinery for them entirely). ``trace`` is an
+    future/callback machinery for them entirely). ``token`` is a served
+    call's reply token (None for every other): such a call carries no
+    future, and its job's completion hands ``(token, result)`` to the
+    runtime's reply function with the rest of the job's. ``trace`` is an
     optional ``(trace_id, parent_span_id)`` request trace context (set
     by the dispatcher's vector bridge and by the cross-process staging
     ring): a batch containing traced items records a correctly-parented
@@ -363,10 +367,10 @@ class _Pending:
     in-process calls."""
 
     __slots__ = ("key_hash", "shard", "slot", "fresh", "args", "future",
-                 "t_enq", "trace", "origin", "t_defer", "held")
+                 "token", "t_enq", "trace", "origin", "t_defer", "held")
 
     def __init__(self, key_hash, shard, slot, args, future,
-                 t_enq=0.0, trace=None, origin=None):
+                 t_enq=0.0, trace=None, origin=None, token=None):
         self.key_hash = key_hash
         self.shard = shard
         self.slot = slot
@@ -375,6 +379,7 @@ class _Pending:
         self.held = False   # counted in engine.held (metrics on only)
         self.args = args
         self.future = future
+        self.token = token
         self.t_enq = t_enq
         self.trace = trace
         self.origin = origin
@@ -396,10 +401,12 @@ class _TickJob:
     with metrics off. ``outbox`` is a sending method's messages between
     its tick and their delivery (``_Outbox``; None for every other job,
     and again once delivered); ``host`` keeps the tick's results across
-    the exchange's stages."""
+    the exchange's stages. ``replied`` is set once the job's served
+    calls have been handed to the reply function, so that no failure
+    after it answers them twice."""
 
     __slots__ = ("cls", "method", "ready", "trace", "per_shard", "span",
-                 "stats", "tick", "t_hand", "outbox", "host")
+                 "stats", "tick", "t_hand", "outbox", "host", "replied")
 
     def __init__(self, cls, method, ready, trace=False, tick=0):
         self.cls = cls
@@ -413,6 +420,7 @@ class _TickJob:
         self.t_hand = 0.0
         self.outbox: _Outbox | None = None
         self.host = None
+        self.replied = False
 
 
 class _Outbox:
@@ -577,6 +585,11 @@ class VectorRuntime:
         # None = a receiver nothing has touched starts from
         # initial_state
         self.receiver_recovery = None
+        # the served calls' reply function, set by dispatch.hosting to
+        # the silo dispatcher's: ``fn(pairs)`` takes one completed job's
+        # ``(reply token, result or exception)`` pairs in lane order and
+        # answers them together (call_group's reply-token items)
+        self.replies = None
         # the tick worker: claimed batches run on a dedicated per-engine
         # thread, started lazily by the first claimed job — staging fill,
         # operand upload, kernel dispatch and the host materialize sync
@@ -787,18 +800,22 @@ class VectorRuntime:
                    items: list, traces: list | None = None,
                    origin: str | None = None) -> list:
         """Grouped enqueue — the engine half of the batched ingress
-        hand-off. ``items`` is a list of ``(key_hash, kwargs,
-        want_future)`` triples for ONE (class, method); every invocation
-        joins the pending batch with a single method/table resolution,
-        one enqueue stamp, and one tick schedule, instead of N
-        :meth:`call` hops. Returns one entry per item in item order
-        (within-batch arrival order is preserved into the tick's lane
-        layout): a future where ``want_future`` was set, else None —
-        one-way calls skip the future/set_result/callback machinery
-        entirely, which is a large slice of the per-message hand-off
-        cost at batch sizes. A per-item schema violation resolves THAT
-        item's future with the error (or drops the one-way item, the
-        per-message one-way contract); the rest of the group proceeds.
+        hand-off. ``items`` is a list of ``(key_hash, kwargs, reply)``
+        triples for ONE (class, method); every invocation joins the
+        pending batch with a single method/table resolution, one enqueue
+        stamp, and one tick schedule, instead of N :meth:`call` hops.
+        ``reply`` says where the call's result goes: ``True`` to a
+        future, a false value nowhere (one-way calls), anything else is
+        a served call's reply token — the call carries it instead of a
+        future, and its job's completion answers it through
+        :attr:`replies` together with the rest of the job's
+        (:meth:`_resolve_batch`). Returns one entry per item in item
+        order (within-batch arrival order is preserved into the tick's
+        lane layout): a future where ``reply`` is ``True``, else None.
+        A per-item schema violation resolves THAT item's future with the
+        error, answers its token with it (one reply call for the group's
+        failures), or drops the one-way item (the per-message one-way
+        contract); the rest of the group proceeds.
 
         ``traces`` is an optional parallel list of per-item
         ``(trace_id, parent_span_id)`` contexts (None entries for
@@ -820,10 +837,14 @@ class VectorRuntime:
         dense_n, per = tbl.dense_n, tbl.dense_per_shard
         writes, active, uninit = not m.read_only, tbl.dense_active, tbl.uninit
         futs: list = []
+        failed: list | None = None
         idx = -1
-        for key_hash, args, want_future in items:
+        for key_hash, args, reply in items:
             idx += 1
-            fut = loop.create_future() if want_future else None
+            if reply is True:
+                fut, token = loop.create_future(), None
+            else:
+                fut, token = None, reply or None
             futs.append(fut)
             try:
                 if skeys is not None and args.keys() != skeys:
@@ -844,15 +865,21 @@ class VectorRuntime:
                 # while already-enqueued items still tick)
                 if fut is not None:
                     fut.set_exception(e)
+                elif token is not None:
+                    if failed is None:
+                        failed = []
+                    failed.append((token, e))
                 continue
             if pend is None:
                 pend = self.pending.setdefault((grain_class, method), [])
             pend.append(_Pending(key_hash, shard, slot, args, fut,
                                  t_enq,
                                  traces[idx] if traces is not None else None,
-                                 origin))
+                                 origin, token))
         if pend is not None:
             self._schedule_tick(loop)
+        if failed is not None:
+            self.replies(failed)
         return futs
 
     def call_packed(self, grain_class: type, method: str, key_hashes: list,
@@ -1237,27 +1264,23 @@ class VectorRuntime:
                           job.cls.__name__, job.method, exc_info=err)
                 self._record_tick_span(getattr(err, "_tick_span", None),
                                        job.ready, error=True)
-                for p in job.ready:
-                    if p.future is not None and not p.future.done():
-                        p.future.set_exception(err)
+                self._fail_job(job, err)
             else:
                 self._record_tick_span(job.span, job.ready)
                 if ob is not None:
                     # a sender one of whose messages could not be
                     # delivered fails alone; the others' replies follow
+                    # (a served sender's in _resolve_batch's one pass)
                     for (s, i), e in ob.failed.items():
                         fut = job.per_shard[s][i].future
                         if fut is not None and not fut.done():
                             fut.set_exception(e)
                     self.messages_processed += ob.delivered
-                self._resolve_batch(job.ready, job.per_shard, host,
-                                    job.tick, job.cls, job.method)
-        except BaseException as e2:  # noqa: BLE001 — fail futures, not loop
+                self._resolve_batch(job, host)
+        except BaseException as e2:  # noqa: BLE001 — fail callers, not loop
             log.exception("vector tick completion failed for %s.%s",
                           job.cls.__name__, job.method)
-            for p in job.ready:
-                if p.future is not None and not p.future.done():
-                    p.future.set_exception(e2)
+            self._fail_job(job, e2)
         finally:
             self._inflight -= 1
             self._inflight_msgs -= len(job.ready)
@@ -1410,20 +1433,64 @@ class VectorRuntime:
             st.increment(_DEFERRED, first_deferrals)  # 0 too: it exists
         return ready
 
-    def _resolve_batch(self, ready: list[_Pending], per_shard,
-                       host, tick: int, cls: type, method: str) -> None:
+    def _fail_job(self, job: _TickJob, exc: BaseException) -> None:
+        """Answer every caller of a job that failed with ``exc``: its
+        futures not yet done, and — unless they were handed over already
+        — its served calls, in one reply call."""
+        for p in job.ready:
+            if p.future is not None and not p.future.done():
+                p.future.set_exception(exc)
+        if job.replied:
+            return
+        job.replied = True
+        pairs = [(p.token, exc) for p in job.ready if p.token is not None]
+        if pairs:
+            self.replies(pairs)
+
+    def _resolve_batch(self, job: _TickJob, host) -> None:
+        """A job's results to its callers in one pass, shard by shard in
+        lane order (a key's replies keep their order): ``host`` is
+        flattened once, and each call's result is its leaves indexed
+        ``[s, i]`` — the objects ``a[s, i]`` gives, so a reply encodes
+        as it always has. A future is resolved; a served call's token
+        joins the job's ``(token, result)`` pairs (a sender whose
+        delivery failed: its error), and the pairs go to the reply
+        function once, at the end, inside the ``engine.resolve`` span."""
         st = self.stats
+        ready = job.ready
         if st is not None:
             # the mix that reached the device, by (class, method); their
             # sum is ``ingest.messages``
-            st.increment(f"{_MESSAGES}.{cls.__name__}.{method}", len(ready))
-        with StageSpan(st, "engine.resolve", tick=tick) \
+            st.increment(f"{_MESSAGES}.{job.cls.__name__}.{job.method}",
+                         len(ready))
+        with StageSpan(st, "engine.resolve", tick=job.tick) \
                 if st is not None else NO_SPAN:
-            for s, ps in enumerate(per_shard):
+            leaves, treedef = jax.tree_util.tree_flatten(host)
+            # a bare array needs no unflatten
+            leaf = leaves[0] if treedef.num_nodes == 1 and leaves else None
+            unflatten = treedef.unflatten
+            failed = job.outbox.failed if job.outbox is not None else None
+            pairs: list = []
+            for s, ps in enumerate(job.per_shard):
                 for i, p in enumerate(ps):
-                    if p.future is not None and not p.future.done():
-                        p.future.set_result(jax.tree_util.tree_map(
-                            lambda a: a[s, i], host))
+                    token = p.token
+                    if token is None:
+                        fut = p.future
+                        if fut is None or fut.done():
+                            continue
+                    v = leaf[s, i] if leaf is not None \
+                        else unflatten([a[s, i] for a in leaves])
+                    if token is None:
+                        fut.set_result(v)
+                    elif failed and (s, i) in failed:
+                        pairs.append((token, failed[s, i]))
+                    else:
+                        pairs.append((token, v))
+            if st is not None:
+                st.increment(_REPLIES_BATCHED, len(pairs))  # 0: it exists
+            if pairs:
+                job.replied = True
+                self.replies(pairs)
         self.messages_processed += len(ready)
 
     def _execute_batch(self, cls: type, method: str, ready: list[_Pending],

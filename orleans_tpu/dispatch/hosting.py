@@ -61,6 +61,8 @@ def add_vector_grains(builder, *grain_classes: type[VectorGrain],
                 mesh=mesh, capacity_per_shard=capacity_per_shard,
                 options=options)
         silo.vector.offloop_tick = silo.config.offloop_tick
+        # served calls are answered a completed job at a time
+        silo.vector.replies = silo.dispatcher._reply_vector_calls
         if silo.tracer is not None:
             silo.vector.tracer = silo.tracer  # device ticks join the traces
         if silo.ingest_stats is not None:

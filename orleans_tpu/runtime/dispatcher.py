@@ -51,10 +51,6 @@ if TYPE_CHECKING:
 
 log = logging.getLogger("orleans.dispatcher")
 
-# default for _finish_vector_call's hdr: "not parsed yet" (None means a
-# parse already happened and found no trace header)
-_HDR_UNPARSED = object()
-
 from ..observability.stats import INGEST_STATS as _INGEST  # noqa: E402
 from ..observability.stats import SLO_STATS as _SLO  # noqa: E402
 from ..observability.stats import (COUNT_BOUNDS,  # noqa: E402
@@ -381,49 +377,44 @@ class Dispatcher:
         # a read of one message (first-touch recovery included)
         self._enqueue_vector_calls(rt, vcls, (msg,))
 
-    def _finish_vector_call(self, msg: Message, fut: "asyncio.Future",
-                            hdr=_HDR_UNPARSED) -> None:
-        """Attach the response plumbing for one device-tier call: the
-        device span (host view of the batched kernel turn) and the
-        tick-resolved response callback. Shared by the per-message bridge
-        and the batched ingress path (receive_vector_batch, which hands
-        in the trace header it already parsed for the want-future
-        decision)."""
-        tracer = self.silo.tracer
-        vspan = None
-        if tracer is not None:
-            if hdr is _HDR_UNPARSED:
-                hdr = context_from_headers(msg.request_context)
-            if hdr is not None:
-                # request-leg network span (host-path twin): the
-                # client's send-side wall stamp → here, so the traced
-                # waterfall has no dark gap between the client root and
-                # the first silo-side span (ISSUE 20: under worker
-                # processes the next span is the shm staging-ring leg)
-                tracer.record(hdr[0], hdr[1], "network", "network",
-                              hdr[2], time.time() - hdr[2])
-                # device span: enqueue → tick-resolved future (the host
-                # view of the batched kernel turn; the engine's own tick
-                # spans carry the per-tick detail)
-                vspan = tracer.open(
-                    f"{msg.interface_name}.{msg.method_name}", "device",
-                    hdr[0], hdr[1])
-                fut.add_done_callback(lambda f, s=vspan: tracer.close(s))
-        if msg.direction == Direction.ONE_WAY:
-            # retrieve a failed tick's exception so the loop never logs
-            # "exception was never retrieved" for fire-and-forget calls
-            fut.add_done_callback(
-                lambda f: None if f.cancelled() else f.exception())
-            return
+    def _open_device_span(self, tracer, msg: Message, hdr):
+        """A sampled device-tier call's spans (``hdr``: its parsed trace
+        header, None where it carries none): the request-leg network
+        span, and the device span it returns — the host view of the
+        batched kernel turn, open until :meth:`_reply_vector_calls`
+        answers the call. None for an unsampled call."""
+        if hdr is None:
+            return None
+        # request-leg network span (host-path twin): the client's
+        # send-side wall stamp → here, so the traced waterfall has no
+        # dark gap between the client root and the first silo-side span
+        # (under worker processes the next span is the shm staging-ring
+        # leg)
+        tracer.record(hdr[0], hdr[1], "network", "network",
+                      hdr[2], time.time() - hdr[2])
+        # device span: enqueue → the job's reply (the engine's own tick
+        # spans carry the per-tick detail)
+        return tracer.open(f"{msg.interface_name}.{msg.method_name}",
+                           "device", hdr[0], hdr[1])
 
-        def done(f: "asyncio.Future") -> None:
-            if f.cancelled():
-                return
-            exc = f.exception()
-            if exc is not None:
-                resp = make_error_response(msg, exc)
-            else:
-                resp = make_response(msg, f.result())
+    def _reply_vector_calls(self, pairs) -> None:
+        """The engine's reply function (``VectorRuntime.replies``): one
+        completed job's served calls, ``(token, result or exception)``
+        in lane order, answered together — each response built here and
+        joined to the egress accumulator, whose armed end-of-burst flush
+        hands the job's replies to the fabric as one group per
+        destination. A token is ``(request, device span or None)``; a
+        one-way call carries one only to close its sampled device
+        span."""
+        tracer = self.silo.tracer
+        send = self.send_response
+        for (msg, vspan), v in pairs:
+            if vspan is not None:
+                tracer.close(vspan)
+            if msg.direction == Direction.ONE_WAY:
+                continue
+            resp = make_error_response(msg, v) \
+                if isinstance(v, BaseException) else make_response(msg, v)
             if vspan is not None:
                 # response-leg wall stamp, as on host turns: the client
                 # measures stamp → arrival as the response network span
@@ -431,9 +422,11 @@ class Dispatcher:
                 # the response-ring pop, so the waterfall's tail —
                 # egress encode + wire — is covered too)
                 self._stamp_response(resp, vspan)
-            self.send_response(msg, resp)
-
-        fut.add_done_callback(done)
+            try:
+                send(msg, resp)
+            except Exception:  # noqa: BLE001 — the job's other callers
+                log.exception("reply to %s failed to send",
+                              msg.method_name)
 
     def receive_vector_batch(self, vcls: type, msgs: list) -> None:
         """Batched twin of :meth:`_handle_vector_request`: one ingress
@@ -536,12 +529,12 @@ class Dispatcher:
             # record the routing hash so ownership sweeps can re-derive
             # who owns this resident row after a membership change
             tbl.note_route(key_hash, msg.target_grain.uniform_hash)
-            # one-way calls need no result plumbing — the engine skips
-            # their futures entirely. Exception: a SAMPLED one-way (trace
-            # header present) still needs its device span closed at tick
-            # resolution; the unsampled majority must not pay the
-            # future/callback cost just because a tracer is installed.
-            # Parsed once here and handed to _finish_vector_call below.
+            # one-way calls need no reply — the engine carries nothing
+            # for them. Exception: a SAMPLED one-way (trace header
+            # present) still needs its device span closed when its job
+            # completes, so it rides with a reply token like a two-way
+            # call; the unsampled majority pays nothing for an installed
+            # tracer. Parsed once here, for _call_vector_groups.
             hdr = (context_from_headers(msg.request_context)
                    if tracer is not None else None)
             want = msg.direction != Direction.ONE_WAY or hdr is not None
@@ -604,35 +597,36 @@ class Dispatcher:
             if m.direction != Direction.ONE_WAY)
 
     def _call_vector_groups(self, rt, vcls: type, groups: dict) -> None:
-        """One ``call_group`` a method, and the response plumbing of
-        every call that wants one."""
+        """One ``call_group`` a method. A call that wants an answer rides
+        with its reply token, ``(request, device span)``, and no future:
+        the engine (or the shm proxy, in a worker process) answers the
+        calls of a completed job together through
+        :meth:`_reply_vector_calls`, its ``replies``."""
         tracer = self.silo.tracer
         for method, items in groups.items():
             if not items:
                 continue
-            try:
+            if tracer is None:
+                traces = None
+                calls = [(kh, kw, (m, None) if w else None)
+                         for m, kh, kw, w, _ in items]
+            else:
                 # per-item trace contexts ride beside the group: the
-                # engine (or the shm proxy, in a worker process) parents
-                # the device-tick span into each sampled request's trace
-                # — hdr differs per message within one group, so it
-                # threads per item, not per group
-                traces = ([hdr[:2] if hdr is not None else None
-                           for _, _, _, _, hdr in items]
-                          if tracer is not None else None)
-                futs = rt.call_group(vcls, method,
-                                     [(kh, kw, w) for _, kh, kw, w, _ in
-                                      items], traces=traces)
+                # engine (or the shm proxy) parents the device-tick span
+                # into each sampled request's trace — hdr differs per
+                # message within one group, so it threads per item, not
+                # per group
+                traces = [hdr[:2] if hdr is not None else None
+                          for _, _, _, _, hdr in items]
+                calls = [(kh, kw, (m, self._open_device_span(tracer, m, hdr))
+                          if w else None)
+                         for m, kh, kw, w, hdr in items]
+            try:
+                rt.call_group(vcls, method, calls, traces=traces)
             except Exception as e:  # noqa: BLE001 — unknown method etc.
-                # the whole group failed together: one egress flush per
-                # destination instead of N per-message response hops
-                self.send_response_batch(
-                    (m, make_error_response(m, e))
-                    for m, _, _, _, _ in items
-                    if m.direction != Direction.ONE_WAY)
-                continue
-            for (m, _, _, _, hdr), fut in zip(items, futs):
-                if fut is not None:
-                    self._finish_vector_call(m, fut, hdr)
+                # the whole group failed together: answered together
+                self._reply_vector_calls(
+                    [(tok, e) for _, _, tok in calls if tok is not None])
 
     # ==================================================================
     # Bulk-population collectives (MapReduce over actors): the host-tier

@@ -351,6 +351,11 @@ class VectorShmClient:
         self.owner_address = owner_address
         self._tables: dict[type, _ProxyTable] = {}
         self._futures: dict[int, asyncio.Future] = {}
+        # corr -> reply token of an in-flight served call (call_group's
+        # reply-token items): answered through ``replies`` — the silo
+        # dispatcher's, set at worker boot — a response batch at a time
+        self._tokens: dict[int, Any] = {}
+        self.replies = None
         self._corr = 0
         # counters mirrored from the engine surface (samplers/ctl read
         # them through getattr guards)
@@ -387,7 +392,7 @@ class VectorShmClient:
         return t
 
     def queue_depth(self) -> int:
-        return len(self._futures)
+        return len(self._futures) + len(self._tokens)
 
     def shutdown_worker(self) -> None:  # Silo.stop symmetry
         pass
@@ -405,8 +410,9 @@ class VectorShmClient:
         (one names tuple + per-argument value columns — the staging
         layout the owner's ``call_packed`` consumes) and lands in the
         shared segment in ONE push. Returns one entry per item in item
-        order: a future where ``want_future`` was set, else None (the
-        ``call_group`` contract).
+        order: a future where ``reply`` is ``True``, else None; a reply
+        token is answered through :attr:`replies` when its response
+        comes back (the ``call_group`` contract).
 
         ``traces`` (optional, parallel to ``items``) carries per-item
         ``(trace_id, parent_span_id)`` contexts: they ride the record
@@ -423,16 +429,21 @@ class VectorShmClient:
         # sub-batches keyed by the kwargs name tuple: schema-bound
         # callers all share one; a mixed group still packs correctly
         subs: dict[tuple, list] = {}
+        sent: list = []  # this push's reply-token corrs
         idx = -1
-        for key_hash, args, want_future in items:
+        for key_hash, args, reply in items:
             idx += 1
-            fut = loop.create_future() if want_future else None
+            fut = loop.create_future() if reply is True else None
             futs.append(fut)
             corr = -1
-            if fut is not None:
+            if reply:
                 self._corr += 1
                 corr = self._corr
-                self._futures[corr] = fut
+                if fut is not None:
+                    self._futures[corr] = fut
+                else:
+                    self._tokens[corr] = reply
+                    sent.append(corr)
             tr = traces[idx] if traces is not None else None
             if tr is not None and tracer is not None:
                 if corr >= 0:
@@ -467,8 +478,11 @@ class VectorShmClient:
                     fut.set_exception(err)
             self._futures = {c: f for c, f in self._futures.items()
                              if not f.done()}
+            failed = [(self._tokens.pop(c), err) for c in sent]
             self._trace_of = {c: t for c, t in self._trace_of.items()
-                              if c in self._futures}
+                              if c in self._futures or c in self._tokens}
+            if failed:
+                self.replies(failed)
         return futs
 
     # -- response-ring drain (worker loop) --------------------------------
@@ -487,12 +501,17 @@ class VectorShmClient:
                 st.observe(_RS_RESPONSE, dwell)
         tracer = self.tracer
         trace_of = self._trace_of
-        futures = self._futures
+        futures, tokens = self._futures, self._tokens
+        pairs: list = []
         for corr, ok, payload in results:
             tr = trace_of.pop(corr, None)
             if tr is not None and tracer is not None and t_push_mono:
                 tracer.record(tr[0], tr[1], "shm.response_ring", "ring",
                               t_push_wall, dwell, pid=os.getpid())
+            token = tokens.pop(corr, None)
+            if token is not None:
+                pairs.append((token, payload))
+                continue
             fut = futures.pop(corr, None)
             if fut is None or fut.done():
                 continue
@@ -500,13 +519,18 @@ class VectorShmClient:
                 fut.set_result(payload)
             else:
                 fut.set_exception(payload)
+        if pairs:
+            self.replies(pairs)
 
     def fail_all(self, exc: Exception) -> None:
         futs, self._futures = self._futures, {}
+        tokens, self._tokens = self._tokens, {}
         self._trace_of.clear()
         for fut in futs.values():
             if not fut.done():
                 fut.set_exception(exc)
+        if tokens:
+            self.replies([(t, exc) for t in tokens.values()])
 
 
 # ---------------------------------------------------------------------------
@@ -1070,6 +1094,7 @@ async def _worker_async(boot: _WorkerBoot) -> None:
         # / metrics-gated registry (both None when the plane is off)
         proxy.tracer = silo.tracer
         proxy.stats = silo.ingest_stats
+        proxy.replies = silo.dispatcher._reply_vector_calls
         silo.vector = proxy
         silo.vector_interfaces.update(boot.vector_interfaces)
     # responses to clients held by OTHER processes route via the owner
